@@ -1,8 +1,6 @@
 # seist_tpu build targets.
 
 NATIVE_DIR := seist_tpu/native
-CXX ?= g++
-CXXFLAGS ?= -O3 -fPIC -shared -std=c++17 -Wall
 
 .PHONY: native test t1 lint lint-baseline irlint-report lockgraph \
 	replay-smoke serve-smoke serve-chaos obs-smoke trace-smoke \
@@ -10,10 +8,11 @@ CXXFLAGS ?= -O3 -fPIC -shared -std=c++17 -Wall
 	bench-repick quant-smoke stream-smoke twin-smoke stream-chaos \
 	batch-chaos bench-batch-fleet clean
 
-native: $(NATIVE_DIR)/libwavekit.so
-
-$(NATIVE_DIR)/libwavekit.so: $(NATIVE_DIR)/wavekit.cpp
-	$(CXX) $(CXXFLAGS) -o $@ $<
+# seist_tpu.native builds its library from wavekit.cpp on first import
+# (hash-named, see seist_tpu/native/__init__.py); this target just does
+# that import and prints where the library landed.
+native:
+	python -c "import seist_tpu.native as n; print(n.lib_path())"
 
 test:
 	python -m pytest tests/ -x -q
@@ -243,4 +242,4 @@ stream-chaos:
 	  -p no:cacheprovider -p no:xdist -p no:randomly
 
 clean:
-	rm -f $(NATIVE_DIR)/libwavekit.so
+	rm -f $(NATIVE_DIR)/libwavekit*.so

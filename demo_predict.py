@@ -55,10 +55,6 @@ def main() -> None:
     parser.add_argument("--output-dir", default="./demo_out", type=str)
     args = parser.parse_args()
 
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
-
     import seist_tpu
     from seist_tpu.models import api
     from seist_tpu.train.checkpoint import load_checkpoint

@@ -3,10 +3,6 @@
 Thin wrapper over seist_tpu.cli (the reference's root main.py equivalent).
 """
 
-from seist_tpu.utils.platform import honor_jax_platforms
-
-honor_jax_platforms()
-
 from seist_tpu.cli import main
 
 if __name__ == "__main__":

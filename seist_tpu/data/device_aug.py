@@ -820,18 +820,22 @@ def unsupported_reasons(
 
 def hbm_budget_bytes(explicit_gb: float = 0.0) -> int:
     """HBM budget for the resident epoch cache: an explicit --device-aug-
-    hbm-gb wins; otherwise half the device's reported bytes_limit; 4 GiB
-    when the backend exposes no memory stats (CPU)."""
+    hbm-gb wins; otherwise half the device's reported ``bytes_limit``. An
+    accelerator that reports no limit is an error (pass the flag). The CPU
+    backend has no HBM and reports no stats: its resident "device" arrays
+    live in host RAM, budgeted at a nominal 4 GiB."""
     if explicit_gb and explicit_gb > 0:
         return int(explicit_gb * (1 << 30))
-    try:
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit // 2
-    except Exception:  # noqa: BLE001 - backends without memory_stats
-        pass
-    return 4 << 30
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
+        return 4 << 30
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit <= 0:
+        raise RuntimeError(
+            f"{dev.device_kind} reports no memory limit (memory_stats() "
+            f"has no bytes_limit): pass --device-aug-hbm-gb explicitly"
+        )
+    return limit // 2
 
 
 def select_device_aug_mode(

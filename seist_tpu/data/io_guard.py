@@ -66,7 +66,7 @@ from seist_tpu.utils.logger import logger
 PREEMPT_EXIT_CODE = 75
 
 
-# --------------------------------------------------------------- fault taxonomy
+# ----------------------------------------------------------------- fault classes
 class CorruptSampleError(Exception):
     """Permanent per-sample fault: the bytes came back but the sample is
     unusable (short read, wrong shape/dtype, non-finite values, missing

@@ -76,12 +76,11 @@ def channel_pad_multiple() -> int:
     """``SEIST_CHANNEL_PAD``: round conv OUT-channel axes up to this
     multiple in the composed/fused dense-conv lowerings (0 = off,
     default). Candidate MFU lowering for the tiny-channel stems
-    (out_dim 8-24 vs the TPU's 128-lane registers; VERDICT r4 #2
-    escalation step 1): zero-padded out-channels compute zeros that are
+    (out_dim 8-24 vs the TPU's 128-lane registers): zero-padded out-channels compute zeros that are
     sliced away before BN, so values and the checkpoint tree are
     untouched — only XLA's layout/tiling choice changes. Promote or
-    revert ON THE MEASURED A/B (tools/r4_silicon.sh iso_channel_pad);
-    until then it is off everywhere."""
+    revert on a measured same-chip A/B (not measured on this
+    installation); until then it is off everywhere."""
     return int(os.environ.get("SEIST_CHANNEL_PAD", "0"))
 
 
@@ -249,8 +248,8 @@ class DepthwiseConv1D(nn.Module):
     Why not XLA's grouped conv: with the SeisT stem's tiny channel counts
     (8-24 vs the TPU's 128-wide lanes, seist.py presets) the grouped-conv
     lowering runs at <1% MFU and dominates the whole model's step time
-    (BASELINE.md round-2 matrix: seist_s 121 ms/step vs phasenet 15 ms at
-    comparable FLOPs). ``impl='shift'`` computes
+    (seen on an earlier installation; not measured on this one).
+    ``impl='shift'`` computes
     ``y[n,l,c] = sum_j x[n, l*s+j, c] * w[j,c]`` as k strided-slice
     multiply-adds — pure VPU elementwise work XLA fuses into one kernel.
     ``impl='grouped'`` keeps the lax.conv path (used off-TPU where grouped
@@ -302,7 +301,7 @@ def depthwise_shift_fma(x: Array, w: Array, stride: int) -> Array:
     lowers on TPU to generic scatter-adds with s32 index vectors and flips
     the activation layout to batch-minor with full-tensor copies — profiled
     at ~6 ms/step in each of SeisT's two stride-2 stems (the same pathology
-    that sank the merged-stem lowering, BASELINE.md). Instead the length
+    that sank the merged-stem lowering). Instead the length
     axis is phase-split by a reshape ``(N, L/s, s, C)``; tap ``j`` is then a
     *contiguous* slice of phase plane ``j % s`` shifted by ``j // s``, whose
     gradient is a plain zero-pad that XLA fuses (pad_add_fusion) like the
@@ -351,8 +350,8 @@ class GroupedConv1D(nn.Module):
       ``y[n,l,g,e] = sum_j sum_d x[n, l*s+j, g, d] * w[j,d,g,e]``.
     * ``dense``   — expand to a block-diagonal DENSE kernel and run one
       ordinary conv: G× more FLOPs, but dense conv1d is the one shape XLA
-      maps well onto the MXU at these sizes (phasenet's 4.1% vs SeisT's
-      0.8% MFU, BASELINE.md) and the FLOPs are ~2% of peak anyway.
+      maps well onto the MXU at these sizes and the FLOPs are ~2% of peak
+      anyway.
     """
 
     features: int

@@ -37,8 +37,8 @@ _conv_kw = dict(kernel_init=trunc_normal_init)
 
 
 def _active_seq_mesh():
-    """The active mesh when its `seq` axis is sharded (--seq-shards > 1),
-    else None. Trace-time lookup — see parallel.mesh.set_active_mesh."""
+    """The mesh of the step being traced when its `seq` axis is sharded
+    (--seq-shards > 1), else None — see parallel.mesh.use_mesh."""
     from seist_tpu.parallel import mesh as mesh_lib
 
     m = mesh_lib.active_mesh()
@@ -114,8 +114,8 @@ class DSConvNormAct(nn.Module):
       one dense conv whose kernel is the tap-wise triple product
       ``A[j,c,o] = sum_d Win[c,d] * w[j,d] * Wp[d,o]`` (tiny einsum over
       the weights, recomputed per step). One dense conv1d is the shape
-      XLA maps best onto the MXU at these channel counts (BASELINE.md:
-      phasenet 4.1% vs SeisT 0.8% MFU), and the activation is read and
+      XLA maps best onto the MXU at these channel counts, and the
+      activation is read and
       written ONCE in each direction instead of three times — the stems
       built from this block were 42% of the seist_l step before.
     """
@@ -257,9 +257,9 @@ class StemBlock(nn.Module):
       kernels are tap-centered into one (K, Cin, 3*Cout) bank, and the
       path concat becomes the conv's out-channel axis (see _fused_paths).
 
-    ``'merged'`` is a measured NEGATIVE result on TPU v5e and therefore
-    not the default: interleaved A/B on seist_l_dpk fp32 b256 gave
-    1,613 wf/s merged vs 1,834/1,838 paths (-12%; BASELINE.md round 2).
+    ``'merged'`` was a NEGATIVE result on an earlier installation
+    (about -12% on seist_l_dpk fp32; not re-measured on this one) and
+    therefore not the default.
     The fwd pass does get fewer passes, but XLA lowers the backward of
     the merged strided-slice FMA (stride-2 stems) to generic scatter-adds
     with s32 index vectors and flips the activation layout to {0,2,1},

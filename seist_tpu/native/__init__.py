@@ -1,41 +1,89 @@
 """ctypes bindings for the native wavekit kernels (see wavekit.cpp).
 
-Loads ``libwavekit.so`` from this directory if present (build with
-``make native``); all callers fall back to the pure-numpy implementations
-when the library is absent, so the build is optional. Set
-``SEIST_TPU_NATIVE=0`` to force the numpy path even when built.
+The library is BUILT from ``wavekit.cpp`` by this module the first time
+it is imported (g++, ~1 s) and cached next to the source under a name
+that carries the source's hash, so a given commit always runs the same
+input path: a stale or foreign ``.so`` lying ignored in the checkout is
+never picked up, and a failed build is an error, not a silent switch to
+numpy (which normalizes in float64, not float32). ``SEIST_TPU_NATIVE=0``
+is the explicit choice of the pure-numpy implementations.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import subprocess
+import tempfile
 from typing import Optional
 
 import numpy as np
 
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "libwavekit.so")
-_lib: Optional[ctypes.CDLL] = None
+_DIR = os.path.dirname(os.path.abspath(__file__))
+_SRC = os.path.join(_DIR, "wavekit.cpp")
 
-if os.environ.get("SEIST_TPU_NATIVE", "auto") != "0" and os.path.exists(_LIB_PATH):
+
+def lib_path() -> str:
+    """Where the library for THIS source lives (hash-named)."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"libwavekit-{digest}.so")
+
+
+def _build(path: str) -> None:
+    """Compile wavekit.cpp to ``path`` atomically (concurrent importers —
+    loader worker processes, xdist workers — each build to a private temp
+    name and rename; last writer wins with identical bytes)."""
+    fd, tmp = tempfile.mkstemp(prefix="libwavekit-", suffix=".so.tmp", dir=_DIR)
+    os.close(fd)
     try:
-        _lib = ctypes.CDLL(_LIB_PATH)
-        _lib.znorm_f32.argtypes = [
-            ctypes.POINTER(ctypes.c_float),
-            ctypes.c_int64,
-            ctypes.c_int64,
-            ctypes.c_int,
-        ]
-        _lib.soft_label_add_f64.argtypes = [
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.c_int64,
-        ]
-    except OSError:
-        _lib = None
+        subprocess.run(
+            [
+                os.environ.get("CXX", "g++"),
+                "-O3", "-fPIC", "-shared", "-std=c++17",
+                "-o", tmp, _SRC,
+            ],
+            check=True,
+            capture_output=True,
+            text=True,
+        )
+        os.replace(tmp, path)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building {path} from wavekit.cpp failed (SEIST_TPU_NATIVE=0 "
+            f"selects the numpy path explicitly):\n{e.stderr}"
+        ) from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
+def _load() -> Optional[ctypes.CDLL]:
+    if os.environ.get("SEIST_TPU_NATIVE", "auto") == "0":
+        return None
+    path = lib_path()
+    if not os.path.exists(path):
+        _build(path)
+    lib = ctypes.CDLL(path)
+    lib.znorm_f32.argtypes = [
+        ctypes.POINTER(ctypes.c_float),
+        ctypes.c_int64,
+        ctypes.c_int64,
+        ctypes.c_int,
+    ]
+    lib.soft_label_add_f64.argtypes = [
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int64,
+    ]
+    return lib
+
+
+_lib: Optional[ctypes.CDLL] = _load()
 
 
 def available() -> bool:

@@ -8,7 +8,8 @@
 // (numerically equal to the numpy path within fp32 accumulation tolerance —
 // verified by tests/test_native.py).
 //
-// Build: `make native` at the repo root (g++ -O3, no dependencies).
+// Built by seist_tpu/native/__init__.py on first import (g++ -O3, no
+// dependencies).
 
 #include <cmath>
 #include <cstdint>

@@ -26,14 +26,14 @@ kernel regenerates the identical mask from the saved seed, so no mask
 tensor is materialized either.
 
 ``fused_pooled_attention`` is numerically identical (fp32) to the
-einsum path for the same seed; on non-TPU backends it falls back to
-that einsum, and ``interpret=True`` drives the same kernels through the
-Pallas interpreter for CPU testing.
+einsum path for the same seed. On the TPU backend the kernel is used and a
+compiler refusal is the run's error; non-TPU backends (and the explicit
+``SEIST_ATTN_IMPL=einsum``) take the einsum, and ``interpret=True`` drives
+the same kernels through the Pallas interpreter in tests only.
 """
 
 from __future__ import annotations
 
-import logging
 import math
 import os
 from functools import partial
@@ -43,9 +43,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-
-_log = logging.getLogger("seist_tpu.pallas_attention")
-
 
 def _wrap_i32(v: int) -> np.int32:
     """Python int -> int32 scalar with explicit two's-complement wrap.
@@ -145,7 +142,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, *, scale, rate, heads):
         sl = slice(h * e, (h + 1) * e)
         p = _softmax_rows(q[:, sl], k[:, sl], scale)
         if rate > 0.0:
-            pid = pl.program_id(0) * heads + h
+            pid = (seed_ref[1] + pl.program_id(0)) * heads + h
             p = _apply_dropout(p, seed_ref[0], pid, rate)
         o_ref[0, :, sl] = jnp.dot(
             p, v[:, sl], preferred_element_type=jnp.float32
@@ -176,7 +173,7 @@ def _bwd_kernel(
     for h in range(heads):
         sl = slice(h * e, (h + 1) * e)
         q, k, v, g = qa[:, sl], ka[:, sl], va[:, sl], ga[:, sl]
-        pid = pl.program_id(0) * heads + h
+        pid = (seed_ref[1] + pl.program_id(0)) * heads + h
         p = _softmax_rows(q, k, scale)  # recomputed probs (L, M)
         if rate > 0.0:
             pd = _apply_dropout(p, seed_ref[0], pid, rate)
@@ -272,185 +269,41 @@ def _fused_bwd(scale, rate, heads, interpret, res, g):
 _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
-# -- kernel health probe ------------------------------------------------------
-#
-# A Mosaic version can reject the kernel at compile time (the head-folded
-# layout writes E-wide feature slices that are not 128-lane aligned). That
-# failure would surface only when the *enclosing* train-step jit compiles —
-# taking down the default train path. Instead, the first TPU-backend call per
-# (L, M, H*E, dropout?, dtype) signature AOT-compiles the kernel fwd+bwd on a
-# batch-1 slice of the real shape (the grid is over batch, so batch-1
-# exercises the exact per-step block shapes) and executes the compiled
-# program once on zero buffers. On failure we log once and route that
-# signature to the identical-math einsum path. Explicit requests
-# (interpret/force/SEIST_ATTN_IMPL=fused) bypass the probe so parity
-# tooling still sees the raw error.
-
-_KERNEL_STATUS: dict = {}
-# Last observed probe outcome per signature, INCLUDING transient failures
-# (which are deliberately kept out of _KERNEL_STATUS so a later trace
-# re-probes). kernel_status_summary() reads this, so a transient failure
-# that baked einsum into a compiled step is still visible in bench JSON
-# and the worker log.
-_KERNEL_EVENTS: dict = {}
-_FALLBACK_LOGGED = False
-
-
-def _probe_kernel(l, m, he, heads, rate, dtype) -> None:
-    # AOT lower+compile, then one real execution. Unlike a traced call,
-    # .lower() never binds into an ambient trace, so this is safe to run
-    # while the enclosing train step is being traced (the previous
-    # ensure_compile_time_eval escape broke outright when JAX moved to the
-    # eager-trace-stack internals — observed live 2026-08-02: constants
-    # created under the eval trace were hoisted out of the kernel trace as
-    # captured consts, then pl.program_id had no eval rule). Mosaic
-    # rejections and VMEM/scratch exhaustion surface at compile; the
-    # execution step keeps runtime-only faults (HBM-full OOM, DMA errors)
-    # routing to the einsum fallback too — the compiled executable takes
-    # concrete (numpy) buffers, so it runs eagerly under any trace.
-    qs = jax.ShapeDtypeStruct((1, l, he), dtype)
-    ks = jax.ShapeDtypeStruct((1, m, he), dtype)
-    ss = jax.ShapeDtypeStruct((1,), jnp.int32)
-
-    def f(q, k, v, seed):
-        return _fused(q, k, v, seed, 1.0, rate, heads, False).sum()
-
-    compiled = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
-        qs, ks, ks, ss
-    ).compile()
-    npdt = np.dtype(dtype)  # ml_dtypes covers bf16 for numpy zeros
-    g = compiled(
-        np.zeros((1, l, he), npdt),
-        np.zeros((1, m, he), npdt),
-        np.zeros((1, m, he), npdt),
-        np.zeros((1,), np.int32),
-    )
-    jax.block_until_ready(g)
-
-
-_TRANSIENT_ERROR_MARKERS = ("RESOURCE_EXHAUSTED", "DEADLINE_EXCEEDED", "UNAVAILABLE")
-# A deterministic kernel VMEM/scratch overflow ALSO surfaces as
-# RESOURCE_EXHAUSTED; unlike HBM pressure it never clears, so re-probing
-# it on every trace would cost a probe compile + warning forever.
-_PERMANENT_EXHAUSTION_MARKERS = ("vmem", "scratch", "smem")
-# Even genuinely-transient failures stop being worth re-probing after a
-# few traces in the same process — cap, then cache as unusable.
-_MAX_TRANSIENT_PROBES = 3
-_TRANSIENT_COUNTS: dict = {}
-
-
-def _is_transient(exc: Exception) -> bool:
-    # A probe can fail for reasons that say nothing about Mosaic's ability to
-    # compile the kernel — e.g. HBM already occupied by the train state, or a
-    # flaky backend connection. Those must not poison the per-process cache.
-    # A VMEM/scratch exhaustion is the opposite: deterministic for the shape,
-    # so treat it as a permanent Mosaic rejection.
-    msg = f"{type(exc).__name__}: {exc}"
-    if not any(marker in msg for marker in _TRANSIENT_ERROR_MARKERS):
-        return False
-    return not any(m in msg.lower() for m in _PERMANENT_EXHAUSTION_MARKERS)
-
-
-def _kernel_usable(l, m, he, heads, rate, dtype) -> bool:
-    key = (l, m, he, heads, rate > 0.0, jnp.dtype(dtype).name)
-    hit = _KERNEL_STATUS.get(key)
-    if hit is not None:
-        return hit
-    try:
-        # The call site usually sits under the train step's jit trace; the
-        # probe must not be traced into it (a nested traced call would
-        # inline instead of compile, and the probe would "fail" on a
-        # perfectly good kernel, permanently einsum-ing the default path).
-        # _probe_kernel uses AOT .lower().compile(), which opens its own
-        # trace context regardless of the ambient one.
-        _probe_kernel(l, m, he, heads, float(rate), dtype)
-        ok = True
-    except Exception as exc:  # noqa: BLE001 - any compile/runtime rejection
-        head = str(exc).splitlines()[0][:200] if str(exc) else ""
-        if _is_transient(exc):
-            n = _TRANSIENT_COUNTS[key] = _TRANSIENT_COUNTS.get(key, 0) + 1
-            if n >= _MAX_TRANSIENT_PROBES:
-                # Enough: stop paying a probe compile per trace. Cache as
-                # unusable (the event log keeps the transient history).
-                _KERNEL_STATUS[key] = False
-                _KERNEL_EVENTS[key] = (
-                    f"einsum-fallback (transient x{n}, re-probe cap hit: "
-                    f"{head})"
-                )
-                _log.warning(
-                    "fused attention probe failed transiently %d times for "
-                    "shape L=%d M=%d HE=%d H=%d %s; caching einsum fallback "
-                    "for this process (%s)",
-                    n, l, m, he, heads, jnp.dtype(dtype).name, head,
-                )
-                return False
-            # Fall back for THIS trace (the enclosing jit bakes einsum in
-            # permanently for this program!) but leave the retry cache
-            # empty so a LATER trace — a re-jit, another shape — re-probes
-            # once memory pressure clears. Record the event so the
-            # fallback is still observable, and log every occurrence (the
-            # one-shot flag below is reserved for permanent rejections).
-            _KERNEL_EVENTS[key] = f"einsum-fallback (transient {head})"
-            _log.warning(
-                "fused attention probe hit a transient error for shape "
-                "L=%d M=%d HE=%d H=%d %s (%s: %s); THIS trace falls back "
-                "to the identical-math einsum path; the kernel will be "
-                "re-probed on the next trace",
-                l, m, he, heads, jnp.dtype(dtype).name,
-                type(exc).__name__, head,
-            )
-            return False
-        global _FALLBACK_LOGGED
-        if not _FALLBACK_LOGGED:
-            _FALLBACK_LOGGED = True
-            _log.warning(
-                "fused attention kernel unusable for shape L=%d M=%d HE=%d "
-                "H=%d %s (%s: %s); falling back to the identical-math einsum "
-                "path (SEIST_ATTN_IMPL=fused to force the kernel)",
-                l, m, he, heads, jnp.dtype(dtype).name,
-                type(exc).__name__, head,
-            )
-        ok = False
-    _KERNEL_STATUS[key] = ok
-    prior = _KERNEL_EVENTS.get(key, "")
-    if ok and "transient" in prior:
-        # An earlier trace of this signature baked einsum in permanently;
-        # this re-probe only helps traces from here on. Keep the history
-        # visible (and keep `overall` degraded) so a bench/worker summary
-        # can't claim a clean "fused" run.
-        _KERNEL_EVENTS[key] = (
-            "fused (re-probed ok; an earlier trace fell back to einsum: "
-            + prior + ")"
-        )
-    else:
-        _KERNEL_EVENTS[key] = "fused" if ok else "einsum-fallback"
-    return ok
-
-
-def kernel_status_summary() -> dict:
-    """Machine-readable outcome of the fused-kernel health probes so far
-    (VERDICT r3 #4: a Mosaic rejection must never silently cost the fused
-    win again). Returns ``{"overall": "fused"|"einsum-fallback"|"unprobed",
-    "signatures": {"L512/M16/HE96/H8/drop=False/bf16": "fused"|
-    "einsum-fallback"|"einsum-fallback (transient ...)"}}`` — bench.py
-    emits this in its JSON line and train/worker.py logs it after the
-    first step. Reads the EVENT log, so a transient probe failure (kept
-    out of the retry cache) is still reported for the trace it affected.
-    """
-    sigs = {}
-    for (l, m, he, heads, drop, dtype), status in _KERNEL_EVENTS.items():
-        sigs[f"L{l}/M{m}/HE{he}/H{heads}/drop={drop}/{dtype}"] = status
-    if not sigs:
-        overall = "unprobed"
-    elif all(v == "fused" for v in sigs.values()):
-        overall = "fused"
-    else:
-        overall = "einsum-fallback"
-    return {"overall": overall, "signatures": sigs}
-
-
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _fused_rows(q3, k3, v3, seed, scale, rate, heads, interpret):
+    """The kernel over the batch rows, laid out as the step being traced
+    lays them out (``parallel.mesh.active_mesh``, scoped by train.step's
+    jit wrappers around the function whose shardings name that mesh).
+
+    No data-parallel mesh: one call on the whole batch. With one: Mosaic
+    kernels cannot be partitioned automatically (XLA refuses the step), so
+    each device runs the kernel on ITS rows inside a shard_map. Either way
+    the second scalar of the seed is the global index of the first row the
+    kernel sees, so the dropout masks are the ones a single device draws
+    for the whole batch."""
+    from jax.sharding import PartitionSpec as P
+
+    from seist_tpu.parallel import mesh as mesh_lib
+
+    static = (scale, rate, heads, interpret)
+    mesh = mesh_lib.active_mesh()
+    if mesh is None or mesh.shape.get(mesh_lib.AXIS_DATA, 1) == 1:
+        seed2 = jnp.concatenate([seed, jnp.zeros((1,), jnp.int32)])
+        return _fused(q3, k3, v3, seed2, *static)
+
+    def local(q3, k3, v3, seed):
+        row0 = lax.axis_index(mesh_lib.AXIS_DATA) * q3.shape[0]
+        seed2 = jnp.stack([seed[0], row0.astype(jnp.int32)])
+        return _fused(q3, k3, v3, seed2, *static)
+
+    rows = P(mesh_lib.AXIS_DATA)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(rows, rows, rows, P()), out_specs=rows,
+        check_vma=False,
+    )(q3, k3, v3, seed)
 
 
 def fused_pooled_attention(
@@ -462,13 +315,13 @@ def fused_pooled_attention(
     dropout_rate: float = 0.0,
     dropout_seed: Optional[jnp.ndarray] = None,
     interpret: bool = False,
-    force: bool = False,
 ) -> jnp.ndarray:
     """Fused attention for ``q (N, L, H, E)``, ``k/v (N, M, H, E)``.
 
-    Uses the Pallas kernel on TPU (or when ``interpret``/``force`` is set);
-    otherwise the XLA einsum path — both compute identical fp32 math,
-    including the dropout mask (same counter-based PRNG in both).
+    Uses the Pallas kernel on TPU (``interpret`` runs it through the Pallas
+    interpreter, for tests); otherwise the XLA einsum path — both compute
+    identical fp32 math, including the dropout mask (same counter-based PRNG
+    in both).
 
     ``dropout_rate`` > 0 applies post-softmax probability dropout (ref
     seist.py:383-388) and requires ``dropout_seed``, an int32 array of
@@ -481,36 +334,18 @@ def fused_pooled_attention(
     if dropout_seed is None:
         dropout_seed = jnp.zeros((1,), jnp.int32)
     dropout_seed = dropout_seed.astype(jnp.int32)
-    # Escape hatches: SEIST_ATTN_IMPL=einsum forces the identical-math XLA
-    # path even on TPU; =fused forces the kernel (skipping the health probe,
-    # so a Mosaic rejection surfaces raw). Unset = auto: kernel on TPU with
-    # a one-time per-shape compile probe and automatic einsum fallback.
-    # Explicit kernel requests (interpret/force, used by parity tooling)
-    # take precedence over the ambient env var.
+    # The kernel on TPU, the einsum elsewhere; SEIST_ATTN_IMPL=einsum is the
+    # explicit choice of the identical-math XLA path on TPU. A compiler
+    # refusal of the kernel is the run's error — nothing routes around it.
     env_impl = os.environ.get("SEIST_ATTN_IMPL")
-    if env_impl not in (None, "", "fused", "einsum"):
+    if env_impl not in (None, "", "einsum"):
         raise ValueError(
-            f"unknown SEIST_ATTN_IMPL {env_impl!r} (use fused or einsum)"
+            f"unknown SEIST_ATTN_IMPL {env_impl!r} (unset it, or einsum)"
         )
-    if env_impl == "einsum" and not (interpret or force):
+    if not (interpret or (env_impl != "einsum" and _on_tpu())):
         return _einsum_attention(q, k, v, scale, dropout_rate, dropout_seed)
-    if not (_on_tpu() or interpret or force):
-        return _einsum_attention(q, k, v, scale, dropout_rate, dropout_seed)
-    h = q.shape[2]
-    if not (interpret or force or env_impl == "fused"):
-        l, m, he = q.shape[1], k.shape[1], h * e
-        if not _kernel_usable(l, m, he, h, dropout_rate, q.dtype):
-            return _einsum_attention(
-                q, k, v, scale, dropout_rate, dropout_seed
-            )
-    o3 = _fused(
-        _fold_heads(q),
-        _fold_heads(k),
-        _fold_heads(v),
-        dropout_seed,
-        scale,
-        float(dropout_rate),
-        h,
-        interpret,
+    o3 = _fused_rows(
+        _fold_heads(q), _fold_heads(k), _fold_heads(v), dropout_seed,
+        scale, float(dropout_rate), q.shape[2], interpret,
     )
     return o3.reshape(q.shape)

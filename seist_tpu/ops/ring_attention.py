@@ -159,13 +159,10 @@ def ring_attention_local(
     o = jnp.zeros((n, h, lq, e), dtype=jnp.float32)
     m = jnp.full((n, h, lq), -jnp.inf, dtype=jnp.float32)
     l = jnp.zeros((n, h, lq), dtype=jnp.float32)
-    if hasattr(lax, "pcast"):
-        # Newer shard_map tracks varying-axis types through scan: the carry
-        # becomes seq-varying after one step, so the initial values must be
-        # marked varying too. (pcast replaced the deprecated lax.pvary.)
-        o, m, l = (lax.pcast(t, (axis_name,), to="varying") for t in (o, m, l))
-    elif "pvary" in dir(lax):  # pragma: no cover - pre-pcast jax
-        o, m, l = (lax.pvary(t, (axis_name,)) for t in (o, m, l))
+    # shard_map tracks varying-axis types through scan: the carry becomes
+    # seq-varying after one step, so the initial values must be marked
+    # varying too.
+    o, m, l = (lax.pcast(t, (axis_name,), to="varying") for t in (o, m, l))
 
     # Peel the first (local-block) step so the scan rotates BEFORE each
     # accumulation — axis_size-1 rotations total, none wasted on a block
@@ -234,22 +231,7 @@ def ring_attention(
         return body(q, k, v, dropout_seed=seed)
 
     in_specs = (spec, spec, spec, seed_spec)
-    try:
-        from jax import shard_map
-
-        fn = shard_map(
-            wrapped, mesh=mesh, in_specs=in_specs, out_specs=spec
-        )
-    except ImportError:  # older jax keeps the experimental path + check_rep
-        from jax.experimental.shard_map import shard_map
-
-        fn = shard_map(
-            wrapped,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=spec,
-            check_rep=False,
-        )
+    fn = jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs, out_specs=spec)
     return fn(q, k, v, dropout_seed)
 
 

@@ -13,8 +13,8 @@ the op count and the summed payload bytes. Payload of one op = the sum of
 its output-shape bytes: XLA's all-reduce combiner merges many gradient
 tensors into ONE tuple-shaped op (``(f32[a], f32[b], ...) all-reduce``)
 whose elements are all distinct transferred buffers (round 3 counted only
-the largest element, undercounting combined gradient all-reduces ~50x —
-VERDICT r3 #6). Async ``-start`` ops are the exception: their tuple
+the largest element, undercounting combined gradient all-reduces ~50x).
+Async ``-start`` ops are the exception: their tuple
 repeats the buffer as (aliased input, output, context scalars), so only
 the largest element is counted there; ``-done`` pairs are skipped.
 These are payload bytes; actual link traffic per chip for a ring

@@ -88,15 +88,19 @@ _BROADCAST_TIMEOUT_MS = 300_000
 
 
 def _coordination_client():
-    """The jax distributed coordination-service client (the same KV store
-    ``jax.distributed.initialize`` rendezvouses through), or None outside
-    an initialized multi-process runtime."""
-    try:
-        from jax._src import distributed
+    """The jax distributed coordination-service client: the KV store
+    ``jax.distributed.initialize`` rendezvouses through. Present whenever
+    ``process_count() > 1`` (tests/test_dist_broadcast.py pins the methods
+    used here against the installed jax)."""
+    from jax._src import distributed
 
-        return distributed.global_state.client
-    except Exception:  # noqa: BLE001 — private API; any change = fallback
-        return None
+    client = distributed.global_state.client
+    if client is None:
+        raise RuntimeError(
+            "broadcast_object needs the coordination service: call "
+            "jax.distributed.initialize() (parallel/dist.init_distributed)"
+        )
+    return client
 
 
 def broadcast_object(obj: Any) -> Any:
@@ -106,52 +110,30 @@ def broadcast_object(obj: Any) -> Any:
     Transport is the coordination-service KV store, NOT an XLA collective:
     process 0 publishes the pickle under a sequenced key, everyone else
     blocks on that key. Host-side control data (checkpoint paths, eval
-    verdicts) has no business riding device allreduces — and on the CPU
-    backend it must not: jaxlib 0.4.37's gloo allreduce intermittently
-    returns a zero-prefixed buffer when two differently-shaped broadcasts
-    run back-to-back (the seed test_multihost failure's second act; an
-    artificial delay between the collectives masks it, which is how it
-    escaped notice upstream). The legacy two-phase broadcast_one_to_all
-    path remains only for runtimes where the private client API is gone.
+    verdicts) has no business riding device allreduces, and the KV store
+    works on every backend, the CPU's included.
     """
     if jax.process_count() <= 1:
         return obj
     import pickle
 
     client = _coordination_client()
-    if client is not None:
-        global _broadcast_seq
-        key = f"seist_tpu/broadcast_object/{_broadcast_seq}"
-        _broadcast_seq += 1
-        if jax.process_index() == 0:
-            client.key_value_set_bytes(key, pickle.dumps(obj))
-            result = obj
-        else:
-            result = pickle.loads(
-                client.blocking_key_value_get_bytes(
-                    key, _BROADCAST_TIMEOUT_MS
-                )
-            )
-        # Barrier-then-delete: once every process has read the value,
-        # process 0 removes the key. Keys must not outlive the call —
-        # they would accumulate over a long run, and a relaunched
-        # incarnation restarting its sequence at 0 against a still-live
-        # coordinator would read the PREVIOUS run's value for the wrong
-        # program point.
-        client.wait_at_barrier(key + "/read", _BROADCAST_TIMEOUT_MS)
-        if jax.process_index() == 0:
-            client.key_value_delete(key)
-        return result
-
-    import numpy as np
-    from jax.experimental import multihost_utils
-
-    payload = np.frombuffer(pickle.dumps(obj), dtype=np.uint8)
-    length = int(
-        multihost_utils.broadcast_one_to_all(np.int64(payload.size))
-    )
-    buf = np.zeros(length, dtype=np.uint8)
+    global _broadcast_seq
+    key = f"seist_tpu/broadcast_object/{_broadcast_seq}"
+    _broadcast_seq += 1
     if jax.process_index() == 0:
-        buf[: payload.size] = payload
-    buf = np.asarray(multihost_utils.broadcast_one_to_all(buf))
-    return pickle.loads(buf.tobytes())
+        client.key_value_set_bytes(key, pickle.dumps(obj))
+        result = obj
+    else:
+        result = pickle.loads(
+            client.blocking_key_value_get_bytes(key, _BROADCAST_TIMEOUT_MS)
+        )
+    # Barrier-then-delete: once every process has read the value, process 0
+    # removes the key. Keys must not outlive the call — they would
+    # accumulate over a long run, and a relaunched incarnation restarting
+    # its sequence at 0 against a still-live coordinator would read the
+    # PREVIOUS run's value for the wrong program point.
+    client.wait_at_barrier(key + "/read", _BROADCAST_TIMEOUT_MS)
+    if jax.process_index() == 0:
+        client.key_value_delete(key)
+    return result

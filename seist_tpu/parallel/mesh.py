@@ -20,6 +20,8 @@ Axis convention (fixed, in this order):
 
 from __future__ import annotations
 
+import contextvars
+from contextlib import contextmanager
 from typing import Any, Optional, Sequence
 
 import jax
@@ -32,38 +34,29 @@ AXIS_MODEL = "model"
 AXIS_SEQ = "seq"
 MESH_AXES = (AXIS_DATA, AXIS_MODEL, AXIS_SEQ)
 
-# Trace-time active mesh: models consult this to route through
-# mesh-axis-aware paths (e.g. SeisT attention -> ring attention when
-# ``seq`` > 1, --seq-shards). Set once by the worker (set_active_mesh) or
-# scoped in tests (use_mesh).
-#
-# CAVEAT: this is read at TRACE time and is NOT part of any jit cache key.
-# A function jitted under one mesh keeps that routing even if the active
-# mesh changes later — always (re)build/jit step functions AFTER setting
-# the mesh, as train_worker/test_worker do. Don't reuse a jitted step
-# across different active meshes.
-_ACTIVE_MESH: list = [None]
-
-
-def set_active_mesh(mesh: Optional[Mesh]) -> None:
-    _ACTIVE_MESH[0] = mesh
+# Trace-time active mesh: the mesh of the step function being traced. Models
+# consult it to route through mesh-axis-aware paths (SeisT attention -> ring
+# attention when ``seq`` > 1; the Pallas attention kernel per batch shard
+# when ``data`` > 1). train.step's jit wrappers scope it around the trace of
+# the function they jit (tests scope it by hand), so a jitted step always
+# traces under the mesh its shardings name and nothing else — parameter
+# init, the server's programs — ever sees one.
+_ACTIVE_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "seist_active_mesh", default=None
+)
 
 
 def active_mesh() -> Optional[Mesh]:
-    return _ACTIVE_MESH[0]
-
-
-from contextlib import contextmanager  # noqa: E402
+    return _ACTIVE_MESH.get()
 
 
 @contextmanager
 def use_mesh(mesh: Optional[Mesh]):
-    old = _ACTIVE_MESH[0]
-    _ACTIVE_MESH[0] = mesh
+    token = _ACTIVE_MESH.set(mesh)
     try:
         yield
     finally:
-        _ACTIVE_MESH[0] = old
+        _ACTIVE_MESH.reset(token)
 
 
 def make_mesh(

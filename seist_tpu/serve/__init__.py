@@ -2,7 +2,7 @@
 stdlib HTTP front-end, tiered load shedding, and a replica-fleet front
 tier. See docs/SERVING.md.
 
-    seist_tpu.serve.protocol   wire format + error taxonomy (HTTP statuses)
+    seist_tpu.serve.protocol   wire format + error classes (HTTP statuses)
     seist_tpu.serve.batcher    request coalescing, backpressure, deadlines
     seist_tpu.serve.pool       model loading, shared-trunk task groups,
                                AOT warm-up, output decode

@@ -1,5 +1,5 @@
 """Wire protocol for the serve subsystem: request parsing, response
-shaping, and the error taxonomy the HTTP front-end maps to status codes.
+shaping, and the error classes the HTTP front-end maps to status codes.
 
 Everything is plain JSON over stdlib types — no new dependencies. A
 predict request body is::

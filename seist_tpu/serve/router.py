@@ -447,7 +447,7 @@ class _Outcome:
         return self.status == 0
 
     def error_code(self) -> str:
-        """The serve error taxonomy code from a JSON error body (the
+        """The serve error-class code from a JSON error body (the
         'shed' vs 'shutting_down' discriminator for 503s)."""
         if not self.body:
             return ""

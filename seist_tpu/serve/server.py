@@ -963,6 +963,9 @@ class ServeService:
         }
 
     def healthz(self) -> Dict[str, Any]:
+        # lazy: this module is imported by the jax-free front tier
+        from seist_tpu.utils.misc import device_summary
+
         entries: Dict[str, Any] = {}
         for name in self.pool.names():
             e = self.pool.get(name)
@@ -986,6 +989,7 @@ class ServeService:
             # vs-mid-roll discriminator (docs/SERVING.md "Live rollout").
             "entries": entries,
             "buckets": list(self.buckets),
+            "device": device_summary(),
             "uptime_s": round(time.monotonic() - self._started_at, 3),
             "warmup": self.pool.warmup_report,
         }
@@ -1493,9 +1497,6 @@ def watch_until_shutdown(
 
 def main(argv: Optional[List[str]] = None) -> None:
     from seist_tpu.utils.misc import enable_compile_cache
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     # Warm-up compiles dominate replica startup; the persistent cache
     # (same one cli.main_worker uses) makes a supervisor relaunch re-enter
     # rotation in seconds instead of re-paying every bucket's compile.

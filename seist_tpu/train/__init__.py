@@ -27,5 +27,4 @@ from seist_tpu.train.step import (  # noqa: F401
     make_accum_train_step,
     make_multi_train_step,
     make_train_step,
-    resolve_donation,
 )

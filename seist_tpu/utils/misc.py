@@ -86,35 +86,51 @@ def dump_namespace(args: Any) -> str:
     return "Arguments:\n" + "\n".join(lines)
 
 
+def device_summary() -> Dict[str, Any]:
+    """The devices this process computes on, as JAX reports them — every
+    run and every result says where it ran (train log, serve /healthz,
+    chip_smoke.py's last line)."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+# The one default place of the persistent XLA compilation cache: a fixed
+# path inside the checkout (the path is part of the cache key's context on
+# some backends, and a machine that keeps nothing but the checkout keeps
+# this). Listed in .gitignore.
+_REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
+
+
 def enable_compile_cache(
     verbose: bool = False, min_compile_seconds: int = 10
 ) -> None:
     """Persistent XLA compilation cache (large models cost minutes per
-    compile on TPU; identical programs across runs hit the disk cache —
-    measured 3x on CPU test-sized programs too, which is why conftest.py
-    enables it for the tier-1 suite with a low threshold).
+    compile; identical programs across processes hit the disk cache).
 
-    Dir from ``JAX_COMPILATION_CACHE_DIR`` (empty value = disabled),
-    default ``~/.cache/seist_tpu_xla``. Best-effort: failures never block
-    a run. Shared by the CLI (cli.main_worker), bench.py, and tests.
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading of it is
+    the whole story (an empty value is JAX's "no cache") and this function
+    sets no directory. Otherwise the cache goes to
+    ``DEFAULT_COMPILE_CACHE_DIR``. The one place every entry point (CLI,
+    server, bench.py, tools, tests) turns the cache on.
     """
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "seist_tpu_xla"),
-    )
-    if not cache_dir:
-        return  # explicit opt-out
-    try:
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
         jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            int(min_compile_seconds),
+            "jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR
         )
-    except Exception as e:  # noqa: BLE001 - cache is best-effort
-        if verbose:
-            import sys
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", int(min_compile_seconds)
+    )
+    if verbose:
+        import sys
 
-            print(f"compilation cache unavailable: {e!r}", file=sys.stderr)
+        print(
+            f"compilation cache: {jax.config.jax_compilation_cache_dir}",
+            file=sys.stderr,
+        )

@@ -5,7 +5,7 @@
   TensorBoard-loadable trace (XLA ops, fusion, HBM traffic) to the log dir.
 * :func:`device_memory_stats` — per-device HBM usage snapshot.
 * :class:`ThroughputMeter` — waveforms/sec with warmup skip, the number
-  BASELINE.md's north-star metric is quoted in.
+  BASELINE.json's north-star metric is quoted in.
 """
 
 from __future__ import annotations

@@ -3,6 +3,7 @@ atomic finalize, overwrite protection, and full-resume-state round-trips
 (the tentpole of the fault-tolerance layer; docs/FAULT_TOLERANCE.md)."""
 
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -242,6 +243,14 @@ def test_interrupted_save_layout_is_ignored_and_swept(tmp_path, trained_state):
         f.write("partial write")
     mgr2 = TrainCheckpointManager(root, keep_last=3)
     assert mgr2.latest_step() == 5
+    # orbax 0.11.32 sweeps on a background thread: CheckpointManager's
+    # constructor wraps cleanup_temporary_paths in a futures.CommitFuture
+    # (a started thread) that only the next save() awaits
+    # (_maybe_await_cleanup_tmp_directory), so the debris is gone shortly
+    # after the open, not necessarily by its return.
+    deadline = time.monotonic() + 30
+    while os.path.exists(fake_tmp) and time.monotonic() < deadline:
+        time.sleep(0.05)
     assert not os.path.exists(fake_tmp), "tmp debris must be swept on open"
     mgr2.close()
 

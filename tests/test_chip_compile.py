@@ -1,0 +1,182 @@
+"""Ask the chip's compiler before the chip: the attention kernels of the
+main path, at the real widths of ``seist_l_dpk`` (3 channels x 8192 samples,
+batch 32), compiled in this process for a DESCRIBED TPU v5e — no chip
+attached, nothing runs. What Mosaic/XLA:TPU refuse here (a slice off the
+tiling, too much VMEM, a program over 16 GB) they refuse on the chip too, so
+these guard every later PR at no chip time. A pass is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at import,
+never in conftest.py, never autouse, never in a child process): only one
+process may load the TPU library, and under xdist every worker imports this
+file. The persistent compile cache is turned off around the compiles — an
+executable compiled for a described chip cannot be read back without one.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from seist_tpu.ops import pallas_attention as pa
+
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+# Folded attention shapes of seist_l_dpk at 8192 samples, batch 32, heads 3
+# (one per stage): (L, M, H*E) with q (32, L, H*E) and k/v (32, M, H*E).
+BATCH = 32
+HEADS = 3
+SHAPES = [(1024, 128, 24), (512, 128, 24), (256, 128, 48), (128, 128, 96)]
+SHAPE_IDS = [f"L{l}-M{m}-HE{he}" for l, m, he in SHAPES]
+DTYPES = [jnp.float32, jnp.bfloat16]
+DTYPE_IDS = ["fp32", "bf16"]
+RATES = [0.0, 0.3]  # eval, and the attention dropout seist_l_dpk trains with
+RATE_IDS = ["nodrop", "drop0.3"]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _structs(l, m, he, dtype, sharding):
+    q = jax.ShapeDtypeStruct((BATCH, l, he), dtype, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((BATCH, m, he), dtype, sharding=sharding)
+    # (dropout seed, batch offset of the dropout counter)
+    seed = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=sharding)
+    return q, kv, seed
+
+
+def _compile(fn, *structs):
+    compiled = jax.jit(fn).lower(*structs).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the Pallas kernel is not in the program"
+    mem = compiled.memory_analysis()
+    total = (
+        mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    )
+    assert total < HBM_BYTES
+    return compiled
+
+
+@pytest.mark.parametrize("rate", RATES, ids=RATE_IDS)
+@pytest.mark.parametrize("dtype", DTYPES, ids=DTYPE_IDS)
+@pytest.mark.parametrize("shape", SHAPES, ids=SHAPE_IDS)
+def test_forward_kernel_compiles_for_v5e(
+    one_chip, no_compile_cache, shape, dtype, rate
+):
+    l, m, he = shape
+    q, kv, seed = _structs(l, m, he, dtype, one_chip)
+    scale = (he // HEADS) ** -0.5
+
+    def fwd(q, k, v, seed):
+        return pa._fused(q, k, v, seed, scale, rate, HEADS, False)
+
+    _compile(fwd, q, kv, kv, seed)
+
+
+@pytest.mark.parametrize("rate", RATES, ids=RATE_IDS)
+@pytest.mark.parametrize("dtype", DTYPES, ids=DTYPE_IDS)
+@pytest.mark.parametrize("shape", SHAPES, ids=SHAPE_IDS)
+def test_backward_kernel_compiles_for_v5e(
+    one_chip, no_compile_cache, shape, dtype, rate
+):
+    l, m, he = shape
+    q, kv, seed = _structs(l, m, he, dtype, one_chip)
+    scale = (he // HEADS) ** -0.5
+
+    def bwd(q, k, v, seed, g):
+        dq, dk, dv, _ = pa._fused_bwd(
+            scale, rate, HEADS, False, (q, k, v, seed), g
+        )
+        return dq, dk, dv
+
+    _compile(bwd, q, kv, kv, seed, q)
+
+
+def test_public_api_takes_the_kernel_when_the_backend_is_tpu(
+    one_chip, no_compile_cache, monkeypatch
+):
+    # The dispatch asks jax.default_backend(), which sees the CPU during
+    # such a compile: steer it from the test. 4-D (N, L, H, E) in, the
+    # model's own call, gradient included (forward + backward kernels).
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    l, m, he = SHAPES[0]
+    e = he // HEADS
+    q = jax.ShapeDtypeStruct((BATCH, l, HEADS, e), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((BATCH, m, HEADS, e), jnp.bfloat16, sharding=one_chip)
+    seed = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, seed):
+        o = pa.fused_pooled_attention(
+            q, k, v, dropout_rate=0.3, dropout_seed=seed
+        )
+        return (o.astype(jnp.float32) ** 2).sum()  # the output is needed
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seed)
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_data_parallel_kernel_compiles_for_four_v5e_chips(
+    topo, no_compile_cache, monkeypatch
+):
+    # Mosaic kernels cannot be partitioned automatically: under a
+    # data-parallel mesh the public API must shard_map the kernel over the
+    # batch rows, or XLA refuses the whole train step on four chips.
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from seist_tpu.parallel import mesh as mesh_lib
+
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    mesh = mesh_lib.make_mesh(data=4, devices=topo.devices)
+    rows = NamedSharding(mesh, P("data"))
+    l, m, he = SHAPES[0]
+    e = he // HEADS
+    q = jax.ShapeDtypeStruct((BATCH, l, HEADS, e), jnp.bfloat16, sharding=rows)
+    kv = jax.ShapeDtypeStruct((BATCH, m, HEADS, e), jnp.bfloat16, sharding=rows)
+    seed = jax.ShapeDtypeStruct(
+        (1,), jnp.int32, sharding=NamedSharding(mesh, P())
+    )
+
+    def loss(q, k, v, seed):
+        o = pa.fused_pooled_attention(
+            q, k, v, dropout_rate=0.3, dropout_seed=seed
+        )
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    with mesh_lib.use_mesh(mesh):
+        compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seed)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    # each chip's kernel sees its quarter of the batch, not all of it
+    assert f"bf16[{BATCH // 4},{l},{he}]" in text
+    assert f"bf16[{BATCH},{l},{he}]" not in text

@@ -83,7 +83,7 @@ class TestNoScatterBackward:
     """HLO regression locks for the round-2 lowering work: the backward
     passes of the conv lowerings must not contain scatter ops (XLA lowers
     the transpose of a strided slice to scatter-adds — the pathology the
-    phase-split and composed lowerings exist to remove; BASELINE.md)."""
+    phase-split and composed lowerings exist to remove)."""
 
     def _grad_hlo(self, fn, *args):
         g = jax.jit(jax.grad(fn))

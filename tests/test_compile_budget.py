@@ -36,21 +36,6 @@ L = 256
 BATCH = 4
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _no_persistent_cache():
-    """Opt this module out of the persistent XLA compile cache: on jax
-    0.4.37 CPU, executables DESERIALIZED from the disk cache intermittently
-    corrupt donated outputs in unsynchronized donated step chains
-    (state.step reads back float bits, ~20-40% of runs — see the ROADMAP
-    open item; reproduced with zero jaxlint code). These tests assert on
-    state after exactly such chains, so they must run on fresh-compiled
-    executables, whose aliasing is correct."""
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    yield
-    jax.config.update("jax_compilation_cache_dir", prev)
-
-
 def _setup():
     model = api.create_model("phasenet", in_samples=L)
     variables = api.init_variables(model, in_samples=L, batch_size=BATCH)
@@ -76,16 +61,7 @@ def test_train_step_steady_state_never_recompiles(rng):
     regression); the guarded property is steady state: once warm, steps
     of identical shape must trace exactly zero times."""
     state, spec, loss_fn = _setup()
-    # donate_state=False: the guarded property here is the COMPILE
-    # count, which donation cannot change — while ANY donated chain on
-    # jax 0.4.37 CPU is exposed to the open use-after-reuse hazard
-    # (ROADMAP): PR 5 saw state.step read float bits once on a
-    # fresh-compiled UNsynced chain, and PR 6's tier-1 caught it on a
-    # fresh-compiled PER-STEP-SYNCED chain (two reads of the same Array
-    # differed), so neither the compile cache nor missing sync is
-    # necessary. The donated-chain repro lives in
-    # tests/test_donation_cache.py; this test stays about retraces.
-    step = jit_step(make_train_step(spec, loss_fn), donate_state=False)
+    step = jit_step(make_train_step(spec, loss_fn))
     key = jax.random.PRNGKey(0)
     x, y = _batch(rng)
     state, loss, _ = step(state, x, y, key)  # warm-up compile
@@ -112,15 +88,7 @@ def test_budget_fails_when_step_is_made_to_retrace(rng):
     x, y = _batch(rng)
     with CompileBudget() as budget:
         for _ in range(2):
-            # donate_state=False, like the steady-state test above: this
-            # file asserts COMPILE counts only, and a donated chain
-            # through freshly re-jitted executables is the most exposed
-            # shape of the open jax-0.4.37-CPU use-after-reuse hazard
-            # (ROADMAP) — it segfaulted a tier-1 run in PR 6. Donation
-            # coverage lives in tests/test_donation_cache.py.
-            step = jit_step(
-                make_train_step(spec, loss_fn), donate_state=False
-            )  # fresh closure
+            step = jit_step(make_train_step(spec, loss_fn))  # fresh closure
             state, loss, _ = step(state, x, y, key)
         jax.block_until_ready((state, loss))
     assert budget.retraces("train_step"), "expected an identical-shape retrace"

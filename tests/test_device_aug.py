@@ -685,6 +685,35 @@ class TestFallbackSelection:
         assert da.hbm_budget_bytes(2.0) == 2 << 30
         assert da.hbm_budget_bytes(0.0) > 0
 
+    @pytest.mark.parametrize("stats", [None, {}, {"bytes_limit": 0}])
+    def test_hbm_budget_accelerator_without_limit_raises(
+        self, monkeypatch, stats
+    ):
+        # An accelerator that reports no memory limit is an error, never
+        # an assumed budget.
+        class _Dev:
+            platform = "tpu"
+            device_kind = "TPU v5 lite"
+
+            def memory_stats(self):
+                return stats
+
+        monkeypatch.setattr(da.jax, "local_devices", lambda: [_Dev()])
+        with pytest.raises(RuntimeError, match="no memory limit"):
+            da.hbm_budget_bytes(0.0)
+        assert da.hbm_budget_bytes(1.0) == 1 << 30  # explicit still wins
+
+    def test_hbm_budget_accelerator_half_of_limit(self, monkeypatch):
+        class _Dev:
+            platform = "tpu"
+            device_kind = "TPU v5 lite"
+
+            def memory_stats(self):
+                return {"bytes_limit": 16 << 30}
+
+        monkeypatch.setattr(da.jax, "local_devices", lambda: [_Dev()])
+        assert da.hbm_budget_bytes(0.0) == 8 << 30
+
     def test_store_estimate_close_to_actual(self):
         sds = pl.from_task_spec(
             taskspec.get_task_spec("phasenet"), "synthetic", "train",

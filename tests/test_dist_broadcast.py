@@ -1,13 +1,11 @@
-"""Direct tests for parallel/dist.broadcast_object's two transports.
+"""Direct tests for parallel/dist.broadcast_object.
 
-PR 7 moved the primary transport to the coordination-service KV store
-(jaxlib 0.4.37's gloo allreduce corrupts back-to-back differently-shaped
-broadcasts on CPU) but kept the legacy two-phase collective as the
-fallback for runtimes without the private client API — and only the KV
-path was exercised (by test_multihost's real worker processes). These
-units pin BOTH paths' semantics process-locally with fake transports, so
-a regression in either shows up in the smoke lane instead of only on a
-multi-host launch."""
+The transport is the coordination-service KV store (host-side control data
+does not ride device collectives). test_multihost's real worker processes
+exercise it end to end; these units pin its semantics process-locally with
+a fake client, and pin the private client API it relies on against the
+installed jax, so a regression shows up in the smoke lane instead of only
+on a multi-host launch."""
 
 import pickle
 
@@ -99,73 +97,31 @@ def test_kv_path_sequences_successive_broadcasts(monkeypatch):
     ]
 
 
-class _FakeCollective:
-    """Stand-in for multihost_utils.broadcast_one_to_all: echoes rank 0's
-    value. For rank 0 that is the argument itself; for other ranks the
-    test provides what rank 0 'sent' for the payload phase."""
+@pytest.mark.parametrize(
+    "method",
+    [
+        "key_value_set_bytes",
+        "blocking_key_value_get_bytes",
+        "wait_at_barrier",
+        "key_value_delete",
+    ],
+)
+def test_installed_jax_client_has_the_methods_used(method):
+    """broadcast_object leans on a private client; the installed jax must
+    still provide each method it calls."""
+    from jax._src import distributed
+    from jax._src.lib import _jax
 
-    def __init__(self, rank0_payload=None):
-        self.calls = []
-        self._rank0_payload = rank0_payload
-
-    def __call__(self, value):
-        self.calls.append(np.asarray(value).copy())
-        arr = np.asarray(value)
-        if self._rank0_payload is None:
-            return arr  # rank 0: input IS the broadcast value
-        if arr.ndim == 0:  # length phase
-            return np.int64(self._rank0_payload.size)
-        return self._rank0_payload  # buffer phase
-
-
-def test_legacy_collective_fallback_rank0(monkeypatch):
-    """No coordination client -> the two-phase length+buffer collective."""
-    from jax.experimental import multihost_utils
-
-    _fake_multiprocess(monkeypatch, index=0)
-    monkeypatch.setattr(dist, "_coordination_client", lambda: None)
-    fake = _FakeCollective()
-    monkeypatch.setattr(multihost_utils, "broadcast_one_to_all", fake)
-    obj = {"resume": True, "epoch": 3}
-    assert dist.broadcast_object(obj) == obj
-    # exactly two collectives: scalar length, then the uint8 pickle buffer
-    assert len(fake.calls) == 2
-    assert fake.calls[0].ndim == 0
-    assert fake.calls[1].dtype == np.uint8
-    assert int(fake.calls[0]) == fake.calls[1].size
+    assert hasattr(distributed.global_state, "client")
+    assert callable(getattr(_jax.DistributedRuntimeClient, method))
 
 
-def test_legacy_collective_fallback_rank1(monkeypatch):
-    """A non-zero rank must reconstruct the object purely from what the
-    collective returns (its own buffer contribution is zeros)."""
-    from jax.experimental import multihost_utils
-
-    _fake_multiprocess(monkeypatch, index=1)
-    monkeypatch.setattr(dist, "_coordination_client", lambda: None)
-    obj = ("ckpt", 120, [1.5, 2.5])
-    payload = np.frombuffer(pickle.dumps(obj), dtype=np.uint8)
-    fake = _FakeCollective(rank0_payload=payload)
-    monkeypatch.setattr(multihost_utils, "broadcast_one_to_all", fake)
-    assert dist.broadcast_object(None) == obj
-    # rank 1 contributed a zero buffer of the broadcast length — the
-    # result came from the collective, not local state
-    assert len(fake.calls) == 2
-    assert not fake.calls[1].any()
-
-
-def test_legacy_fallback_engages_when_client_api_gone(monkeypatch):
-    """_coordination_client returning None (private API changed/removed)
-    must route to the fallback rather than crash."""
-    from jax.experimental import multihost_utils
+def test_multiprocess_without_client_raises(monkeypatch):
+    """No second transport: a multi-process run whose coordination service
+    was never initialized is an error, not a silent collective fallback."""
+    from jax._src import distributed
 
     _fake_multiprocess(monkeypatch, index=0)
-
-    def _broken_client():
-        raise AssertionError("must go through dist._coordination_client")
-
-    # simulate the private-API import failing inside the helper
-    monkeypatch.setattr(dist, "_coordination_client", lambda: None)
-    fake = _FakeCollective()
-    monkeypatch.setattr(multihost_utils, "broadcast_one_to_all", fake)
-    assert dist.broadcast_object([1, 2]) == [1, 2]
-    assert len(fake.calls) == 2
+    monkeypatch.setattr(distributed.global_state, "client", None)
+    with pytest.raises(RuntimeError, match="coordination service"):
+        dist.broadcast_object({"x": 1})

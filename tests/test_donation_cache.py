@@ -1,14 +1,12 @@
-"""Donation/compile-cache correctness gate (train/step.py resolve_donation).
+"""State donation is kept, persistent compile cache or not.
 
-The ROADMAP open item from the PR 4 audit lane: on jax 0.4.37 CPU, an
-executable DESERIALIZED from the persistent XLA compile cache
-intermittently corrupts donated outputs in unsynchronized donated step
-chains (state.step reads back float bits; repeated reads differ). The
-mitigation gates donation out of exactly that configuration — disk cache
-active AND CPU backend — so cached executables never carry input/output
-aliasing. These tests pin the gate's decision table and run the original
-repro chain under the previously-hazardous config, where it is now
-deterministic instead of a 20-40% coin flip.
+An older jax (0.4.37, CPU) intermittently corrupted donated outputs of an
+executable DESERIALIZED from the persistent compile cache, and the train
+step used to drop donation in that configuration. On the installed jax the
+hazard does not reproduce (80 deserialized chains over 16 processes, single
+device and an 8-device mesh, none bad), so the gate is gone: these tests pin
+that every shipped jit wrapper donates the state under the cache, and keep
+the original repro chain as a regression test.
 """
 
 import jax
@@ -23,7 +21,6 @@ from seist_tpu.train import (
     create_train_state,
     jit_step,
     make_train_step,
-    resolve_donation,
 )
 
 seist_tpu.load_all()
@@ -33,11 +30,10 @@ BATCH = 4
 
 
 @pytest.fixture
-def warm_cache_dir(tmp_path, monkeypatch):
+def warm_cache_dir(tmp_path):
     """A fresh persistent compile cache with no compile-time threshold, so
     the test's small programs are serialized (and deserialized on a
     re-wrap) exactly like production-sized ones."""
-    monkeypatch.delenv("SEIST_DONATE_WITH_CACHE", raising=False)
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     cache = str(tmp_path / "xla_cache")
@@ -69,41 +65,57 @@ def _batch(rng):
     return jnp.asarray(x), jnp.asarray(y)
 
 
-# ------------------------------------------------------------ decision table
-def test_gate_drops_donation_with_cache_on_cpu(warm_cache_dir):
+# ------------------------------------------------------- donation is kept
+def _donated_leaves(jitted, *args) -> int:
+    """Leaves of the lowered program that carry a donation marker."""
+    text = jitted.__wrapped__.lower(*args).as_text()  # under the span wrap
+    return text.count("tf.aliasing_output") + text.count("jax.buffer_donor")
+
+
+@pytest.mark.parametrize("meshed", [False, True], ids=["plain", "mesh"])
+def test_jit_step_donates_state_with_cache_on_cpu(warm_cache_dir, rng, meshed):
+    from seist_tpu.parallel.mesh import make_mesh
+
     assert jax.default_backend() == "cpu"
-    assert resolve_donation((0,)) == ()
+    assert jax.config.jax_compilation_cache_dir == warm_cache_dir
+    state, spec, loss_fn = _setup()
+    x, y = _batch(rng)
+    mesh = make_mesh(data=len(jax.devices())) if meshed else None
+    if meshed:
+        reps = -(-len(jax.devices()) // BATCH)
+        x, y = (np.tile(np.asarray(a), (reps, 1, 1)) for a in (x, y))
+    step = jit_step(make_train_step(spec, loss_fn), mesh)
+    n = _donated_leaves(step, state, x, y, jax.random.PRNGKey(0))
+    # every params + opt-state leaf has a same-shaped output to reuse
+    assert n >= len(jax.tree.leaves(state.params))
 
 
-def test_gate_keeps_donation_without_cache(monkeypatch):
-    monkeypatch.delenv("SEIST_DONATE_WITH_CACHE", raising=False)
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        assert resolve_donation((0,)) == (0,)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+def test_donate_state_false_donates_nothing(warm_cache_dir, rng):
+    state, spec, loss_fn = _setup()
+    x, y = _batch(rng)
+    step = jit_step(make_train_step(spec, loss_fn), donate_state=False)
+    assert _donated_leaves(step, state, x, y, jax.random.PRNGKey(0)) == 0
 
 
-def test_gate_env_overrides(warm_cache_dir, monkeypatch):
-    monkeypatch.setenv("SEIST_DONATE_WITH_CACHE", "1")
-    assert resolve_donation((0,)) == (0,)
-    monkeypatch.setenv("SEIST_DONATE_WITH_CACHE", "0")
-    assert resolve_donation((0,)) == ()
-
-
-def test_gate_passes_empty_through(warm_cache_dir):
-    assert resolve_donation(()) == ()
+def test_donated_state_is_consumed(warm_cache_dir, rng):
+    """The runtime honours the donation: the old state's buffers are gone
+    after the step (the memory saving donation exists for)."""
+    state, spec, loss_fn = _setup()
+    x, y = _batch(rng)
+    step = jit_step(make_train_step(spec, loss_fn))
+    old_leaf = jax.tree.leaves(state.params)[0]
+    new_state, loss, _ = step(state, x, y, jax.random.PRNGKey(0))
+    jax.block_until_ready((new_state, loss))
+    assert old_leaf.is_deleted()
+    assert not jax.tree.leaves(new_state.params)[0].is_deleted()
 
 
 # ------------------------------------------------------------- repro mirror
 def test_deserialized_step_chain_is_correct(warm_cache_dir, rng):
-    """The test_compile_budget repro, run WITH the persistent cache (the
-    config that module must opt out of): warm the disk cache, re-wrap the
-    step so the next call DESERIALIZES the executable, then run 4
-    back-to-back unsynchronized steps. Under the donation gate the
-    deserialized executable carries no aliasing, so the chain's state is
-    exact every time — previously this flaked in 20-40% of processes."""
+    """The old hazard's repro, with donation ON: warm the disk cache,
+    re-wrap the step so the next call DESERIALIZES the executable, then
+    run 4 back-to-back unsynchronized donated steps. The chain's state
+    must be exact (on jax 0.4.37 this flaked in 20-40% of processes)."""
     state, spec, loss_fn = _setup()
     key = jax.random.PRNGKey(0)
     x, y = _batch(rng)

@@ -114,7 +114,7 @@ def test_pretrained_forward_parity(ckpt, torch_models):
 # DropPath scaling, interpolate vjp...). These tests push ONE identical
 # batch through the torch reference (its own loss, ref train.py:108-111)
 # and through our flax step with converted weights, then compare loss and
-# per-leaf gradients (VERDICT r1 #6).
+# per-leaf gradients.
 
 L_GRAD = 1024
 # eqtransformer exercises the scan-BiLSTM + additive-attention backward —
@@ -126,7 +126,7 @@ L_GRAD = 1024
 # its eigen feature branch uses eigh on the symmetric covariance where the
 # reference uses no-grad general eig — eigenvalue ordering/eigenvector sign
 # conventions differ, so forward activations (and hence all grads) diverge by
-# design (BASELINE.md design notes; the branch is no-grad in BOTH frameworks).
+# design (the branch is no-grad in BOTH frameworks).
 GRAD_MODELS = [
     "phasenet",
     "seist_s_dpk",

@@ -1,6 +1,6 @@
 """Model-zoo tests: registration surface, output shapes, parameter parity.
 
-Parameter parity: reference state-dict totals (BASELINE.md, measured from
+Parameter parity: reference state-dict totals (measured from
 pretrained/*.pth) equal our params + batch_stats + one `num_batches_tracked`
 scalar per BN layer. Counting uses jax.eval_shape (no compute) so the suite
 stays fast.
@@ -46,7 +46,7 @@ def _count_with_bn(model, in_samples, in_channels):
 @pytest.mark.parametrize(
     "name,ref_total",
     [
-        # Reference state-dict numels incl. BN buffers (BASELINE.md).
+        # Reference state-dict numels incl. BN buffers.
         ("seist_s_dpk", 128_981),
         ("seist_m_dpk", 387_620),
         ("seist_l_dpk", 670_681),

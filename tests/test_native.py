@@ -1,32 +1,32 @@
 """Native wavekit kernels vs the numpy reference path.
 
-Builds libwavekit.so on demand (g++ is in the image); skips if the build
-fails. Parity uses fp32-accumulation tolerances.
+seist_tpu.native builds its library from wavekit.cpp on import (g++ is in
+the image); a failed build is an error. Parity uses fp32-accumulation
+tolerances.
 """
 
 import importlib
 import os
-import subprocess
 
 import numpy as np
 import pytest
 
-REPO = os.path.join(os.path.dirname(__file__), "..")
-
 
 @pytest.fixture(scope="module")
 def native():
-    lib = os.path.join(REPO, "seist_tpu", "native", "libwavekit.so")
-    if not os.path.exists(lib):
-        r = subprocess.run(["make", "native"], cwd=REPO, capture_output=True)
-        if r.returncode != 0:
-            pytest.skip(f"native build failed: {r.stderr.decode()[:200]}")
     import seist_tpu.native as native_mod
 
-    native_mod = importlib.reload(native_mod)
-    if not native_mod.available():
-        pytest.skip("libwavekit.so not loadable")
+    assert native_mod.available()
     return native_mod
+
+
+def test_library_is_built_from_this_source(native):
+    # The loaded library is named after wavekit.cpp's hash and sits next
+    # to it: a foreign .so left in the checkout can never be picked up.
+    path = native.lib_path()
+    assert os.path.exists(path)
+    assert os.path.dirname(path) == os.path.dirname(native.__file__)
+    assert native._lib._name == path
 
 
 @pytest.mark.parametrize("mode", ["std", "max", ""])

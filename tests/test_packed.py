@@ -5,7 +5,7 @@ SURVEY §7's offline input-pipeline mitigation: tools/pack_dataset.py
 repacks an HDF5 dataset into contiguous binary shards + columnar index;
 the ``packed`` dataset then serves the identical Event dicts through a
 memmap slice instead of h5py's per-sample group walk (the measured ~30%
-read tax, BASELINE.md §Input pipeline).
+read tax).
 """
 
 import json

@@ -4,7 +4,7 @@ Each dataset's production read path (``_load_meta_data`` +
 ``_load_event_data``: pandas dtype maps, h5py layouts, key quirks) is
 exercised end to end — fixture files on disk -> reader -> preprocessor ->
 Loader batch -> one jitted train step — so a malformed dtype/column
-assumption dies here, not at step 0 of a real run (VERDICT r1 missing #2).
+assumption dies here, not at step 0 of a real run.
 
 Formats reproduced (ref anchors):
 * DiTing: 28 CSV (+HDF5) parts, ``earthquake/<key>`` datasets of shape

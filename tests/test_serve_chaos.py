@@ -623,7 +623,7 @@ def test_overload_sheds_batch_tier_protects_alert_slo(tmp_path):
         batch.join(timeout=300)
         rc_alert, res_alert = results["alert"]
         rc_batch, res_batch = results["batch"]
-        # Low tier: actually shed, with the shed taxonomy code (not 429).
+        # Low tier: actually shed, with the shed error-class code (not 429).
         assert res_batch["by_error_code"].get("shed", 0) > 0, res_batch
         assert res_batch["by_status"].get("503", 0) > 0, res_batch
         # High tier: NEVER shed, and p99 inside the SLO (the gate's rc).

@@ -229,7 +229,7 @@ def test_build_optimizer_applies_scoped_l1():
 )
 def test_bf16_train_step_tracks_fp32(rng, model_name):
     """bf16 compute dtype: loss close to fp32, params/stats stay fp32, and
-    several steps still reduce the loss (VERDICT r1 #4)."""
+    several steps still reduce the loss."""
     x, y = _fake_dpk_batch(rng)
     key = jax.random.PRNGKey(0)
 
@@ -345,6 +345,65 @@ def test_multi_step_sharded_matches_single_device(rng):
         jax.tree_util.tree_leaves(s1.params), jax.tree_util.tree_leaves(s2.params)
     ):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6)
+
+
+def test_worker_runs_the_attention_kernel_on_a_data_mesh(tmp_path, monkeypatch):
+    """The trainer's own call order on several chips: init at batch 1, then
+    the jitted steps on a data=4 mesh. On the TPU backend the attention is
+    the Pallas kernel (driven here through the interpreter), which has to
+    run per batch shard in the steps and on the whole (batch-1) input at
+    init — the mesh a kernel call sees is the one its jit wrapper names,
+    never a process-wide one."""
+    from seist_tpu.ops import pallas_attention as pa
+    from seist_tpu.train import worker
+    from seist_tpu.utils.logger import logger
+    from tests.test_fault_tolerance_e2e import make_args
+
+    seen = []
+    kernel = pa._fused
+
+    def interpreted(q3, k3, v3, seed, scale, rate, heads, interpret):
+        seen.append(q3.shape[0])
+        return kernel(q3, k3, v3, seed, scale, rate, heads, True)
+
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "_fused", interpreted)
+    monkeypatch.setattr(
+        worker.mesh_lib, "make_mesh",
+        lambda seq=1, _make=worker.mesh_lib.make_mesh: _make(
+            data=4, seq=seq, devices=jax.devices()[:4]
+        ),
+    )
+    logger.set_logdir(str(tmp_path))
+    ckpt = worker.train_worker(make_args(model_name="seist_s_dpk", batch_size=8))
+    assert ckpt and os.path.isdir(ckpt)
+    losses = np.load(os.path.join(str(tmp_path), "train_losses.npy"))
+    assert losses.size == 4 and np.isfinite(losses).all()
+    # batch 1 at init (no mesh), 8 / 4 = 2 rows per device in the steps
+    assert set(seen) == {1, 2}, sorted(set(seen))
+
+
+@pytest.mark.parametrize("seq", [1, 2])
+def test_jit_step_scopes_its_mesh_around_the_trace(seq):
+    """The model's mesh-aware paths follow the mesh jit_step was given, for
+    the trace of that step only: a seq-sharded mesh routes SeisT attention
+    through the ring (collective permutes in the lowered step), and no mesh
+    is left active once the trace is done."""
+    from seist_tpu.parallel import mesh as mesh_lib
+
+    name, n = "seist_s_dpk", 512
+    model = api.create_model(name, in_samples=n)
+    variables = api.init_variables(model, in_samples=n)
+    state = create_train_state(model, variables, build_optimizer("sgd", 1e-2))
+    step_fn = make_train_step(taskspec.get_task_spec(name), taskspec.make_loss(name))
+    mesh = make_mesh(seq=seq, devices=jax.devices()[:4])
+    x = np.zeros((4, n, 3), np.float32)
+    text = (
+        jit_step(step_fn, mesh).__wrapped__
+        .lower(replicate(mesh, state), x, x, jax.random.PRNGKey(0)).as_text()
+    )
+    assert ("collective_permute" in text) == (seq > 1)
+    assert mesh_lib.active_mesh() is None
 
 
 def test_mesh_axes():

@@ -1,4 +1,4 @@
-"""Training-dynamics parity: torch reference vs seist_tpu (VERDICT r3 #5).
+"""Training-dynamics parity: torch reference vs seist_tpu.
 
 Both sides train phasenet and seist_s_dpk (all drop rates zeroed) from the
 IDENTICAL initialization on
@@ -56,7 +56,7 @@ def _run_side(side: str, model: str, tmp: str) -> dict:
 # phasenet: plain conv+BN+CE dynamics. seist_s_dpk: the flagship family —
 # stems, grouped convs, pooled attention, DropPath residuals, BCE. Both
 # measured 2026-07-31: max train-loss drift 1.0e-4 / 1.5e-5 respectively.
-# seist_s_dpk_droppath: the dropout-ON lane (VERDICT r4 #6) — stochastic
+# seist_s_dpk_droppath: the dropout-ON lane — stochastic
 # depth at 0.2 with per-sample uniforms INJECTED identically on both
 # sides; measured 2026-08-01: max train-loss drift 8.4e-6 over 48 steps,
 # 33 DropPath calls consumed per forward on each side.
@@ -170,7 +170,7 @@ def test_val_loss_trajectory_matches(trajectories):
 
 
 def test_val_metric_trajectory_matches(trajectories):
-    # VERDICT r4 #6 (metric half): per-epoch P/S pick F1 on the val set,
+    # Metric half: per-epoch P/S pick F1 on the val set,
     # scored by the ONE shared numpy scorer on each side's eval-mode
     # probabilities. A dynamics drift that losses average away would
     # move individual picks across the threshold/tolerance and split the
@@ -210,7 +210,7 @@ def test_val_metric_trajectory_matches(trajectories):
 
 
 def test_droppath_lane_consumed_identical_masks(trajectories):
-    # Dropout-ON lane (VERDICT r4 #6): both frameworks must consume the
+    # Dropout-ON lane: both frameworks must consume the
     # SAME number of injected DropPath rows per forward (call-order
     # symmetry), and — asserted by the trajectory tests above running on
     # this lane too — produce matching losses WITH stochastic depth

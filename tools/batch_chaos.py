@@ -48,6 +48,8 @@ import tempfile
 import time
 from typing import Any, Dict, List
 
+from tools.device_procs import refuse_shared_chip
+
 # repick_smoke geometry (warm XLA cache across lanes): 44 events over
 # 16-sample shards -> 3 shards == 3 work units, one per worker.
 N_EVENTS = 44
@@ -223,10 +225,7 @@ def main(argv=None) -> int:
                     help="keep the scratch directory for inspection")
     args = ap.parse_args(argv)
 
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
-
+    refuse_shared_chip(3, "batch_chaos")  # the 3-worker chaos fleet
     t0 = time.monotonic()
     root = tempfile.mkdtemp(prefix="batch_chaos_")
     try:

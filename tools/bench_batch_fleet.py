@@ -16,11 +16,15 @@ Two gates, one hard and one hardware-conditional:
 * **byte-identity (hard)** — sha256(catalog.jsonl) of the 3-worker
   fleet EQUALS the 1-worker run's. Fleet concurrency may never cost
   bytes; a scaling number for a diverging catalog would be meaningless.
-* **scaling (>= --min-speedup, chips only)** — on a single-core CI host
-  3 compute-bound workers just time-slice one CPU, so the gate is
-  recorded as ``pending`` (the quant_smoke ``tpu_run: pending`` idiom)
-  and the measured speedup is logged, not enforced. On a >= 3-core
-  host (or a real slice) it gates.
+* **scaling (>= --min-speedup, >= 3 cores only)** — on a single-core
+  host 3 compute-bound workers just time-slice one CPU, so the gate is
+  recorded as ``pending`` and the measured speedup is logged, not
+  enforced. On a >= 3-core host it gates.
+
+Three worker processes each initialise a JAX device, and a chip belongs to
+one process at a time, so this lane runs on the CPU backend only
+(tools/device_procs.py refuses anything else); fleet scaling across chips
+is not measured.
 
 Writes the BENCH JSON (--out) and prints it. Exit 0 iff every
 applicable gate holds.
@@ -40,6 +44,7 @@ import time
 from typing import Any, Dict
 
 from tools.batch_chaos import BATCH, BPC, COMMIT, _pack, _repick_args
+from tools.device_procs import refuse_shared_chip
 
 _DEF_OUT = "BENCH_batch_fleet_r01.json"
 
@@ -97,11 +102,7 @@ def main(argv=None) -> int:
     ap.add_argument("--keep", action="store_true")
     args = ap.parse_args(argv)
 
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
-    import jax
-
+    refuse_shared_chip(3, "bench_batch_fleet")
     cores = os.cpu_count() or 1
     root = tempfile.mkdtemp(prefix="bench_batch_fleet_")
     try:
@@ -128,7 +129,7 @@ def main(argv=None) -> int:
             "scaling_gate": (
                 "enforced" if scaling_gated
                 else f"pending ({cores} core host: 3 compute-bound "
-                     "workers time-slice one CPU; chip run pending)"
+                     "workers time-slice one CPU)"
             ),
             "byte_identical": identical,
             "sha256": sha3,
@@ -142,8 +143,9 @@ def main(argv=None) -> int:
                 "batches_per_call": BPC, "commit_every": COMMIT,
                 "slow_ms": args.slow_ms, "host_cores": cores,
             },
-            "device": jax.devices()[0].platform,
-            "backend": jax.default_backend(),
+            # refuse_shared_chip pinned it: the only backend N worker
+            # processes can share
+            "backend": os.environ["JAX_PLATFORMS"],
             "measured_at": time.strftime(
                 "%Y-%m-%dT%H:%M:%SZ", time.gmtime()
             ),

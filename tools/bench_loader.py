@@ -59,7 +59,7 @@ def run() -> None:
     workers = int(os.environ.get("BENCH_WORKERS", os.cpu_count() or 1))
     # BENCH_PROCESSES > 0 routes the per-sample work through the process
     # pool (`--loader-processes` in the CLI) — the measured scaling knob
-    # for feeding a chip from a multi-core host (VERDICT r3 #7).
+    # for feeding a chip from a multi-core host.
     processes = int(os.environ.get("BENCH_PROCESSES", 0))
     device_wfs = float(os.environ.get("DEVICE_WFS", 4236.0))
 
@@ -70,7 +70,7 @@ def run() -> None:
     if dataset_name == "synthetic":
         ds_kw = {"num_events": batch * 4}
     elif dataset_name == "packed":
-        # Packed-shard repack of the diting_light fixture (VERDICT r4 #8).
+        # Packed-shard repack of the diting_light fixture.
         from tools.fixtures import ensure_packed_fixture
 
         data_dir = ensure_packed_fixture(max(batch * 2, 512), in_samples)

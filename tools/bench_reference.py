@@ -1,6 +1,6 @@
 """Measure the torch reference's training throughput (host CPU).
 
-The reference repo publishes no benchmark numbers (BASELINE.md) and this
+The reference repo publishes no benchmark numbers and this
 environment has no GPU, so the comparison baseline for bench.py is the
 reference's own training step (forward + BCE loss + backward + Adam) timed on
 this host's CPU. The reference code is *imported* from /root/reference at

@@ -58,9 +58,6 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="BENCH_repick_r01.json")
     args = ap.parse_args(argv)
 
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     import jax
 
     import seist_tpu

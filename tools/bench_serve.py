@@ -319,13 +319,6 @@ def _http_client(url: str, timeout_ms: float):
 def main(argv: Optional[List[str]] = None) -> int:
     args = get_args(argv)
 
-    if not args.url:
-        # --url mode must run from jax-free front-tier boxes (the same
-        # constraint as serve/router.py): nothing below may import jax.
-        from seist_tpu.utils.platform import honor_jax_platforms
-
-        honor_jax_platforms()
-
     if args.stream_stations > 0:
         return _run_stream_bench(args)
 

@@ -28,7 +28,7 @@ sys.path.insert(
 
 
 def attribute_collectives(ops, param_shapes, batch: int, devices: int) -> dict:
-    """Bucket per-op collective payloads (VERDICT r3 #6 + advisor r4).
+    """Bucket per-op collective payloads.
 
     Gradient reductions are all-reduces of param-shaped tensors inside
     the backward pass (op_name carries XLA's "transpose(jvp(...))"
@@ -157,7 +157,7 @@ def main() -> None:
     total = sum(s["bytes"] for s in stats.values())
     n = args.devices
 
-    # Attribute the bytes (VERDICT r3 #6: make it self-evident which ops
+    # Attribute the bytes (make it self-evident which ops
     # carry the gradient bytes). Gradient reductions are all-reduces of
     # param-shaped tensors INSIDE the backward pass (op_name metadata
     # carries XLA's "transpose(jvp(...))" marker); BN cross-replica

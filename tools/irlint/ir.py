@@ -170,13 +170,17 @@ _MAIN_RE = re.compile(
 )
 _ARG_HEAD_RE = re.compile(r"%arg(?P<idx>\d+):\s*tensor<(?P<ty>[^>]*)>")
 _ALIAS_RE = re.compile(r"tf\.aliasing_output\s*=\s*(\d+)")
-_SHARD_RE = re.compile(r'mhlo\.sharding\s*=\s*"([^"]*)"')
+# Shardy (the sharding dialect jax lowers to): per-dimension axis lists,
+# e.g. ``sdy.sharding = #sdy.sharding<@mesh, [{"data"}, {}]>``.
+_SHARD_RE = re.compile(r"sdy\.sharding\s*=\s*#sdy\.sharding<@\w+,\s*(\[[^\]]*\])")
 
 
 def parse_main_args(stablehlo_text: str) -> List[Dict[str, Any]]:
     """Flat entry-arg records from a lowered module's ``@main`` signature:
     ``{"index", "type", "aliased_output": int|None, "buffer_donor": bool,
-    "sharding": str|None}``.
+    "sharding": str|None}`` — ``sharding`` is the Shardy per-dimension
+    axis list (``'[{"data"}, {}]'``); a dimension naming no axis is not
+    split.
 
     Donation shows up two ways depending on how the program was lowered:
     a plain jit emits ``tf.aliasing_output = N`` on every donated arg it
@@ -188,8 +192,8 @@ def parse_main_args(stablehlo_text: str) -> List[Dict[str, Any]]:
 
     Parsing splits on ``%argN:`` boundaries instead of matching the attr
     brace block — attribute values legally contain nested braces
-    (``mhlo.sharding = "{replicated}"``), which brace-matching regexes
-    silently truncate.
+    (``sdy.sharding = #sdy.sharding<@mesh, [{"data"}, {}]>``), which
+    brace-matching regexes silently truncate.
     """
     m = _MAIN_RE.search(stablehlo_text)
     if not m:
@@ -257,8 +261,7 @@ def donation_audit(
       LOWERING time (``tf.aliasing_output``, plain-jit lowerings);
     * ``deferred_leaves`` — donated buffers marked ``jax.buffer_donor``
       (sharded lowerings): donation accepted, the input->output pairing
-      happens inside XLA's compile — the exact stage where the
-      jax-0.4.37 deserialized-executable corruption lives (ROADMAP);
+      happens inside XLA's compile;
     * ``unaliased`` — declared-donated buffers carrying NEITHER marker:
       the lowering dropped them ("Some donated buffers were not
       usable"), so they free HBM only after the program finishes.
@@ -320,8 +323,8 @@ def sharding_audit(
     """Entry-arg sharding of a mesh-lowered program: for each declared
     DATA argument (expected batch-sharded), report whether the lowered
     module actually annotates it with a device split. ``replicated``
-    lists data-arg buffers lowered as ``{replicated}`` (or with no
-    sharding at all) — each one is a full copy of the global batch on
+    lists data-arg buffers whose dimensions name no mesh axis (or that
+    carry no sharding at all) — each one is a full copy of the global batch on
     every device. Args the lowering pruned (unused) are skipped."""
     args = parse_main_args(stablehlo_text)
     by_pos = {a["index"]: a for a in args}
@@ -346,7 +349,7 @@ def sharding_audit(
             continue
         total += 1
         s = a["sharding"]
-        if s is not None and "devices=" in s:
+        if s is not None and '"' in s:  # some dimension names an axis
             sharded += 1
         else:
             replicated.append(

@@ -87,22 +87,13 @@ def check_precision(prog: ProgramInfo) -> List[Finding]:
 _DONATE_HINT = (
     "a donated buffer the lowering could not alias frees HBM only after "
     "the program finishes — match the donated leaf's (shape, dtype) to an "
-    "output or drop it from donate_argnums; the runtime use-after-reuse "
-    "hazard itself is gated by train/step.py:resolve_donation"
+    "output or drop it from donate_argnums"
 )
 
 
 def check_donation(prog: ProgramInfo) -> List[Finding]:
     spec = prog.spec
-    if not spec.donate_intent:
-        return []
     if not spec.donate:
-        # resolve_donation gated donation out (hazard config): the lowered
-        # program legitimately carries no aliasing. Record, don't flag.
-        prog.report["donation"] = dict(
-            spec.notes, declared_argnums=list(spec.donate_intent),
-            aliased_leaves=0, donated_leaves=0,
-        )
         return []
     audit = ir.donation_audit(
         prog.stablehlo, spec.args, spec.donate, kept=prog.kept_var_idx

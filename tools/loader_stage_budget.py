@@ -1,7 +1,7 @@
 """Per-stage cost budget of the input pipeline, in ms per waveform.
 
 Times each loader stage in isolation on the real-format reader path
-(VERDICT r2 #6: "publish a per-stage cost breakdown that lets a reader
+("publish a per-stage cost breakdown that lets a reader
 verify the claim"):
 
   read      — dataset reader: h5py waveform read + metadata row
@@ -51,7 +51,7 @@ def main() -> None:
     if dataset_name == "synthetic":
         ds_kw = {"num_events": max(512, n)}
     elif dataset_name == "packed":
-        # The packed-shard repack of the SAME fixture (VERDICT r4 #8):
+        # The packed-shard repack of the SAME fixture:
         # read-stage delta vs diting_light is the measured h5py tax.
         from tools.fixtures import ensure_packed_fixture
 

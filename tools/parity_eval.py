@@ -1,7 +1,7 @@
 """End-to-end metric parity: torch reference vs this framework, same data.
 
 The accuracy half of the north-star ("P/S-pick F1 parity with the reference",
-BASELINE.md) cannot be run on real PNW/DiTing archives in this sandbox (no
+BASELINE.json) cannot be run on real PNW/DiTing archives in this sandbox (no
 datasets on disk, zero egress) — so this harness constructs the strongest
 available evidence: BOTH frameworks evaluate the SAME published reference
 weights on the SAME on-disk DiTing-light-format fixture through their FULL

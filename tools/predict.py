@@ -43,10 +43,6 @@ def main() -> None:
                     "with record length (4 per window span)")
     args = ap.parse_args()
 
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
-
     import numpy as np
     import pandas as pd
 

@@ -207,9 +207,6 @@ def main(argv=None) -> int:
                     "headline JSON here (BENCH_repick_r02.json)")
     args = ap.parse_args(argv)
 
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     import jax
 
     import seist_tpu

@@ -503,7 +503,9 @@ def run_driver(args) -> int:
     contract), then run the reduce."""
     from seist_tpu.obs.bus import monotonic
     from seist_tpu.train.checkpoint import PREEMPT_EXIT_CODE
+    from tools.device_procs import refuse_shared_chip
 
+    refuse_shared_chip(args.workers, "repick_archive --workers")
     t0 = monotonic()
     meta, cols = _archive_index(args.archive)
     units = _units_from_cols(cols)
@@ -546,9 +548,6 @@ def run_driver(args) -> int:
 
 def main(argv=None) -> int:
     args = get_args(argv)
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     import seist_tpu
     from seist_tpu.utils.misc import enable_compile_cache
 

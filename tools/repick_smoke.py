@@ -54,12 +54,12 @@ def _worker_cmd(archive: str, out: str, index: int):
 
 
 def main() -> int:
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     import seist_tpu
     from seist_tpu.data.packed import PackSource, pack_sources
+    from tools.device_procs import refuse_shared_chip
 
+    # this process runs the serial reference, then two workers at once
+    refuse_shared_chip(3, "repick_smoke")
     seist_tpu.load_all()
     t0 = time.monotonic()
     root = tempfile.mkdtemp(prefix="repick_smoke_")

@@ -193,9 +193,6 @@ def _journal_exercise(out: str) -> Dict[str, object]:
 
 
 def _child(args) -> int:
-    from seist_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     import seist_tpu
 
     seist_tpu.load_all()

@@ -69,6 +69,8 @@ from typing import Dict, List, Optional, Tuple
 _TOOLS = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(_TOOLS))
 
+from tools.device_procs import refuse_shared_chip  # noqa: E402 - stdlib-only
+
 # Keep in sync with seist_tpu.serve.server.PREEMPT_EXIT_CODE /
 # seist_tpu.train.checkpoint.PREEMPT_EXIT_CODE
 # (tests/test_serve_fleet.py pins all three together).
@@ -370,6 +372,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                  "python main.py serve ...)")
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
+    refuse_shared_chip(args.replicas, "supervise_fleet")
 
     from seist_tpu.obs import trace as obs_trace
     from seist_tpu.obs.bus import BUS

@@ -45,6 +45,7 @@ import sys
 import time
 from typing import Any, Dict, List, Optional
 
+from tools.device_procs import refuse_shared_chip
 from tools.repick_archive import _archive_index, _units_from_cols
 
 PREEMPT_EXIT_CODE = 75  # train.checkpoint contract (import-free: no jax here)
@@ -217,6 +218,7 @@ def main(argv=None) -> int:
     os.makedirs(args.lease_dir, exist_ok=True)
     log_dir = os.path.join(args.out, "logs")
     os.makedirs(log_dir, exist_ok=True)
+    refuse_shared_chip(args.workers, "supervise_repick")
     fault_env = _parse_fault_env(args.fault_env, args.workers)
 
     workers = [

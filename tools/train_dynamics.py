@@ -1,4 +1,4 @@
-"""Training-dynamics parity harness: torch reference vs seist_tpu (VERDICT r3 #5).
+"""Training-dynamics parity harness: torch reference vs seist_tpu.
 
 Forward/gradient parity (tools/parity.py) proves single-step math; this tool
 probes what those tests cannot see — BN-momentum convention, LR-schedule
@@ -25,7 +25,7 @@ seist_s_dpk_droppath (stochastic depth ON with the per-sample DropPath
 uniforms injected identically on both sides). The
 zero-drop lanes zero every drop rate because free-running dropout masks
 are framework-RNG-specific; the droppath lane instead shares the masks,
-closing that excluded axis (VERDICT r4 #6). Everything else under the
+closing that excluded axis. Everything else under the
 reference's CyclicLR (train.py:343-354) is deterministic and directly
 comparable. Each epoch also records per-epoch val metrics through ONE
 shared numpy scorer (P/S pick F1; accuracy for pmp and the motion
@@ -137,7 +137,7 @@ MODELS = {
         # sides) keeps the trajectory in a comparable regime.
         "cfg_overrides": {"max_lr": 3e-4},
     },
-    # Classification lane (VERDICT r4 #6, metric half): first-motion
+    # Classification lane (metric half): first-motion
     # polarity, CE over a (N, 2) softmax — the accuracy-metric dynamics.
     # The synthetic data encodes the class as the SIGN of the P wavelet
     # (make_data), so polarity is learnable from the waveform.
@@ -152,7 +152,7 @@ MODELS = {
         "labels": "pmp_onehot",
         "ref_loss": "ce_pmp",
     },
-    # Dropout-ON lane (VERDICT r4 #6): stochastic depth active, with the
+    # Dropout-ON lane: stochastic depth active, with the
     # per-sample DropPath uniforms INJECTED identically on both sides
     # (torch: the timm-stub's DropPath.inject; jax: models/common.py
     # droppath_mask_injection) — the technique ring attention's
