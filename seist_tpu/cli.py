@@ -304,10 +304,17 @@ def get_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
 
 def main_worker(args: argparse.Namespace) -> None:
     """Mode dispatch (ref main.py:182-210)."""
-    from seist_tpu.train.worker import is_main_process, test_worker, train_worker
-    from seist_tpu.utils.misc import enable_compile_cache
+    from seist_tpu.obs.bus import BUS
 
-    enable_compile_cache()
+    with BUS.span("setup_imports"):  # flax, optax, orbax come in here
+        from seist_tpu.train.worker import (
+            is_main_process,
+            test_worker,
+            train_worker,
+        )
+        from seist_tpu.utils.misc import enable_compile_cache
+
+        enable_compile_cache()
 
     log_dir = (
         os.path.join(
@@ -362,9 +369,14 @@ def main(argv: Optional[List[str]] = None) -> None:
         from seist_tpu.serve.server import main as serve_main
 
         return serve_main(argv[1:])
-    args = get_args(argv)
-    args.distributed = init_distributed_mode()
-    seist_tpu.load_all()
+    from seist_tpu.obs.bus import BUS
+
+    # The first of the set-up spans (docs/OBSERVABILITY.md): its start is
+    # the program's start on the bus clock.
+    with BUS.span("setup_imports"):
+        args = get_args(argv)
+        args.distributed = init_distributed_mode()
+        seist_tpu.load_all()
     main_worker(args)
 
 

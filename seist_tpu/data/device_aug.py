@@ -755,7 +755,12 @@ def make_row_processor(cfg: AugConfig, input_names, label_names):
         return inputs, targets
 
     def process(rows, idx, aug, epoch):
-        return jax.vmap(lambda r, i, a: one(r, i, a, epoch))(rows, idx, aug)
+        # Region scope (obs/scopes.py): metadata only, names the device
+        # ops of augmentation and label synthesis in a trace.
+        with jax.named_scope("device_aug"):
+            return jax.vmap(lambda r, i, a: one(r, i, a, epoch))(
+                rows, idx, aug
+            )
 
     return process
 
@@ -777,7 +782,10 @@ def make_cache_processor(
         else:
             raw_idx = idx
             aug = jnp.zeros(idx.shape, bool)
-        rows = jax.tree.map(lambda a: jnp.take(a, raw_idx, axis=0), cache)
+        with jax.named_scope("cache_gather"):
+            rows = jax.tree.map(
+                lambda a: jnp.take(a, raw_idx, axis=0), cache
+            )
         # RNG keys use the GLOBAL epoch index (matching the host path's
         # SeedSequence([seed, epoch, idx])), so the raw and augmented
         # replicas of a sample draw from different streams.

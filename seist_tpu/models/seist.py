@@ -472,35 +472,39 @@ class MultiScaleMixedConv(nn.Module):
 
     @nn.compact
     def __call__(self, x: Array, train: bool) -> Array:
-        group_size = self.io_dim // self.groups
-        dims_ = []
-        outs = []
-        for i, kernel_size in enumerate(self.kernel_sizes):
-            dim = make_divisible(
-                (self.io_dim - sum(dims_)) // (len(self.kernel_sizes) - len(dims_)),
-                group_size,
-            )
-            assert dim > 0
-            dims_.append(dim)
-            xi = nn.Dense(dim, use_bias=False, name=f"proj{i}", **_dense_kw)(x)
-            xi = common.make_norm(
-                self.norm, use_running_average=not train, name=f"norm{i}"
-            )(xi)
-            xi = xi + GroupConvBlock(
-                io_dim=dim,
-                groups=dim // group_size,
-                kernel_size=kernel_size,
-                path_drop_rate=self.path_drop_rate,
-                mlp_drop_rate=self.mlp_drop_rate,
-                mlp_ratio=self.mlp_ratio,
-                mlp_bias=self.mlp_bias,
-                norm=self.norm,
-                act=self.act,
-                name=f"conv{i}",
-            )(xi, train)
-            outs.append(xi)
-        x = jnp.concatenate(outs, axis=-1)
-        x = common.make_norm(self.norm, use_running_average=not train, name="out_norm")(x)
+        # Region scope (obs/scopes.py REGIONS): this block and the
+        # transformer layer share their instance names
+        # (stage<i>_block<j>), so the scope says which one an op is of.
+        with jax.named_scope("msmc"):
+            group_size = self.io_dim // self.groups
+            dims_ = []
+            outs = []
+            for i, kernel_size in enumerate(self.kernel_sizes):
+                dim = make_divisible(
+                    (self.io_dim - sum(dims_)) // (len(self.kernel_sizes) - len(dims_)),
+                    group_size,
+                )
+                assert dim > 0
+                dims_.append(dim)
+                xi = nn.Dense(dim, use_bias=False, name=f"proj{i}", **_dense_kw)(x)
+                xi = common.make_norm(
+                    self.norm, use_running_average=not train, name=f"norm{i}"
+                )(xi)
+                xi = xi + GroupConvBlock(
+                    io_dim=dim,
+                    groups=dim // group_size,
+                    kernel_size=kernel_size,
+                    path_drop_rate=self.path_drop_rate,
+                    mlp_drop_rate=self.mlp_drop_rate,
+                    mlp_ratio=self.mlp_ratio,
+                    mlp_bias=self.mlp_bias,
+                    norm=self.norm,
+                    act=self.act,
+                    name=f"conv{i}",
+                )(xi, train)
+                outs.append(xi)
+            x = jnp.concatenate(outs, axis=-1)
+            x = common.make_norm(self.norm, use_running_average=not train, name="out_norm")(x)
         return x
 
 
@@ -626,47 +630,50 @@ class MultiPathTransformerLayer(nn.Module):
 
         outs = []
         if attn_out_dim > 0:
-            x1 = nn.Dense(attn_out_dim, use_bias=False, name="attn_proj", **_dense_kw)(x)
-            x1 = common.make_norm(self.norm, use_running_average=not train, name="norm0")(x1)
-            a = AttentionBlock(
-                io_dim=attn_out_dim,
-                head_dim=self.head_dim,
-                qkv_bias=self.qkv_bias,
-                attn_drop_rate=self.attn_drop_rate,
-                key_drop_rate=self.key_drop_rate,
-                proj_drop_rate=self.attn_out_drop_rate,
-                attn_aggr_ratio=self.attn_aggr_ratio,
-                norm=self.norm,
-                name="attention",
-            )(x1, train)
-            x1 = x1 + DropPath(self.path_drop_rate * self.attn_ratio)(a, train)
+            with jax.named_scope("attn_path"):
+                x1 = nn.Dense(attn_out_dim, use_bias=False, name="attn_proj", **_dense_kw)(x)
+                x1 = common.make_norm(self.norm, use_running_average=not train, name="norm0")(x1)
+                a = AttentionBlock(
+                    io_dim=attn_out_dim,
+                    head_dim=self.head_dim,
+                    qkv_bias=self.qkv_bias,
+                    attn_drop_rate=self.attn_drop_rate,
+                    key_drop_rate=self.key_drop_rate,
+                    proj_drop_rate=self.attn_out_drop_rate,
+                    attn_aggr_ratio=self.attn_aggr_ratio,
+                    norm=self.norm,
+                    name="attention",
+                )(x1, train)
+                x1 = x1 + DropPath(self.path_drop_rate * self.attn_ratio)(a, train)
             outs.append(x1)
 
         if conv_out_dim > 0:
-            x2 = nn.Dense(conv_out_dim, use_bias=False, name="conv_proj", **_dense_kw)(x)
-            x2 = common.make_norm(self.norm, use_running_average=not train, name="norm1")(x2)
-            g = GroupConvBlock(
-                io_dim=conv_out_dim,
-                groups=conv_out_dim // self.head_dim,
-                kernel_size=3,
-                path_drop_rate=self.path_drop_rate,
-                mlp_drop_rate=self.mlp_drop_rate,
-                mlp_ratio=self.mlp_ratio,
-                mlp_bias=self.mlp_bias,
-                norm=self.norm,
-                act=self.act,
-                name="gconv",
-            )(x2, train)
-            x2 = x2 + DropPath(self.path_drop_rate * (1 - self.attn_ratio))(g, train)
+            with jax.named_scope("gconv_path"):
+                x2 = nn.Dense(conv_out_dim, use_bias=False, name="conv_proj", **_dense_kw)(x)
+                x2 = common.make_norm(self.norm, use_running_average=not train, name="norm1")(x2)
+                g = GroupConvBlock(
+                    io_dim=conv_out_dim,
+                    groups=conv_out_dim // self.head_dim,
+                    kernel_size=3,
+                    path_drop_rate=self.path_drop_rate,
+                    mlp_drop_rate=self.mlp_drop_rate,
+                    mlp_ratio=self.mlp_ratio,
+                    mlp_bias=self.mlp_bias,
+                    norm=self.norm,
+                    act=self.act,
+                    name="gconv",
+                )(x2, train)
+                x2 = x2 + DropPath(self.path_drop_rate * (1 - self.attn_ratio))(g, train)
             outs.append(x2)
 
         x = jnp.concatenate(outs, axis=-1)
-        x = common.make_norm(self.norm, use_running_average=not train, name="norm2")(x)
-        m = MLP(
-            self.io_dim, self.mlp_ratio, self.mlp_bias, self.mlp_drop_rate, self.act,
-            name="mlp",
-        )(x, train)
-        x = x + DropPath(self.path_drop_rate)(m, train)
+        with jax.named_scope("mlp_path"):
+            x = common.make_norm(self.norm, use_running_average=not train, name="norm2")(x)
+            m = MLP(
+                self.io_dim, self.mlp_ratio, self.mlp_bias, self.mlp_drop_rate, self.act,
+                name="mlp",
+            )(x, train)
+            x = x + DropPath(self.path_drop_rate)(m, train)
         return x
 
 

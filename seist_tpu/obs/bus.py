@@ -28,9 +28,11 @@ bus, arXiv:2203.17189, is the blueprint):
   summaries, rollbacks, quarantines, deaths) for reconstructing a
   days-long supervised run after the fact.
 
-Hot-path cost: one span is two ``monotonic()`` calls, one dict lookup and
-one locked histogram observe — single-digit microseconds, benched in the
-BENCH ``step_breakdown.telemetry`` section at <1% of step time.
+Hot-path cost: one span is two ``monotonic()`` calls, a TraceMe (a flag
+test outside a profiler capture), a push and a pop on the thread's span
+stack, one dict lookup and one locked histogram observe — microseconds,
+benched in the BENCH ``step_breakdown.telemetry`` section at <1% of step
+time.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
@@ -144,25 +147,86 @@ class Histogram(LatencyHistogram):
         self.labels = labels
 
 
+_SPAN_STACKS = threading.local()
+
+
+def _open_spans() -> List["Span"]:
+    """The calling thread's stack of open spans, innermost last."""
+    stack = getattr(_SPAN_STACKS, "stack", None)
+    if stack is None:
+        stack = _SPAN_STACKS.stack = []
+    return stack
+
+
+@contextlib.contextmanager
+def span_frame() -> Iterator[None]:
+    """Whatever spans the block opens on this thread and never ends (a
+    preempt exit inside an epoch, an early stop) are dropped from the
+    thread's stack when the block ends, so that they are not taken for
+    the parents of the next run's spans."""
+    stack = _open_spans()
+    depth = len(stack)
+    try:
+        yield
+    finally:
+        del stack[depth:]
+
+
+def _trace_annotation(name: str, labels: Dict[str, str]):
+    """An entered ``jax.profiler.TraceAnnotation``, or None in a process
+    that has not loaded jax (the model-free serving front tier never does,
+    and no profiler capture can run there). Outside a capture a TraceMe is
+    a flag test."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation(name, **labels)
+    annotation.__enter__()
+    return annotation
+
+
 class Span:
     """One timed phase. Context manager (``with bus.span(...)``) or
     explicit form (``s = bus.begin(...)``, later ``s.end()``).
-    ``duration_s`` is available after exit/end."""
+    ``duration_s`` is available after exit/end.
 
-    __slots__ = ("name", "labels", "_bus", "_t0", "duration_s")
+    ``start`` is the span's start on the bus clock and ``parent`` the name
+    of the span that was open around it on its thread: with both, a sink
+    has the tree, and a span's self time is its duration less its
+    children's. For its lifetime the span is also a
+    ``jax.profiler.TraceAnnotation`` of its own name (labels as arguments),
+    so any profiler capture shows the program's spans on ``/host:CPU``, on
+    the device's clock."""
+
+    __slots__ = ("name", "labels", "start", "parent", "duration_s",
+                 "_bus", "_stack", "_annotation")
 
     def __init__(self, bus: "MetricsBus", name: str, labels: Dict[str, str]):
         self.name = name
         self.labels = labels
         self._bus = bus
-        self._t0 = monotonic()
+        self._stack = _open_spans()
+        self.parent: Optional[str] = (
+            self._stack[-1].name if self._stack else None
+        )
+        self._stack.append(self)
         self.duration_s: Optional[float] = None
+        self._annotation = _trace_annotation(name, labels)
+        self.start = monotonic()
 
     def end(self) -> float:
         """Stop the clock, record on the bus, return elapsed seconds.
         Idempotent: a second end() returns the first duration."""
         if self.duration_s is None:
-            self.duration_s = monotonic() - self._t0
+            self.duration_s = monotonic() - self.start
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
+            # This span and whatever was opened under it and never ended
+            # leave the stack (a span_frame may have dropped it already).
+            try:
+                del self._stack[self._stack.index(self):]
+            except ValueError:
+                pass
             self._bus._record_span(self)
         return self.duration_s
 

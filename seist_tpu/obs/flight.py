@@ -91,7 +91,9 @@ class FlightRecorder:
                 {
                     "name": span.name,
                     "step": self._current_step,
+                    "t_mono": round(span.start, 6),  # record_step's clock
                     "dur_ms": round((span.duration_s or 0.0) * 1e3, 3),
+                    **({"parent": span.parent} if span.parent else {}),
                     **({"labels": span.labels} if span.labels else {}),
                 }
             )

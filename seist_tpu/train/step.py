@@ -38,22 +38,28 @@ def _first_call_span(jitted: Callable, name: str) -> Callable:
     (``jit_first_call_ms{fn=...}``, obs/bus.py) — on a fresh process that
     call IS the compile (minutes for the big models), historically
     invisible outside stderr. Steady-state cost: one truthiness check per
-    call."""
-    from functools import wraps
+    call.
 
-    done: list = []
+    The wrapper keeps what ``obs.scopes.scope_map`` needs to read the
+    executable's HLO back later, on demand: the jitted function
+    (``.jitted``) and the first call's types (``.first_call_types``:
+    shapes, dtypes and shardings, no buffers — the state is donated)."""
 
     @wraps(jitted)
     def call(*args, **kwargs):
-        if done:
+        if call.first_call_types is not None:
             return jitted(*args, **kwargs)
         from seist_tpu.obs.bus import BUS
+        from seist_tpu.obs.scopes import abstract_call
 
+        types = abstract_call(args, kwargs)
         with BUS.span("jit_first_call", fn=name):
             out = jitted(*args, **kwargs)
-        done.append(1)
+        call.first_call_types = types
         return out
 
+    call.jitted = jitted
+    call.first_call_types = None
     return call
 
 
@@ -74,26 +80,44 @@ def _forward_loss(spec: TaskSpec, loss_fn: Callable, cdtype, apply_fn) -> Callab
     """
 
     def compute(params, stats, inputs, targets, key):
-        variables = {"params": cast_floating(params, cdtype)}
         has_stats = stats is not None
-        if has_stats:
-            variables["batch_stats"] = stats
-        with precision_policy(cdtype):
-            out = apply_fn(
-                variables,
-                cast_floating(inputs, cdtype),
-                train=True,
-                mutable=["batch_stats"] if has_stats else [],
-                rngs={"dropout": key},
-            )
-        outputs, mutated = out if has_stats else (out[0], {})
-        outputs = cast_to_float32(outputs)
-        o, t = _apply_transforms(spec, outputs, targets)
-        return loss_fn(o, t), (outputs, mutated.get("batch_stats"))
+        # Named scopes are metadata only: they name the region a device op
+        # belongs to (obs/scopes.py REGIONS) and change no generated code.
+        with jax.named_scope("model"):
+            variables = {"params": cast_floating(params, cdtype)}
+            if has_stats:
+                variables["batch_stats"] = stats
+            with precision_policy(cdtype):
+                out = apply_fn(
+                    variables,
+                    cast_floating(inputs, cdtype),
+                    train=True,
+                    mutable=["batch_stats"] if has_stats else [],
+                    rngs={"dropout": key},
+                )
+            outputs, mutated = out if has_stats else (out[0], {})
+            outputs = cast_to_float32(outputs)
+        with jax.named_scope("loss"):
+            o, t = _apply_transforms(spec, outputs, targets)
+            loss = loss_fn(o, t)
+        return loss, (outputs, mutated.get("batch_stats"))
 
     return compute
 
 
+def _apply_update(state: TrainState, grads, new_stats):
+    """The optimizer's update and the new BN statistics."""
+    state = state.apply_gradients(grads=grads)
+    if new_stats is not None:
+        state = state.replace(batch_stats=cast_to_float32(new_stats))
+    return state
+
+
+# Under the ``optimizer`` region scope (obs/scopes.py; metadata only).
+_update = jax.named_scope("optimizer")(_apply_update)
+
+
+@jax.named_scope("optimizer")
 def _guarded_update(state: TrainState, grads, loss, new_stats):
     """Apply the gradient update only when loss AND global grad-norm are
     finite; otherwise return ``state`` unchanged (params, opt_state, BN
@@ -112,9 +136,7 @@ def _guarded_update(state: TrainState, grads, loss, new_stats):
     """
     grad_norm = optax.global_norm(grads)
     finite = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-    updated = state.apply_gradients(grads=grads)
-    if new_stats is not None:
-        updated = updated.replace(batch_stats=cast_to_float32(new_stats))
+    updated = _apply_update(state, grads, new_stats)
     # NaN grads make NaN optimizer moments; jnp.where discards the whole
     # poisoned update in one pass over the state pytree.
     state = jax.tree.map(
@@ -148,7 +170,8 @@ def make_train_step(
     cdtype = resolve_dtype(compute_dtype)
 
     def train_step(state: TrainState, inputs, targets, rng):
-        step_rng = jax.random.fold_in(rng, state.step)
+        with jax.named_scope("model"):  # the dropout masks' key
+            step_rng = jax.random.fold_in(rng, state.step)
         fwd = _forward_loss(spec, loss_fn, cdtype, state.apply_fn)
         (loss, (outputs, new_stats)), grads = jax.value_and_grad(
             fwd, has_aux=True
@@ -156,10 +179,7 @@ def make_train_step(
         if guard:
             state, diag = _guarded_update(state, grads, loss, new_stats)
             return state, loss, outputs, diag
-        state = state.apply_gradients(grads=grads)
-        if new_stats is not None:
-            state = state.replace(batch_stats=cast_to_float32(new_stats))
-        return state, loss, outputs
+        return _update(state, grads, new_stats), loss, outputs
 
     return train_step
 
@@ -476,9 +496,7 @@ def make_accum_train_step(
                 state, grads, mean_loss, stats if has_stats else None
             )
             return state, mean_loss, None, diag
-        state = state.apply_gradients(grads=grads)
-        if has_stats:
-            state = state.replace(batch_stats=stats)
+        state = _update(state, grads, stats if has_stats else None)
         return state, mean_loss, None
 
     return accum_step
@@ -500,25 +518,29 @@ def make_eval_step(
     cdtype = resolve_dtype(compute_dtype)
 
     def eval_step(state: TrainState, inputs, targets, mask):
-        variables = {"params": cast_floating(state.params, cdtype)}
-        if state.batch_stats is not None:
-            variables["batch_stats"] = state.batch_stats
-        with precision_policy(cdtype):
-            outputs = state.apply_fn(
-                variables, cast_floating(inputs, cdtype), train=False
-            )
-        outputs = cast_to_float32(outputs)
-        o, t = _apply_transforms(spec, outputs, targets)
+        with jax.named_scope("model"):
+            variables = {"params": cast_floating(state.params, cdtype)}
+            if state.batch_stats is not None:
+                variables["batch_stats"] = state.batch_stats
+            with precision_policy(cdtype):
+                outputs = state.apply_fn(
+                    variables, cast_floating(inputs, cdtype), train=False
+                )
+            outputs = cast_to_float32(outputs)
 
         def one(o1, t1):
             ob = jax.tree.map(lambda a: a[None], o1)
             tb = jax.tree.map(lambda a: a[None], t1)
             return loss_fn(ob, tb)
 
-        per_sample = jax.vmap(one)(o, t)
-        w = mask.astype(per_sample.dtype)
-        masked = (per_sample * w).sum()
-        loss = masked if sum_reduced else masked / jnp.maximum(w.sum(), 1.0)
+        with jax.named_scope("loss"):
+            o, t = _apply_transforms(spec, outputs, targets)
+            per_sample = jax.vmap(one)(o, t)
+            w = mask.astype(per_sample.dtype)
+            masked = (per_sample * w).sum()
+            loss = (
+                masked if sum_reduced else masked / jnp.maximum(w.sum(), 1.0)
+            )
         return loss, outputs
 
     return eval_step

@@ -68,6 +68,19 @@ def is_main_process() -> bool:
     return jax.process_index() == 0
 
 
+class _LapClock:
+    """Seconds since the last call (since construction, the first time), on
+    the bus clock: the log lines' wave/s interval."""
+
+    def __init__(self):
+        self._last = obs.monotonic()
+
+    def __call__(self) -> float:
+        now = obs.monotonic()
+        lap, self._last = now - self._last, now
+        return lap
+
+
 class _PreemptionHandler:
     """SIGTERM -> checkpoint-at-next-step-boundary -> exit(75).
 
@@ -331,26 +344,37 @@ def validate(
         ResultSaver(item_names=tasks) if (save_results and is_main_process()) else None
     )
 
+    # Four spans partition a pass (docs/OBSERVABILITY.md): the wait on the
+    # host loader, the eval call with the loss fetch (the device's share and
+    # the sync), the un-jitted picking, and the host scoring.
     for step, batch in enumerate(
-        io_guard.watch(
-            pipeline.prefetch_to_device(iter(val_loader), mesh), watchdog
+        obs.timed_iter(
+            io_guard.watch(
+                pipeline.prefetch_to_device(iter(val_loader), mesh), watchdog
+            ),
+            "val_host_wait",
         )
     ):
-        loss, outputs = eval_step(
-            state, batch.inputs, batch.loss_targets, batch.mask
-        )
-        valid = int(mesh_lib.to_local(batch.mask).sum())
-        # Weight by the GLOBAL valid count so every host's running val loss
-        # is identical — checkpoint/early-stop decisions must not diverge
-        # across hosts (tail padding lives on one host's shard only).
-        # jaxlint: disable=host-sync-item-loop -- one scalar per VAL batch; the running meter (and the float(loss) next line) needs it now
-        global_valid = int(np.asarray(jax.device_get(batch.mask.sum())))
-        loss_meter.update(float(loss), max(global_valid, 1))
-        results = _postprocess_batch(args, spec, outputs, fs)
-        batch_metrics = _make_metrics(args, tasks, fs)
-        _update_task_metrics(
-            metrics_merged, batch_metrics, results, batch.metrics_targets, valid
-        )
+        with obs.BUS.span("val_step"):
+            loss, outputs = eval_step(
+                state, batch.inputs, batch.loss_targets, batch.mask
+            )
+            valid = int(mesh_lib.to_local(batch.mask).sum())
+            # Weight by the GLOBAL valid count so every host's running val
+            # loss is identical — checkpoint/early-stop decisions must not
+            # diverge across hosts (tail padding lives on one host's shard
+            # only).
+            # jaxlint: disable=host-sync-item-loop -- one scalar per VAL batch; the running meter (and the float(loss) next line) needs it now
+            global_valid = int(np.asarray(jax.device_get(batch.mask.sum())))
+            loss_meter.update(float(loss), max(global_valid, 1))
+        with obs.BUS.span("val_postprocess"):
+            results = _postprocess_batch(args, spec, outputs, fs)
+        with obs.BUS.span("val_metrics"):
+            batch_metrics = _make_metrics(args, tasks, fs)
+            _update_task_metrics(
+                metrics_merged, batch_metrics, results,
+                batch.metrics_targets, valid,
+            )
         if saver is not None:
             import json as _json
 
@@ -406,7 +430,10 @@ def _dump_flight_on_exception(fn):
     @functools.wraps(fn)
     def wrapper(*a, **k):
         try:
-            return fn(*a, **k)
+            # A preempt exit or an early stop leaves train_epoch open: the
+            # frame drops such spans from this thread's stack of open spans.
+            with obs.span_frame():
+                return fn(*a, **k)
         except Exception as e:
             obs.flight.dump_on_death("exception", dedup_s=5.0, error=repr(e))
             raise
@@ -447,9 +474,14 @@ def train_worker(args: Any) -> str:
             f"be divisible by the mesh 'data' axis ({data_axis} devices)"
         )
 
+    # Set-up phases as spans (explicit begin/end: the phases are this
+    # function's own sections). With jit_first_call and the first
+    # train_epoch they cover the program's part of a run's set-up.
+    setup = obs.BUS.begin("setup_loaders")
     train_loader = _build_loader(args, spec, "train")
     val_loader = _build_loader(args, spec, "val")
     fs = train_loader.dataset.sampling_rate()
+    setup.end()
 
     steps_per_epoch = len(train_loader)
     if steps_per_epoch == 0:
@@ -472,7 +504,8 @@ def train_worker(args: Any) -> str:
             )
         total_steps = (steps_per_epoch // gas) * epochs
 
-    # Model + optimizer + state.
+    # Model + optimizer + state (+ restore).
+    setup = obs.BUS.begin("setup_init")
     in_channels = taskspec.get_num_inchannels(args.model_name)
     model = api.create_model(
         args.model_name, in_channels=in_channels, in_samples=args.in_samples
@@ -571,6 +604,7 @@ def train_worker(args: Any) -> str:
     # its avals, the step's output does, and the difference would retrace
     # and recompile the whole step on its second call.
     state = mesh_lib.replicate(mesh, state)
+    setup.end()
 
     dtype = getattr(args, "dtype", "fp32")
     # Bad-update guard: detect non-finite loss/grad-norm inside the jitted
@@ -598,6 +632,7 @@ def train_worker(args: Any) -> str:
     # live in HBM and a scan executor consumes (k, B) index arrays — zero
     # per-step host stacking. Unsupported configs fall back to the host
     # path; an over-budget 'cached' falls back to 'step' (both logged).
+    setup = obs.BUS.begin("setup_store")  # device store build and upload
     device_req = str(getattr(args, "device_aug", "off") or "off")
     device_mode = "off"
     dev_store = dev_cache = None
@@ -749,6 +784,10 @@ def train_worker(args: Any) -> str:
             f"({dev_cache.nbytes / 2**20:.1f} MiB HBM), "
             f"steps_per_call={spc}"
         )
+    setup.end()
+
+    setup = obs.BUS.begin("setup_steps")  # the step closures
+    if device_mode == "cached":
         train_step = jit_cached_call(
             make_cached_train_call(
                 spec, loss_fn,
@@ -832,7 +871,12 @@ def train_worker(args: Any) -> str:
     eval_step = jit_eval_step(
         make_eval_step(spec, loss_fn, compute_dtype=dtype), mesh
     )
+    setup.end()
     base_rng = jax.random.PRNGKey(args.seed)
+
+    # Scalar writer (its TensorBoard backend imports torch), checkpoint
+    # manager, watchdog, telemetry plane, signal handlers.
+    setup = obs.BUS.begin("setup_writers")
 
     writer = (
         ScalarWriter(os.path.join(logger.logdir(), "tensorboard"))
@@ -1146,6 +1190,7 @@ def train_worker(args: Any) -> str:
     g_wps = obs.BUS.gauge("waveforms_per_sec")
     g_epoch = obs.BUS.gauge("epoch")
     g_gstep = obs.BUS.gauge("global_step")
+    setup.end()
 
     for epoch in range(start_epoch, epochs):
         epoch_span = obs.BUS.begin("train_epoch")
@@ -1173,9 +1218,8 @@ def train_worker(args: Any) -> str:
         progress = ProgressMeter(
             steps_per_epoch, [loss_meter, wps_meter], prefix=f"Epoch[{epoch}] "
         )
-        # Log-interval clock for wave/s: span begin/end pairs replace the
-        # old ad-hoc time.monotonic() bookkeeping.
-        rate_span = obs.BUS.begin("log_interval")
+        # Log-interval clock for wave/s (seconds since the last log line).
+        lap = _LapClock()
         # Device->host transfers are confined to every --log-step steps:
         # pulling loss/outputs every step serializes JAX's async dispatch
         # and stalls the chip on host postprocess (the per-step numbers are
@@ -1250,8 +1294,7 @@ def train_worker(args: Any) -> str:
                 if call % args.log_step == 0:
                     loss_f = float(loss)
                     loss_meter.update(loss_f, 1)
-                    interval = rate_span.end()
-                    rate_span = obs.BUS.begin("log_interval")
+                    interval = lap()
                     calls_done = min(args.log_step, call) or 1
                     wps_meter.update(
                         global_bs * kpack * calls_done
@@ -1325,8 +1368,7 @@ def train_worker(args: Any) -> str:
                 if step % args.log_step == 0:
                     loss_f = float(loss)
                     loss_meter.update(loss_f, 1)
-                    interval = rate_span.end()
-                    rate_span = obs.BUS.begin("log_interval")
+                    interval = lap()
                     steps_done = min(args.log_step, step) or 1
                     wps_meter.update(
                         global_bs * steps_done / max(interval, 1e-9)
@@ -1388,8 +1430,7 @@ def train_worker(args: Any) -> str:
                 if call % args.log_step == 0:
                     loss_f = float(loss)
                     loss_meter.update(loss_f, 1)
-                    interval = rate_span.end()
-                    rate_span = obs.BUS.begin("log_interval")
+                    interval = lap()
                     calls_done = min(args.log_step, call) or 1
                     wps_meter.update(
                         global_bs * kpack * calls_done
@@ -1445,8 +1486,7 @@ def train_worker(args: Any) -> str:
                 if step % args.log_step == 0:
                     loss_f = float(loss)
                     loss_meter.update(loss_f, 1)
-                    interval = rate_span.end()
-                    rate_span = obs.BUS.begin("log_interval")
+                    interval = lap()
                     steps_done = min(args.log_step, step) or 1
                     wps_meter.update(
                         global_bs * steps_done / max(interval, 1e-9)
@@ -1487,7 +1527,11 @@ def train_worker(args: Any) -> str:
 
         if monitor.flush():  # lagging guard flags from the epoch tail
             state = _rollback(state)
-        epoch_losses = [float(l) for l in jax.device_get(deferred_losses)]
+        # The device finishing the calls the host ran ahead of.
+        with obs.BUS.span("epoch_drain"):
+            epoch_losses = [
+                float(l) for l in jax.device_get(deferred_losses)
+            ]
         train_losses.extend(epoch_losses)
         # Exact epoch mean from every step's loss (the meter only samples
         # every log_step steps, for the progress line). Guard-skipped steps
@@ -1554,18 +1598,20 @@ def train_worker(args: Any) -> str:
             # (replacing the reference's rank0 ckpt-path broadcast,
             # train.py:481-482). The val metric feeds the manager's
             # keep-best retention, so GC never deletes this step.
-            best_ckpt_path = ckpt_mgr.save(
-                epoch_end_step,
-                state,
-                epoch=epoch,
-                data_epoch=epoch + 1,
-                data_batch_offset=0,
-                val_loss=val_loss,
-                seed=args.seed,
-                steps_per_epoch=steps_per_epoch,
-                batch_size=int(args.batch_size),
-                on_exists="skip",  # an interval save may own this boundary
-            )
+            with obs.BUS.span("checkpoint_save"):
+                best_ckpt_path = ckpt_mgr.save(
+                    epoch_end_step,
+                    state,
+                    epoch=epoch,
+                    data_epoch=epoch + 1,
+                    data_batch_offset=0,
+                    val_loss=val_loss,
+                    seed=args.seed,
+                    steps_per_epoch=steps_per_epoch,
+                    batch_size=int(args.batch_size),
+                    # an interval save may own this boundary
+                    on_exists="skip",
+                )
         else:
             patience_counter += 1
             if patience_counter > args.patience:

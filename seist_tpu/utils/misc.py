@@ -127,6 +127,11 @@ def enable_compile_cache(
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", int(min_compile_seconds)
     )
+    # Compile accounting rides along: trace / lower / compile seconds and
+    # cache hits as bus counters (imported here: obs imports this package).
+    from seist_tpu.obs import jit_events
+
+    jit_events.install()
     if verbose:
         import sys
 

@@ -1,27 +1,36 @@
 """Profiling / tracing — a first-class subsystem the reference lacks
 (SURVEY.md §5: only coarse epoch timing + TensorBoard scalars).
 
-* :func:`trace` — context manager around ``jax.profiler`` writing a
-  TensorBoard-loadable trace (XLA ops, fusion, HBM traffic) to the log dir.
-* :func:`device_memory_stats` — per-device HBM usage snapshot.
-* :class:`ThroughputMeter` — waveforms/sec with warmup skip, the number
-  BASELINE.json's north-star metric is quoted in.
+* :func:`trace` / :func:`trace_start` / :func:`trace_stop` — a
+  ``jax.profiler`` capture (device ops and the program's bus spans, on one
+  clock) written to the log dir as an ``.xplane.pb``.
+* :func:`stopwatch`, :class:`StepTimeSplit` — interval timing on the bus
+  clock (obs/bus.py).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
 from typing import Callable, Dict, Iterator, List, Optional
 
 
 def trace_start(logdir: str) -> None:
     """Begin a jax.profiler trace (pair with :func:`trace_stop`) — the
     non-contextmanager form for capture windows that span loop iterations
-    (the worker's --profile-steps path)."""
+    (the worker's --profile-steps path, SIGUSR2, ``POST /profile``).
+
+    Device ops and TraceMe annotations (every bus span is one), no Python
+    tracer: with it on, a few seconds of a trainer's loader threads filled
+    40 GiB of host memory on the chip (PERF.md, PR 23, call 1). The same
+    options as the benchmark's capture, so an operator's trace holds the
+    same spans and regions."""
     import jax
 
-    jax.profiler.start_trace(logdir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    options.enable_hlo_proto = False
+    jax.profiler.start_trace(logdir, profiler_options=options)
 
 
 def trace_stop() -> None:
@@ -54,19 +63,6 @@ def stopwatch() -> Iterator[Callable[[], float]]:
 
     with _stopwatch() as elapsed:
         yield elapsed
-
-
-def device_memory_stats() -> List[Dict[str, float]]:
-    """Per-device memory stats (bytes). Empty list on backends without
-    memory_stats support (CPU)."""
-    import jax
-
-    out = []
-    for d in jax.devices():
-        stats = getattr(d, "memory_stats", lambda: None)()
-        if stats:
-            out.append({"device": str(d), **{k: float(v) for k, v in stats.items()}})
-    return out
 
 
 class StepTimeSplit:
@@ -132,28 +128,3 @@ class StepTimeSplit:
             "per_step_host_wait_ms": [round(x * 1e3, 3) for x in h],
             "per_step_device_time_ms": [round(x * 1e3, 3) for x in d],
         }
-
-
-class ThroughputMeter:
-    """Waveforms/sec over a sliding run, skipping compile-time warmup steps."""
-
-    def __init__(self, warmup_steps: int = 2):
-        self._warmup = warmup_steps
-        self._count = 0
-        self._items = 0
-        self._start: Optional[float] = None
-
-    def step(self, n_items: int) -> None:
-        self._count += 1
-        if self._count == self._warmup + 1:
-            self._start = time.perf_counter()
-            self._items = 0
-        if self._count > self._warmup:
-            self._items += n_items
-
-    @property
-    def items_per_sec(self) -> float:
-        if self._start is None or self._items == 0:
-            return 0.0
-        dt = time.perf_counter() - self._start
-        return self._items / dt if dt > 0 else 0.0

@@ -434,14 +434,16 @@ def test_step_time_split_span_helpers():
 
     split = StepTimeSplit(skip_first=0)
     for _ in range(2):
+        # Long enough that a loaded machine's overshoot of a sleep (a few
+        # ms under the suite's six workers) cannot turn the ratio around.
         with split.host():
-            time.sleep(0.004)
+            time.sleep(0.04)
         with split.device():
-            time.sleep(0.002)
+            time.sleep(0.02)
     s = split.summary()
     assert s["steps"] == 2
-    assert s["host_wait_ms_per_step"] >= 4.0
-    assert s["device_time_ms_per_step"] >= 2.0
+    assert s["host_wait_ms_per_step"] >= 40.0
+    assert s["device_time_ms_per_step"] >= 20.0
     assert 0.5 < s["input_bound_fraction"] < 1.0
 
 
