@@ -793,6 +793,139 @@ def prefetch_to_device(
     yield from _double_buffer(iterator, put, prefetch)
 
 
+def _per_device_bytes(batch: Batch) -> int:
+    """Device memory one placed eval batch takes on each device (``meta``
+    stays on the host)."""
+    import jax
+
+    def nbytes(x) -> int:
+        shards = getattr(x, "addressable_shards", None)
+        return int(shards[0].data.nbytes if shards else np.asarray(x).nbytes)
+
+    return sum(
+        nbytes(x)
+        for x in jax.tree.leaves(
+            (batch.inputs, batch.loss_targets, batch.metrics_targets, batch.mask)
+        )
+    )
+
+
+def free_device_bytes() -> int:
+    """What a run may still keep resident on each device: ``bytes_limit``
+    less ``peak_bytes_in_use`` (the train step's temporaries come back
+    every step, so the peak and not the current use), less an eighth of
+    the limit for fragmentation, the eval step's own temporaries and what
+    the host places on demand (PERF.md). The CPU backend reports no stats:
+    its nominal budget is ``device_aug.hbm_budget_bytes``; an accelerator
+    that reports none has nothing free."""
+    import jax
+
+    from seist_tpu.data.device_aug import hbm_budget_bytes
+
+    free = []
+    for dev in jax.local_devices():
+        if dev.platform == "cpu":
+            return hbm_budget_bytes()
+        stats = dev.memory_stats() or {}
+        limit = int(stats.get("bytes_limit", 0))
+        free.append(
+            limit - int(stats.get("peak_bytes_in_use", 0)) - limit // 8
+        )
+    return min(free)
+
+
+class ResidentEvalPass:
+    """Memo of one eval loader's pass, owned by a training run: validation
+    never augments, never shuffles and never advances its epoch, so every
+    pass of a run builds bit-identical batches. The first whole pass
+    streams from the host loader and keeps each placed ``Batch`` as the
+    eval step consumed it; every later pass replays that list — no loader
+    threads, no preprocessing, no host-to-device copy, the same buffers
+    and shardings (the eval step donates nothing).
+
+    A pass that ends early keeps nothing. A pass that does not fit
+    ``free_bytes()`` (reckoned from the first placed batch x the loader's
+    batches) makes the run stream every pass, with one log line."""
+
+    def __init__(self, free_bytes=free_device_bytes) -> None:
+        from seist_tpu.obs.bus import BUS
+
+        self._free_bytes = free_bytes
+        # (placed batch, its valid rows) of one whole pass, once held
+        self._pass: Optional[List[Tuple[Batch, int]]] = None
+        self._too_large = False
+        self._c_replayed = BUS.counter("val_samples_replayed")
+        self._g_bytes = BUS.gauge("val_resident_bytes")
+
+    @property
+    def ready(self) -> bool:
+        return self._pass is not None
+
+    def replay(self) -> Iterator[Batch]:
+        for batch, valid in self._pass:
+            self._c_replayed.inc(valid)
+            yield batch
+
+    def fits(self, first: Batch, n_batches: int) -> bool:
+        """Whether a pass of ``n_batches`` like ``first`` may stay on the
+        devices; a no holds for the run and is logged once."""
+        if not self._too_large:
+            need = _per_device_bytes(first) * n_batches
+            free = int(self._free_bytes())
+            if need > free:
+                self._too_large = True
+                logger.info(
+                    f"validation pass stays on the host loader: {need} "
+                    f"bytes a device to keep it resident, {free} free"
+                )
+        return not self._too_large
+
+    def hold(self, batches: List[Batch], valid: List[int]) -> None:
+        """Keep one whole pass: its placed batches and their valid rows."""
+        self._pass = list(zip(batches, valid))
+        self._g_bytes.set(sum(_per_device_bytes(b) for b in batches))
+
+
+def eval_batches(
+    loader: Loader,
+    mesh=None,
+    *,
+    watchdog: Optional[io_guard.StallWatchdog] = None,
+    resident: Optional[ResidentEvalPass] = None,
+) -> Iterator[Batch]:
+    """The placed batches of one eval pass: replayed from ``resident`` once
+    it holds a whole pass, else streamed from the host loader (the stall
+    watchdog armed while blocked on it) and, if a ``resident`` has room,
+    handed to it when the pass ran to its end."""
+    if resident is not None and resident.ready:
+        yield from resident.replay()
+        return
+    from seist_tpu.obs.bus import BUS
+
+    c_streamed = BUS.counter("val_samples_streamed")
+    valid: List[int] = []  # per batch, noted on the host side of the copy
+
+    def noting() -> Iterator[Batch]:
+        for batch in loader:
+            valid.append(int(batch.mask.sum()))
+            yield batch
+
+    n_batches = len(loader)
+    keep = resident is not None
+    kept: List[Batch] = []
+    for i, batch in enumerate(
+        io_guard.watch(prefetch_to_device(noting(), mesh), watchdog)
+    ):
+        c_streamed.inc(valid[i])
+        if keep and i == 0:
+            keep = resident.fits(batch, n_batches)
+        if keep:
+            kept.append(batch)
+        yield batch
+    if keep and len(kept) == n_batches:
+        resident.hold(kept, valid)
+
+
 def _guarded_raw_event(sds: SeismicDataset, i: int) -> dict:
     """RawStore ingest read: transient faults retried like the host path;
     a permanently-corrupt sample raises ValueError — the device store

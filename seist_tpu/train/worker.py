@@ -331,11 +331,15 @@ def validate(
     testing: bool = False,
     save_results: bool = False,
     watchdog: Optional[io_guard.StallWatchdog] = None,
+    resident: Optional[pipeline.ResidentEvalPass] = None,
 ) -> Tuple[float, Dict[str, Metrics]]:
     """Eval loop (ref validate.py:10-134): loss + per-task metrics; at test
     time optionally accumulate the results CSV. ``watchdog`` (the train
     worker's data-plane stall watchdog) is armed while blocked on val
-    batches — a wedged val loader preempts instead of hanging the run."""
+    batches — a wedged val loader preempts instead of hanging the run.
+    ``resident`` (a training run's memo of its validation pass) replays
+    the first whole pass's placed batches in every later one; without it
+    every pass streams from the host loader."""
     tasks = list(spec.eval)
     fs = val_loader.dataset.sampling_rate()
     metrics_merged = _make_metrics(args, tasks, fs)
@@ -346,11 +350,12 @@ def validate(
 
     # Four spans partition a pass (docs/OBSERVABILITY.md): the wait on the
     # host loader, the eval call with the loss fetch (the device's share and
-    # the sync), the un-jitted picking, and the host scoring.
+    # the sync), the un-jitted picking, and the host scoring. A replayed
+    # pass waits on nothing: its val_host_wait reads the replay itself.
     for step, batch in enumerate(
         obs.timed_iter(
-            io_guard.watch(
-                pipeline.prefetch_to_device(iter(val_loader), mesh), watchdog
+            pipeline.eval_batches(
+                val_loader, mesh, watchdog=watchdog, resident=resident
             ),
             "val_host_wait",
         )
@@ -480,6 +485,10 @@ def train_worker(args: Any) -> str:
     setup = obs.BUS.begin("setup_loaders")
     train_loader = _build_loader(args, spec, "train")
     val_loader = _build_loader(args, spec, "val")
+    # Validation builds the same batches in every pass: keep the first
+    # whole pass resident and replay it (independent of the model state,
+    # so a rollback keeps it and a restart refills it).
+    resident_val = pipeline.ResidentEvalPass()
     fs = train_loader.dataset.sampling_rate()
     setup.end()
 
@@ -1569,7 +1578,7 @@ def train_worker(args: Any) -> str:
             with obs.BUS.span("validate"):
                 val_loss, val_metrics = validate(
                     args, state, eval_step, spec, val_loader, mesh,
-                    watchdog=watchdog,
+                    watchdog=watchdog, resident=resident_val,
                 )
         except io_guard.LoaderDeathError as e:
             _loader_death_exit(e, state, epoch, steps_per_epoch)
