@@ -105,9 +105,15 @@ _SCALE_COLS = ("scale_0", "scale_1", "scale_2")
 INT8_POISON = -128
 
 
+#: Storage of sources whose ``data`` is integer (token sequences): the ids
+#: as they are, never a float rendering of them. Not a choice of the caller:
+#: :func:`pack_sources` takes it whenever the source's data is integer.
+INT_SEQUENCE_DTYPE = "int32"
+
+
 def canonical_dtype(name: str) -> str:
     name = _DTYPE_ALIASES.get(str(name).lower(), str(name).lower())
-    if name not in ("float32", "bfloat16", "int8"):
+    if name not in ("float32", "bfloat16", "int8", INT_SEQUENCE_DTYPE):
         raise ValueError(
             f"unsupported packed storage dtype '{name}' "
             "(use float32, bfloat16 or int8)"
@@ -122,6 +128,8 @@ def storage_dtype(name: str) -> np.dtype:
     name = canonical_dtype(name)
     if name == "float32":
         return np.dtype(np.float32)
+    if name == INT_SEQUENCE_DTYPE:
+        return np.dtype(np.int32)
     if name == "int8":
         return np.dtype(np.int8)
     import ml_dtypes
@@ -302,7 +310,10 @@ def pack_shard(
         with open(tmp_bin, "wb") as f:
             for j in range(plan.lo, plan.hi):
                 event, row = src[j]
-                data = np.ascontiguousarray(event["data"], dtype=np.float32)
+                data = np.ascontiguousarray(
+                    event["data"],
+                    dtype=np.int32 if store_dt == np.int32 else np.float32,
+                )
                 if data.ndim != 2:
                     raise ValueError(
                         f"event {j}: data must be (C, L), got {data.shape}"
@@ -319,7 +330,7 @@ def pack_shard(
                         cols[f"scale_{c}"].append(
                             float(scale[c]) if c < scale.size else np.nan
                         )
-                elif store_dt != np.float32:
+                elif store_dt not in (np.float32, np.int32):
                     data = data.astype(store_dt)
                 f.write(data.tobytes())
                 _append_sample(cols, event, row, j)
@@ -529,6 +540,8 @@ def pack_sources(
         ):
             raise DtypeMixError(existing, dtype, out_dir)
     datasets = [s.create() for s in sources]
+    if np.asarray(datasets[0][0][0]["data"]).dtype.kind in "iu":
+        dtype = INT_SEQUENCE_DTYPE  # integer sequences are stored as such
     channels = list(datasets[0].channels())
     fs = int(datasets[0].sampling_rate())
     for ds in datasets[1:]:
@@ -815,11 +828,10 @@ class PackedDataset(DatasetBase):
         )
         # .astype always copies — bf16 packs upcast, f32 packs keep the
         # original copy-out-of-the-memmap semantics.
-        data = (
-            np.frombuffer(raw, dtype=self._storage_dtype)
-            .reshape(c, length)
-            .astype(np.float32)
-        )
+        data = np.frombuffer(raw, dtype=self._storage_dtype).reshape(c, length)
+        # .astype always copies; integer sequences stay integers
+        data = data.astype(
+            np.int32 if self._storage_dtype == np.int32 else np.float32)
         if self._storage_dtype == np.int8:
             # Format v3 host-path dequant. int8 rows cannot carry NaN,
             # so their poison markers are the out-of-contract -128 byte

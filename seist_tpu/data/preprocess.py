@@ -502,6 +502,12 @@ class DataPreprocessor:
         if not inplace:
             event = copy.deepcopy(event)
 
+        if np.asarray(event["data"]).dtype.kind in "iu":
+            # A token sequence: cut to the window and nothing else — no
+            # noise test, augmentation, normalisation or phase labels.
+            event["data"] = np.asarray(event["data"])[:, : self.in_samples]
+            return event
+
         if self._is_noise(event["data"], event["ppks"], event["spks"], event["snr"]):
             self._clear_event_except(event, "data")
 
@@ -662,6 +668,13 @@ class DataPreprocessor:
             return np.stack(children, axis=-1)
 
         kind = taskspec.get_kind(name)
+        if kind == taskspec.TOKENS:
+            ids = np.asarray(event["data"][0], np.int32)
+            if name == "ids":
+                return ids
+            # next_ids: the window shifted by one; its last position has
+            # no target (-1, which the loss and the accuracy leave out)
+            return np.concatenate([ids[1:], np.full(1, -1, np.int32)])
         if kind == taskspec.SOFT:
             return self._generate_soft_label(
                 name, event, soft_label_width, soft_label_shape

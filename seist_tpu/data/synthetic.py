@@ -117,3 +117,58 @@ class Synthetic(DatasetBase):
 @register_dataset
 def synthetic(**kwargs):
     return Synthetic(**kwargs)
+
+
+class SyntheticTokens(DatasetBase):
+    """Integer sequences for a token task: event ``idx`` is one document of
+    ``trace_samples`` ids drawn Zipf (exponent 1) over ``vocab_size``, from
+    ``default_rng(seed * 1e6 + idx)`` like :class:`Synthetic`. ``data`` is
+    (1, L) int32 — the one "channel" is the ids — and no other field is
+    set: nothing here is normalised, augmented or given soft labels
+    (data/preprocess.py passes integer data through)."""
+
+    _name = "synthetic_tokens"
+    _part_range = None
+    _channels = ["ids"]
+    _sampling_rate = 1
+
+    def __init__(
+        self,
+        *,
+        num_events: int = 256,
+        trace_samples: int = 8192,
+        vocab_size: int = 16384,
+        data_dir: str = "",
+        cache: bool = False,
+        **kwargs,
+    ):
+        del cache  # accepted for PackSource's sake; a draw is cheap
+        self._num_events = num_events
+        self._trace_samples = trace_samples
+        ranks = np.arange(1, vocab_size + 1, dtype=np.float64)
+        cdf = np.cumsum(1.0 / ranks)
+        self._cdf = cdf / cdf[-1]
+        super().__init__(data_dir=data_dir, **kwargs)
+
+    def _load_meta_data(self) -> pd.DataFrame:
+        meta = pd.DataFrame({"idx": np.arange(self._num_events)})
+        return self._shuffle_and_split(meta)
+
+    def _load_event_data(self, idx: int) -> Tuple[Event, dict]:
+        row = self._meta_data.iloc[idx]
+        rng = np.random.default_rng(int(self._seed) * 1_000_000 + int(row["idx"]))
+        ids = np.searchsorted(self._cdf, rng.random(self._trace_samples))
+        ids = np.minimum(ids, len(self._cdf) - 1).astype(np.int32)
+        return {"data": ids[None, :]}, {"idx": int(row["idx"])}
+
+
+@register_dataset
+def synthetic_tokens(**kwargs):
+    return SyntheticTokens(**kwargs)
+
+
+@register_dataset
+def synthetic_tokens_tiny(**kwargs):
+    """The same over 256 ids: the vocabulary of the CPU-sized model preset
+    (``models/nemotron_h.py`` TINY)."""
+    return SyntheticTokens(**{"vocab_size": 256, **kwargs})

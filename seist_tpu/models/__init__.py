@@ -10,6 +10,7 @@ from seist_tpu.models.losses import (  # noqa: F401
     HuberLoss,
     MousaviLoss,
     MSELoss,
+    TokenCELoss,
 )
 
 # Import model modules for their registration side effects.
@@ -19,6 +20,7 @@ from seist_tpu.models import (  # noqa: F401
     ditingmotion,
     eqtransformer,
     magnet,
+    nemotron_h,
     phasenet,
     seist,
 )

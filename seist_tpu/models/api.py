@@ -21,6 +21,19 @@ def create_model(model_name: str, in_channels: int = 3, in_samples: int = 8192, 
     )
 
 
+def example_input(model, batch_size: int, in_samples: int, in_channels: int,
+                  *, abstract: bool = False):
+    """The input a model is initialised on: a float waveform (N, L, C), or
+    integer ids (N, L) for a model that says ``input_kind = "tokens"``."""
+    if getattr(model, "input_kind", "waveform") == "tokens":
+        shape, dtype = (batch_size, in_samples), jnp.int32
+    else:
+        shape, dtype = (batch_size, in_samples, in_channels), jnp.float32
+    if abstract:
+        return jax.ShapeDtypeStruct(shape, dtype)
+    return jnp.zeros(shape, dtype)
+
+
 def init_variables(
     model,
     seed: int = 0,
@@ -33,7 +46,7 @@ def init_variables(
     The whole init is jitted: flax init executed op-by-op compiles hundreds of
     tiny XLA programs; one fused program is ~50x faster.
     """
-    x = jnp.zeros((batch_size, in_samples, in_channels), dtype=jnp.float32)
+    x = example_input(model, batch_size, in_samples, in_channels)
     key = jax.random.PRNGKey(seed)
 
     @jax.jit
@@ -48,7 +61,7 @@ def param_shapes(
     model, in_samples: int = 8192, in_channels: int = 3
 ) -> Dict[str, Any]:
     """Shape-only init (no compute) — for counting/inspection."""
-    x = jax.ShapeDtypeStruct((1, in_samples, in_channels), jnp.float32)
+    x = example_input(model, 1, in_samples, in_channels, abstract=True)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     return jax.eval_shape(
         lambda k, x: model.init({"params": k, "dropout": k}, x, train=False), key, x

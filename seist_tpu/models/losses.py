@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Callable, List, Optional, Sequence, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -200,3 +201,33 @@ __all__ = [
     "CombinationLoss",
     "MousaviLoss",
 ]
+
+
+def _token_targets(logits, targets):
+    valid = targets >= 0
+    return valid, jnp.where(valid, targets, 0).astype(jnp.int32)
+
+
+class TokenCELoss:
+    """Mean next-token cross-entropy in float32 over the vocabulary the
+    logits span. ``logits`` (N, L, V) in any float dtype, ``targets`` (N, L)
+    integer ids, negative where a position has no target (the last of a
+    window). No auxiliary term."""
+
+    reduction = "mean"
+
+    def __call__(self, logits, targets):
+        valid, safe = _token_targets(logits, targets)
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
+        nll = jnp.where(valid, lse - picked, 0.0)
+        return nll.sum() / jnp.maximum(valid.sum(), 1).astype(jnp.float32)
+
+
+def token_hits(logits, targets):
+    """(positions whose largest logit is the target, positions with a
+    target): token accuracy's numerator and denominator."""
+    valid, safe = _token_targets(logits, targets)
+    hit = (jnp.argmax(logits, axis=-1).astype(jnp.int32) == safe) & valid
+    return hit.sum(), valid.sum()

@@ -48,6 +48,17 @@ REGIONS: Tuple[Tuple[str, str], ...] = (
     ("mlp", r"/mlp_path[)/]"),
     ("stage_aggr", r"/stage\d+_aggr/"),
     ("head", r"/out_head/"),
+    # NemotronH (models/nemotron_h.py): its attention mixer is an
+    # ``attn_path`` too and falls under ``attention`` above
+    ("ssm_scan", r"(^|[/(])ssm_scan[)/]"),
+    ("ssm_proj", r"(^|[/(])ssm_proj[)/]"),
+    ("moe_router", r"(^|[/(])moe_router[)/]"),
+    # (the TPU compiler names the grouped product it builds from
+    # ``ragged_dot`` ``ragged-dot-*`` and drops the scope path)
+    ("moe_experts", r"(^|[/(])moe_experts[)/]|^ragged-dot-"),
+    ("moe_shared", r"(^|[/(])moe_shared[)/]"),
+    ("embed", r"(^|[/(])embed[)/]"),
+    ("lm_head", r"(^|[/(])lm_head[)/]"),
     # PhaseNet (models/phasenet.py)
     ("conv_down", r"/down\d+/"),
     ("conv_up", r"/up\d+/"),

@@ -32,7 +32,8 @@ import jax.numpy as jnp
 SOFT = "soft"
 VALUE = "value"
 ONEHOT = "onehot"
-_IO_KINDS = (SOFT, VALUE, ONEHOT)
+TOKENS = "tokens"  # integer ids of a sequence, (L,) int32, never normalised
+_IO_KINDS = (SOFT, VALUE, ONEHOT, TOKENS)
 
 AVAILABLE_METRICS = (
     "precision",
@@ -95,6 +96,10 @@ IO_ITEMS: Dict[str, IOItem] = {
         IOItem("dis", VALUE, _REGR_METRICS),
         IOItem("pmp", ONEHOT, _CLS_METRICS, num_classes=2),
         IOItem("clr", ONEHOT, _CLS_METRICS, num_classes=2),
+        # A token task: the input is a window of ids, the label the same
+        # window shifted by one (-1 where the window ends: no target).
+        IOItem("ids", TOKENS, ()),
+        IOItem("next_ids", TOKENS, ()),
     ]
 }
 
@@ -170,6 +175,11 @@ class TaskSpec:
     targets_transform_for_loss: Optional[Callable] = None
     outputs_transform_for_loss: Optional[Callable] = None
     outputs_transform_for_results: Optional[Callable] = None
+    #: A token task: integer ids in, logits over a vocabulary out, scored by
+    #: loss and token accuracy (no picker, no per-task metrics). Its train
+    #: step hands back the model's ``aux`` counts in place of the outputs
+    #: (the logits of one step are a gigabyte nobody reads).
+    tokens: bool = False
 
     def matches(self, model_name: str) -> bool:
         return bool(re.findall(self.pattern, model_name))
@@ -266,6 +276,15 @@ def _build_task_specs() -> List[TaskSpec]:
             labels=("dis",),
             eval=("dis",),
         ),
+        # ----------------------------------- NemotronH (models/nemotron_h.py)
+        TaskSpec(
+            pattern="nemotron",
+            loss=L.TokenCELoss,
+            inputs=("ids",),
+            labels=("next_ids",),
+            eval=(),
+            tokens=True,
+        ),
     ]
 
 
@@ -312,6 +331,8 @@ def flatten_io_names(names: Sequence[IOName]) -> List[str]:
 def get_num_inchannels(model_name: str) -> int:
     """Number of waveform input channels. Ref: config.py:396-408."""
     spec = get_task_spec(model_name)
+    if spec.tokens:
+        return 1  # one integer channel: the ids
     for inp in spec.inputs:
         if isinstance(inp, (tuple, list)) and IO_ITEMS[inp[0]].kind == SOFT:
             return len(inp)

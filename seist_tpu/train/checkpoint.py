@@ -76,15 +76,35 @@ def _as_abstract(tree):
     )
 
 
+class _Snapshot(np.ndarray):
+    """A host array that only its save holds. orbax's numpy handler
+    deep-copies every leaf before it returns (the caller might go on writing
+    to it while the background write reads); of a snapshot taken for the
+    save that second copy protects nothing and was 8.0-9.4 s of an 8 GB
+    save's 10.6-12.7 s, the device idle (PERF.md, PR 29)."""
+
+    def __deepcopy__(self, memo):
+        return self
+
+
 def _host_copy(tree):
-    """Deep-copy a pytree to host numpy. Async saves serialize in the
-    background while the train loop keeps stepping with DONATED state
-    buffers; on the CPU backend np-views of those buffers would be
-    silently rewritten mid-serialization, so the snapshot must own its
-    memory."""
-    return jax.tree_util.tree_map(
-        lambda x: np.array(x) if hasattr(x, "shape") else x, tree
-    )
+    """Snapshot a pytree to host numpy that owns its memory. Async saves
+    serialize in the background while the train loop keeps stepping with
+    DONATED state buffers; on the CPU backend np-views of those buffers
+    would be silently rewritten mid-serialization, so there the snapshot is
+    a copy. From an accelerator the device-to-host transfer already is one
+    (a second copy of an 8 GB state would be 8 GB of host memory for
+    nothing), leaf after leaf so that the transfers' staging stays one
+    leaf large."""
+
+    def one(x):
+        if not hasattr(x, "shape"):
+            return x
+        on_cpu = not isinstance(x, jax.Array) or all(
+            d.platform == "cpu" for d in x.devices())
+        return (np.array(x) if on_cpu else np.asarray(x)).view(_Snapshot)
+
+    return jax.tree_util.tree_map(one, tree)
 
 
 def _state_payload(state) -> Dict[str, Any]:
@@ -239,6 +259,10 @@ class TrainCheckpointManager:
                 f"checkpoint step {step} already exists in {self.directory}; "
                 "refusing to overwrite (pass on_exists='skip' to tolerate)"
             )
+        # The previous save's snapshot goes before this one is taken (orbax
+        # would wait for that write inside its own save anyway): two
+        # snapshots of a state that fills a chip do not fit a small host.
+        self.wait()
         payload = _host_copy(_state_payload(state))
         payload["meta"] = {
             "epoch": int(epoch),

@@ -77,7 +77,13 @@ def _forward_loss(spec: TaskSpec, loss_fn: Callable, cdtype, apply_fn) -> Callab
     apply with mutable BN stats, cast outputs back to fp32, apply the task
     transforms. Returns ``compute(params, stats, inputs, targets, key) ->
     (loss, (outputs, new_stats))`` — differentiable in ``params`` (arg 0).
+
+    A token task (``spec.tokens``) gets the scalars its model sowed into the
+    ``aux`` collection in place of ``outputs``: the counts the host reads
+    with the epoch's losses, where the logits would be a gigabyte nobody
+    reads.
     """
+    tokens = bool(getattr(spec, "tokens", False))
 
     def compute(params, stats, inputs, targets, key):
         has_stats = stats is not None
@@ -92,14 +98,17 @@ def _forward_loss(spec: TaskSpec, loss_fn: Callable, cdtype, apply_fn) -> Callab
                     variables,
                     cast_floating(inputs, cdtype),
                     train=True,
-                    mutable=["batch_stats"] if has_stats else [],
+                    mutable=(["batch_stats"] if has_stats else [])
+                    + (["aux"] if tokens else []),
                     rngs={"dropout": key},
                 )
-            outputs, mutated = out if has_stats else (out[0], {})
+            outputs, mutated = out if (has_stats or tokens) else (out[0], {})
             outputs = cast_to_float32(outputs)
         with jax.named_scope("loss"):
             o, t = _apply_transforms(spec, outputs, targets)
             loss = loss_fn(o, t)
+        if tokens:  # sow keeps a tuple per name: the one value of this call
+            outputs = {k: v[-1] for k, v in mutated.get("aux", {}).items()}
         return loss, (outputs, mutated.get("batch_stats"))
 
     return compute

@@ -212,7 +212,9 @@ def _build_loader(args: Any, spec: taskspec.TaskSpec, mode: str) -> pipeline.Loa
         seed=args.seed,
         data_dir=args.data,
         in_samples=args.in_samples,
-        augmentation=args.augmentation,
+        # a token sequence has nothing to augment (and the 2x-epoch rule
+        # would only repeat it)
+        augmentation=args.augmentation and not spec.tokens,
         shuffle=args.shuffle,
         data_split=args.data_split,
         train_size=args.train_size,
@@ -320,6 +322,35 @@ def _update_task_metrics(
         metrics_merged[task].add(m)
 
 
+@jax.jit
+def _token_hits(logits, targets, mask):
+    from seist_tpu.models.losses import token_hits
+
+    rows = (mask > 0)[:, None]
+    return token_hits(logits, jax.numpy.where(rows, targets, -1))
+
+
+def _feed_token_counters(aux_per_step: List[Dict[str, Any]]) -> None:
+    """A token task's per-step counts (fetched with the epoch's losses)
+    onto the bus: ``tokens_trained``, ``moe_slots_local`` (token-slots on
+    experts held), ``moe_overflow_rows`` (slots that found no room in the
+    experts' buffer: must stay 0) and the gauge
+    ``moe_load_max_over_mean``."""
+    if not aux_per_step:
+        return
+    counters = {
+        name: obs.BUS.counter(name)
+        for name in ("tokens_trained", "moe_slots_local", "moe_overflow_rows")
+    }
+    for aux in aux_per_step:
+        counters["tokens_trained"].inc(float(aux["tokens"]))
+        if "moe_slots_local" in aux:
+            for name in ("moe_slots_local", "moe_overflow_rows"):
+                counters[name].inc(float(aux[name]))
+            obs.BUS.gauge("moe_load_max_over_mean").set(
+                float(aux["moe_load_max_over_mean"]))
+
+
 def validate(
     args: Any,
     state,
@@ -345,8 +376,11 @@ def validate(
     metrics_merged = _make_metrics(args, tasks, fs)
     loss_meter = AverageMeter("loss", ":.4e")
     saver = (
-        ResultSaver(item_names=tasks) if (save_results and is_main_process()) else None
+        ResultSaver(item_names=tasks)
+        if (save_results and is_main_process() and not spec.tokens)
+        else None
     )
+    token_counts: List[Any] = []  # per batch (hits, positions), on the device
 
     # Four spans partition a pass (docs/OBSERVABILITY.md): the wait on the
     # host loader, the eval call with the loss fetch (the device's share and
@@ -372,6 +406,14 @@ def validate(
             # jaxlint: disable=host-sync-item-loop -- one scalar per VAL batch; the running meter (and the float(loss) next line) needs it now
             global_valid = int(np.asarray(jax.device_get(batch.mask.sum())))
             loss_meter.update(float(loss), max(global_valid, 1))
+        if spec.tokens:
+            # No picker and no per-task scores: token accuracy, counted on
+            # the device from the logits (padded rows carry real targets,
+            # so they are weighted out like the loss's).
+            with obs.BUS.span("val_metrics"):
+                token_counts.append(
+                    _token_hits(outputs, batch.loss_targets, batch.mask))
+            continue
         with obs.BUS.span("val_postprocess"):
             results = _postprocess_batch(args, spec, outputs, fs)
         with obs.BUS.span("val_metrics"):
@@ -409,6 +451,17 @@ def validate(
         logger.info(f"Test results saved: {out_csv}")
 
     phase = "test" if testing else "val"
+    if spec.tokens:
+        with obs.BUS.span("val_metrics"):  # one fetch for the whole pass
+            fetched = jax.device_get(token_counts)
+        token_hits = sum(int(h) for h, _ in fetched)
+        token_count = sum(int(c) for _, c in fetched)
+        accuracy = token_hits / max(token_count, 1)
+        obs.BUS.gauge(f"{phase}_token_accuracy").set(accuracy)
+        logger.info(
+            f"[{phase}] {args.model_name} tokens: loss {loss_meter.avg:.4f} "
+            f"accuracy {accuracy:.4f} over {token_count} positions"
+        )
     for task, m in metrics_merged.items():
         logger.info(f"[{phase}] {args.model_name} {task}: {m}")
     return loss_meter.avg, metrics_merged
@@ -1235,6 +1288,7 @@ def train_worker(args: Any) -> str:
         # only diagnostics — TB scalars and the progress line). Per-step
         # losses are kept as device scalars and fetched once per epoch.
         deferred_losses: List[Any] = []
+        deferred_aux: List[Any] = []  # a token task's per-step counts
         global_bs = args.batch_size * jax.process_count()
         # Loader-death handling (io_guard.watch on_death): checkpoint at
         # the last completed batch and preempt-exit. `batches_done` is
@@ -1460,6 +1514,49 @@ def train_worker(args: Any) -> str:
                         )
 
         else:
+            # The progress line reads a loss back. Reading the newest
+            # step's would empty the device's queue every --log-step
+            # steps, and whatever then holds the host up is the device's
+            # idle time: beside a checkpoint's background write the next
+            # dispatches took 0.2-0.5 s each where they take 3 ms (PERF.md,
+            # PR 29). So the line is made `monitor.lag` steps late, where
+            # the guard reads too: that step has ended by then and two
+            # more are queued behind it.
+            late_logs: "collections.deque" = collections.deque()
+
+            def _log_step(step, gstep, loss, outputs, batch) -> None:
+                loss_f = float(loss)
+                loss_meter.update(loss_f, 1)
+                interval = lap()
+                steps_done = min(args.log_step, step) or 1
+                wps_meter.update(global_bs * steps_done / max(interval, 1e-9))
+                g_loss.set(loss_f)
+                g_wps.set(wps_meter.val)
+
+                batch_metrics = {}
+                if tasks:  # a token task has nothing to pick
+                    results = _postprocess_batch(args, spec, outputs, fs)
+                    batch_metrics = _make_metrics(args, tasks, fs)
+                    _update_task_metrics(
+                        metrics_merged,
+                        batch_metrics,
+                        results,
+                        batch.metrics_targets,
+                        args.batch_size,
+                    )
+                if writer is not None:
+                    writer.add_scalar("train-loss/step", loss_f, gstep)
+                    for task, m in batch_metrics.items():
+                        writer.add_scalars(
+                            f"train.{task}.metrics/step",
+                            m.get_all_metrics(),
+                            gstep,
+                        )
+                if is_main_process():
+                    logger.info(
+                        f"{args.model_name}_train {progress.get_str(step)}"
+                    )
+
             for step, batch in enumerate(
                 obs.timed_iter(
                     io_guard.watch(
@@ -1484,6 +1581,8 @@ def train_worker(args: Any) -> str:
                         )
                     )
                 deferred_losses.append(loss)
+                if spec.tokens:
+                    deferred_aux.append(outputs)
                 if diag is not None and monitor.push(diag["applied"]):
                     state = _rollback(state)
                 _maybe_trace(step, loss)
@@ -1493,37 +1592,11 @@ def train_worker(args: Any) -> str:
                     _preempt_exit(state, epoch, step + 1, gstep + 1)
 
                 if step % args.log_step == 0:
-                    loss_f = float(loss)
-                    loss_meter.update(loss_f, 1)
-                    interval = lap()
-                    steps_done = min(args.log_step, step) or 1
-                    wps_meter.update(
-                        global_bs * steps_done / max(interval, 1e-9)
-                    )
-                    g_loss.set(loss_f)
-                    g_wps.set(wps_meter.val)
-
-                    results = _postprocess_batch(args, spec, outputs, fs)
-                    batch_metrics = _make_metrics(args, tasks, fs)
-                    _update_task_metrics(
-                        metrics_merged,
-                        batch_metrics,
-                        results,
-                        batch.metrics_targets,
-                        args.batch_size,
-                    )
-                    if writer is not None:
-                        writer.add_scalar("train-loss/step", loss_f, gstep)
-                        for task, m in batch_metrics.items():
-                            writer.add_scalars(
-                                f"train.{task}.metrics/step",
-                                m.get_all_metrics(),
-                                gstep,
-                            )
-                    if is_main_process():
-                        logger.info(
-                            f"{args.model_name}_train {progress.get_str(step)}"
-                        )
+                    late_logs.append((step, gstep, loss, outputs, batch))
+                while late_logs and step - late_logs[0][0] >= monitor.lag:
+                    _log_step(*late_logs.popleft())
+            while late_logs:  # the epoch's tail
+                _log_step(*late_logs.popleft())
 
         if tracing:  # epoch shorter than the capture window
             # Sync first: steps may still be executing asynchronously, and
@@ -1538,9 +1611,10 @@ def train_worker(args: Any) -> str:
             state = _rollback(state)
         # The device finishing the calls the host ran ahead of.
         with obs.BUS.span("epoch_drain"):
-            epoch_losses = [
-                float(l) for l in jax.device_get(deferred_losses)
-            ]
+            epoch_losses, epoch_aux = jax.device_get(
+                (deferred_losses, deferred_aux))
+            epoch_losses = [float(l) for l in epoch_losses]
+        _feed_token_counters(epoch_aux)
         train_losses.extend(epoch_losses)
         # Exact epoch mean from every step's loss (the meter only samples
         # every log_step steps, for the progress line). Guard-skipped steps

@@ -240,7 +240,7 @@ def test_named_scopes_change_metadata_only(monkeypatch):
 FAMILIES = [  # one per registered model family, at its rehearsal size
     ("seist_s_dpk", 512), ("phasenet", 512), ("eqtransformer", 6000),
     ("magnet", 512), ("baz_network", 512), ("distpt_network", 512),
-    ("ditingmotion", 128),
+    ("ditingmotion", 128), ("nemotron3_tiny", 256),
 ]
 # baz_network's eigendecomposition has no bf16 lowering on the CPU backend;
 # distpt_network is registered without a task spec (three channels).
@@ -251,10 +251,11 @@ def test_families_cover_the_registry():
     seist_tpu.load_all()
     from seist_tpu import registry
 
-    families = {name.split("_")[0] if name.startswith("seist") else name
-                for name in registry.MODELS.names()}
-    assert families == {n.split("_")[0] if n.startswith("seist") else n
-                        for n, _ in FAMILIES}
+    def family(name):  # a family's presets share its first word
+        return name.split("_")[0] if name.startswith(("seist", "nemotron3")) else name
+
+    families = {family(name) for name in registry.MODELS.names()}
+    assert families == {family(n) for n, _ in FAMILIES}
 
 
 @pytest.mark.parametrize("name, in_samples", FAMILIES)
@@ -282,7 +283,7 @@ def test_model_family_ops_have_owners(name, in_samples):
             compute_dtype="fp32" if name in FP32_ONLY else "bf16", guard=True),
         donate_state=False,
     )
-    x = jax.ShapeDtypeStruct((4, in_samples, in_channels), jnp.float32)
+    x = api.example_input(model, 4, in_samples, in_channels, abstract=True)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     text = step.jitted.lower(state, x, None, key).compile().as_text()
     m = scopes.parse_hlo(text)
@@ -296,5 +297,8 @@ def test_model_family_ops_have_owners(name, in_samples):
     expected = {"seist_s_dpk": {"stem", "msmc", "attention", "mlp", "head",
                                 "stage_aggr"},
                 "phasenet": {"conv_down", "conv_up"},
-                "eqtransformer": {"lstm"}, "magnet": {"lstm"}}
+                "eqtransformer": {"lstm"}, "magnet": {"lstm"},
+                "nemotron3_tiny": {"ssm_proj", "ssm_scan", "moe_router",
+                                   "moe_experts", "moe_shared", "embed",
+                                   "lm_head", "attention"}}
     assert expected.get(name, set()) <= regions, regions

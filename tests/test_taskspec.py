@@ -7,8 +7,10 @@ from seist_tpu.models import losses as L
 
 
 def test_io_item_catalog_complete():
-    # The 20 io-items of the reference catalog (config.py:207-264).
+    # The io-items of the reference catalog (config.py:207-264) and the
+    # two of the token task (ids in, the same shifted by one as the label).
     expected = {
+        "ids", "next_ids",
         "z", "n", "e", "dz", "dn", "de", "non", "det", "ppk", "spk",
         "ppk+", "spk+", "det+", "ppks", "spks", "emg", "smg", "baz",
         "dis", "pmp", "clr",
