@@ -27,6 +27,26 @@ device count. (The host path's numpy analogue is
 different generator, so host and device runs are each reproducible but
 not bit-identical to each other.)
 
+Batch-wide by construction
+--------------------------
+Every op here is written for ONE sample and ``vmap``ped over the batch,
+and what ``vmap`` makes of a per-row ``lax.dynamic_slice`` /
+``dynamic_update_slice`` is a gather / a scatter with a different start in
+every row — which the TPU compiler expands into a ``while`` loop that
+walks the batch ONE ROW an iteration, about 2 us each however few bytes
+move. The soft labels were once placed that way (slice 26 samples out of a
+padded buffer, add the window, write them back, per phase slot): twelve
+loops, 3072 serial iterations and 6.3 of the region's 8.6 ms a step at
+batch 256, to move 80 KB (PERF.md, PR 30). :func:`soft_label_place` is
+therefore dense: each slot evaluates its curve over all the window's
+columns from the one ``make_soft_window`` table, which the compiler fuses
+into a few elementwise passes. Write the next op the same way: masks over
+``jnp.arange(length)`` (as ``add_gaps``, ``generate_noise`` and
+``add_event_once`` do) instead of a data-dependent slice. The two per-row
+moves that remain, ``shift_event``'s roll and ``cut_window``'s crop, are
+such loops too (0.97 ms a step); ``tests/test_chip_compile.py`` counts
+them, so a third shows up there before it shows up on a chip.
+
 Golden parity
 -------------
 Integer draws are derived as ``low + min(floor(u * (high-low)),
@@ -483,21 +503,28 @@ def soft_label_place(idxs, valid, window_arr, length: int):
     """Sum label windows centered at ``idxs`` (ref preprocess.py:567-619):
     out-of-range indices (idx < 0 or idx > length-1) contribute NOTHING
     (the reference skips them entirely, not partially); in-range windows
-    are edge-cropped."""
+    are edge-cropped.
+
+    Dense on purpose (module docstring, "Batch-wide by construction"):
+    every slot evaluates its curve at all ``length`` columns from the one
+    window table — column ``c`` reads entry ``c - (idx - left)`` where
+    that lies in ``[0, width]`` and adds 0.0 elsewhere. Each sample is the
+    same sum of the same float32 table entries in the same slot order as
+    slicing and updating a padded buffer would give, bit for bit."""
     width = window_arr.shape[0] - 1
     left = width // 2
-    off = width + 1
-    buf = jnp.zeros((length + 2 * off,), jnp.float32)
     wf = window_arr.astype(jnp.float32)
+    cols = jnp.arange(length, dtype=jnp.int32)
+    label = jnp.zeros((length,), jnp.float32)
     for j in range(idxs.shape[0]):
         idx = idxs[j]
         ok = valid[j] & (idx >= 0) & (idx <= length - 1)
-        start = jnp.where(ok, idx - left + off, 0)
-        seg = jax.lax.dynamic_slice(buf, (start,), (width + 1,))
-        buf = jax.lax.dynamic_update_slice(
-            buf, seg + jnp.where(ok, wf, 0.0), (start,)
-        )
-    return buf[off : off + length]
+        rel = cols - (idx - left)
+        inside = ok & (rel >= 0) & (rel <= width)
+        # mode="clip": the look-up clamps by itself; `inside` masks what
+        # the clamp reads off either end of the table.
+        label = label + jnp.where(inside, jnp.take(wf, rel, mode="clip"), 0.0)
+    return label
 
 
 def label_pick(cfg: AugConfig, vals, n, window_arr):

@@ -5,12 +5,21 @@ attached, nothing runs. What Mosaic/XLA:TPU refuse here (a slice off the
 tiling, too much VMEM, a program over 16 GB) they refuse on the chip too, so
 these guard every later PR at no chip time. A pass is not a chip run.
 
+Beside the kernels: the augmentation and label synthesis of the cached
+train path (``data/device_aug.make_cache_processor``) at the benchmark's row
+shape, for what the compiler makes of its per-row indexing — a gather or a
+scatter that ``vmap`` made out of a per-row ``dynamic_slice`` /
+``dynamic_update_slice`` becomes a ``while`` over the batch, one row an
+iteration (PERF.md, PR 30).
+
 The topology is described inside a module-scoped fixture (never at import,
 never in conftest.py, never autouse, never in a child process): only one
 process may load the TPU library, and under xdist every worker imports this
 file. The persistent compile cache is turned off around the compiles — an
 executable compiled for a described chip cannot be read back without one.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -180,3 +189,54 @@ def test_data_parallel_kernel_compiles_for_four_v5e_chips(
     # each chip's kernel sees its quarter of the batch, not all of it
     assert f"bf16[{BATCH // 4},{l},{he}]" in text
     assert f"bf16[{BATCH},{l},{he}]" not in text
+
+
+# ------------------------------------------------- augmentation's row loops
+AUG_LABELS = [("det", "ppk", "spk"), ("non", "ppk", "spk")]
+
+
+@pytest.mark.parametrize("labels", AUG_LABELS, ids=["-".join(l) for l in AUG_LABELS])
+def test_device_aug_walks_the_batch_only_for_the_roll_and_the_crop(
+    one_chip, no_compile_cache, labels
+):
+    """The benchmark cells' label sets at their row shape (3 x 12000 raw,
+    8192 window, one phase slot, the trainer's default rates), batch 32:
+    the program for the chip holds two serial loops over the rows under
+    ``device_aug`` — ``shift_event``'s roll and ``cut_window``'s crop —
+    where it held fourteen while ``soft_label_place`` sliced and updated a
+    padded buffer per row (six placements: a gather loop and a scatter
+    loop each). A new per-row ``dynamic_slice`` shows up here as a third."""
+    from seist_tpu.data import device_aug as da
+
+    cfg = da.AugConfig(
+        seed=0, window=8192, raw_len=12000, channels=3, phase_slots=1,
+        data_channels=("z", "n", "e"), sampling_rate=100, coda_ratio=2.0,
+        min_event_gap=50, shift_event_rate=0.2, generate_noise_rate=0.05,
+        drop_channel_rate=0.4, scale_amplitude_rate=0.4,
+        pre_emphasis_rate=0.4, add_noise_rate=0.4, add_gap_rate=0.4,
+    )
+    n_raw = 256
+    process = da.make_cache_processor(
+        cfg, (("z", "n", "e"),), (labels,), n_raw=n_raw, augmentation=True
+    )
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = {
+        "data": struct((n_raw, 3, 12000), jnp.float32),
+        "ppks": struct((n_raw, 1), jnp.int32),
+        "np_p": struct((n_raw,), jnp.int32),
+        "spks": struct((n_raw, 1), jnp.int32),
+        "np_s": struct((n_raw,), jnp.int32),
+    }
+    text = (
+        jax.jit(process)
+        .lower(cache, struct((BATCH,), jnp.int32), struct((), jnp.int32))
+        .compile()
+        .as_text()
+    )
+    # op_name of every `while` the region owns ('.' stops at the line's end)
+    loops = re.findall(r' while\(.*op_name="([^"]*device_aug[^"]*)"', text)
+    assert len(loops) <= 2, loops
+    assert not any("scatter" in op for op in loops), loops

@@ -10,6 +10,7 @@ per-op and end-to-end through ``process()`` + label synthesis.
 """
 
 import copy
+import re
 
 import numpy as np
 import pytest
@@ -21,7 +22,7 @@ import seist_tpu
 from seist_tpu import taskspec
 from seist_tpu.data import device_aug as da
 from seist_tpu.data import pipeline as pl
-from seist_tpu.data.preprocess import DataPreprocessor
+from seist_tpu.data.preprocess import DataPreprocessor, make_soft_window
 
 seist_tpu.load_all()
 
@@ -80,6 +81,10 @@ def phase_arrays(ppks, spks, P=4):
         list(v) + [da._BIG] * (P - len(v)), jnp.int32
     )
     return arr(ppks), jnp.int32(len(ppks)), arr(spks), jnp.int32(len(spks))
+
+
+def soft_window(width, shape):
+    return jnp.asarray(make_soft_window(width, shape), jnp.float32)
 
 
 # --------------------------------------------------------------- per-op parity
@@ -259,9 +264,7 @@ class TestPerOpParity:
     def test_soft_labels(self, shape):
         pre = make_pre(soft_label_shape=shape)
         cfg = make_cfg(pre)
-        from seist_tpu.data.preprocess import make_soft_window
-
-        window = jnp.asarray(make_soft_window(40, shape), jnp.float32)
+        window = soft_window(40, shape)
         # edge placements: left-clipped, middle, right-clipped, out-of-range
         ev = {"data": np.zeros((C, W), np.float32),
               "ppks": [3, 250], "spks": [40, W - 2], "snr": [20.0] * C}
@@ -289,6 +292,200 @@ class TestPerOpParity:
             n = int(n)
             assert list(np.asarray(dp)[:n]) == ref_p, (ppks, spks)
             assert list(np.asarray(ds)[:n]) == ref_s, (ppks, spks)
+
+
+# ------------------------------------------------- batch-wide label synthesis
+def _loop_soft_label_place(idxs, valid, window_arr, length: int):
+    """The ORACLE: ``soft_label_place`` as it stood until the dense form
+    replaced it — slice ``width + 1`` samples out of a padded buffer, add
+    the window, write them back, slot by slot. Right, and under ``vmap`` a
+    batch-long serial gather loop and scatter loop per slot on the TPU,
+    which is why the module no longer holds it. Kept verbatim: the dense
+    form must equal it bit for bit."""
+    width = window_arr.shape[0] - 1
+    left = width // 2
+    off = width + 1
+    buf = jnp.zeros((length + 2 * off,), jnp.float32)
+    wf = window_arr.astype(jnp.float32)
+    for j in range(idxs.shape[0]):
+        idx = idxs[j]
+        ok = valid[j] & (idx >= 0) & (idx <= length - 1)
+        start = jnp.where(ok, idx - left + off, 0)
+        seg = jax.lax.dynamic_slice(buf, (start,), (width + 1,))
+        buf = jax.lax.dynamic_update_slice(
+            buf, seg + jnp.where(ok, wf, 0.0), (start,)
+        )
+    return buf[off : off + length]
+
+
+LABEL_SHAPES = ["gaussian", "triangle", "box", "sigmoid"]
+LABEL_WIDTHS = [8, 21, 50]
+LW = 256  # label length of the placement cases
+
+
+def _place_cases(width):
+    """case -> (idxs (rows, 2), valid (rows, 2)): each case a batch of
+    rows, so that the comparison runs under ``vmap`` as the step does."""
+    left = width // 2
+    far = LW + 10 * width
+    mid = LW // 2
+    T, F = True, False
+    cases = {
+        # never a cropped part of a window: nothing at all
+        "below_zero": ([[-1, far], [-left, far], [-width - 3, far],
+                        [-(2**30), far]], [[T, F]] * 4),
+        "above_end": ([[LW, -7], [LW + left, -7], [LW + width + 3, -7],
+                       [da._BIG, -7]], [[T, F]] * 4),
+        # inside, cropped at an edge
+        "near_edges": ([[0, LW - 1], [1, LW - 2], [left - 1, LW - left],
+                        [left, LW - 1 - left], [width, LW - 1 - width],
+                        [left + 1, LW - width]], [[T, T]] * 6),
+        "overlapping": ([[mid, mid], [mid, mid + 1], [mid, mid + left],
+                         [mid, mid + width], [mid + width, mid],
+                         [3, 5], [LW - 2, LW - 4]], [[T, T]] * 7),
+        "invalid_slot": ([[mid, mid + 2], [mid, mid + 2], [mid, mid + 2],
+                          [2, LW - 3]],
+                         [[T, F], [F, T], [F, F], [F, T]]),
+    }
+    return {
+        k: (jnp.asarray(i, jnp.int32), jnp.asarray(v, bool))
+        for k, (i, v) in cases.items()
+    }
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.int32)
+
+
+class TestBatchWideLabels:
+    @pytest.mark.parametrize(
+        "case",
+        ["below_zero", "above_end", "near_edges", "overlapping",
+         "invalid_slot"],
+    )
+    @pytest.mark.parametrize("width", LABEL_WIDTHS)
+    @pytest.mark.parametrize("shape", LABEL_SHAPES)
+    def test_place_equals_the_loop_form_bit_for_bit(self, shape, width, case):
+        window = soft_window(width, shape)
+        idxs, valid = _place_cases(width)[case]
+
+        def batch(place):
+            return jax.jit(
+                jax.vmap(lambda i, v: place(i, v, window, LW))
+            )(idxs, valid)
+
+        new = batch(da.soft_label_place)
+        old = batch(_loop_soft_label_place)
+        assert new.shape == (idxs.shape[0], LW)
+        np.testing.assert_array_equal(_bits(new), _bits(old))
+        if case in ("below_zero", "above_end"):
+            assert not np.asarray(new).any()  # skipped whole, not cropped
+        else:
+            assert np.asarray(new).any()
+
+    def test_place_equals_the_loop_form_on_random_rows(self):
+        """Many slots, random indices well off both edges, random valid
+        masks: every sample the same sum in the same order."""
+        rng = np.random.default_rng(30)
+        window = soft_window(21, "triangle")  # has a negative last entry
+        idxs = jnp.asarray(rng.integers(-40, LW + 40, (300, 6)), jnp.int32)
+        valid = jnp.asarray(rng.random((300, 6)) < 0.8)
+        out = [
+            jax.jit(jax.vmap(lambda i, v: f(i, v, window, LW)))(idxs, valid)
+            for f in (da.soft_label_place, _loop_soft_label_place)
+        ]
+        np.testing.assert_array_equal(_bits(out[0]), _bits(out[1]))
+
+    # The golden parity cases of test_soft_labels (a left-clipped, a
+    # middle and a right-clipped placement) plus the phase lists that
+    # make pad_phases prepend and append its sentinels, as ONE batch.
+    PHASE_LISTS = [
+        ([3, 250], [40, W - 2]),
+        ([10, 50, 90], [30, 80]),   # trailing unmatched P -> S sentinel
+        ([50], [30]),               # inverted pair -> both sentinels
+        ([120], [125]),             # overlapping P and S windows
+        ([W - 1], []),
+        ([], [0]),
+        ([], []),
+    ]
+
+    @pytest.mark.parametrize("width", LABEL_WIDTHS)
+    @pytest.mark.parametrize("shape", LABEL_SHAPES)
+    def test_non_det_match_host_and_loop_form(self, shape, width, monkeypatch):
+        """``label_non`` / ``label_det`` under vmap: equal to the host
+        ``DataPreprocessor`` at the golden parity tolerance, and bit for
+        bit to what the loop form gave."""
+        pre = make_pre(soft_label_shape=shape, soft_label_width=width)
+        cfg = make_cfg(pre)
+        window = soft_window(width, shape)
+        rows = [phase_arrays(p, s) for p, s in self.PHASE_LISTS]
+        batch = [jnp.stack(col) for col in zip(*rows)]
+
+        def run():
+            return {
+                name: np.asarray(jax.jit(jax.vmap(
+                    lambda pp, npp, ss, nss: fn(
+                        cfg, pp, npp, ss, nss, window)
+                ))(*batch))
+                for name, fn in (("non", da.label_non), ("det", da.label_det))
+            }
+
+        new = run()
+        monkeypatch.setattr(da, "soft_label_place", _loop_soft_label_place)
+        old = run()
+        for name in ("non", "det"):
+            np.testing.assert_array_equal(_bits(new[name]), _bits(old[name]))
+            for row, (ppks, spks) in enumerate(self.PHASE_LISTS):
+                ev = {"data": np.zeros((C, W), np.float32),
+                      "ppks": list(ppks), "spks": list(spks),
+                      "snr": [20.0] * C}
+                ref = pre._generate_soft_label(name, ev)
+                np.testing.assert_allclose(
+                    new[name][row], ref, rtol=1e-5, atol=1e-5,
+                    err_msg=f"{name} {ppks} {spks}",
+                )
+
+    @pytest.mark.parametrize(
+        "labels", [("det", "ppk", "spk"), ("non", "ppk", "spk")],
+        ids=["det-ppk-spk", "non-ppk-spk"],
+    )
+    def test_lowered_row_processor_moves_no_label_row_by_row(self, labels):
+        """Holds the mechanism in place: a per-row ``dynamic_slice`` /
+        ``dynamic_update_slice`` under ``vmap`` lowers to a gather / a
+        scatter that the TPU walks one row at a time. Of those the row
+        processor may keep the roll and the crop of the waveform, integer
+        phase look-ups and look-ups in the (width + 1)-entry window table;
+        nothing is scattered, and no gather reads a label-sized buffer."""
+        pre = make_pre(max_event_num=1, add_event_rate=0.0)
+        cfg = make_cfg(pre, phase_slots=1)
+        table = cfg.soft_label_width + 1
+        proc = da.make_row_processor(cfg, [["z", "n", "e"]], [list(labels)])
+        B = 4
+        rows = {
+            "data": jnp.zeros((B, C, L), jnp.float32),
+            "ppks": jnp.zeros((B, 1), jnp.int32),
+            "np_p": jnp.zeros((B,), jnp.int32),
+            "spks": jnp.zeros((B, 1), jnp.int32),
+            "np_s": jnp.zeros((B,), jnp.int32),
+        }
+        text = jax.jit(proc).lower(
+            rows, jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool),
+            jnp.int32(0),
+        ).as_text()
+        assert "stablehlo.scatter" not in text
+        assert "dynamic_update_slice" not in text
+        operands = re.findall(
+            r"stablehlo\.(?:dynamic_)?gather\"?\(.*?:\s*\(tensor<([^>]*)>", text
+        )
+        assert operands, "the roll and the crop are gathers: parse failed"
+        float_minors = set()
+        for operand in operands:
+            *dims, dtype = operand.split("x")
+            if dtype == "f32":
+                float_minors.add(int(dims[-1]))
+        # the window table, the raw row (crop) and its doubled copy (roll)
+        assert float_minors <= {table, L, 2 * L}, float_minors
+        assert L in float_minors  # the crop is there: the parse sees gathers
 
 
 # --------------------------------------------------------- composed parity
