@@ -15,11 +15,13 @@ jitted step over a device mesh:
 from __future__ import annotations
 
 import collections
+import dataclasses
 import json
 import os
 import signal
 import sys
 import threading
+import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -30,6 +32,7 @@ from seist_tpu.data import io_guard, pipeline
 from seist_tpu.models import api
 from seist_tpu.ops import Metrics, ResultSaver, process_outputs
 from seist_tpu.parallel import mesh as mesh_lib
+from seist_tpu.train import feed as feed_lib
 from seist_tpu.train import (
     PREEMPT_EXIT_CODE,
     TrainCheckpointManager,
@@ -195,15 +198,6 @@ class _BadUpdateMonitor:
             )
 
 
-def _mixture_temperature(args: Any, mode: str) -> float:
-    """--mixture-temperature applies to TRAIN sampling only: evaluation
-    walks every source's split plainly so per-source metrics stay
-    comparable across temperature settings."""
-    if mode != "train":
-        return 0.0
-    return float(getattr(args, "mixture_temperature", 0.0) or 0.0)
-
-
 def _build_loader(args: Any, spec: taskspec.TaskSpec, mode: str) -> pipeline.Loader:
     sds = pipeline.from_task_spec(
         spec,
@@ -265,7 +259,7 @@ def _build_loader(args: Any, spec: taskspec.TaskSpec, mode: str) -> pipeline.Loa
         seed=args.seed,
         num_shards=jax.process_count(),
         shard_index=jax.process_index(),
-        mixture_temperature=_mixture_temperature(args, mode),
+        mixture_temperature=feed_lib.mixture_temperature(args, mode),
     )
 
 
@@ -467,7 +461,7 @@ def validate(
     return loss_meter.avg, metrics_merged
 
 
-# Cleanup callbacks registered by the running worker (its _obs_close);
+# Cleanup callbacks registered by the running worker (its _Telemetry.close);
 # drained by _dump_flight_on_exception's finally so EVERY exit path —
 # return, sys.exit, uncaught exception — tears the telemetry plane down
 # (os._exit hard deaths skip it; the process is gone anyway).
@@ -507,67 +501,315 @@ def _dump_flight_on_exception(fn):
     return wrapper
 
 
-@_dump_flight_on_exception
-def train_worker(args: Any) -> str:
-    """Full training run; returns the best checkpoint path
-    (ref train.py:182-484)."""
-    spec = taskspec.get_task_spec(args.model_name)
-    loss_fn = spec.loss()
-    seq_shards = int(getattr(args, "seq_shards", 1) or 1)
-    mesh = mesh_lib.make_mesh(seq=seq_shards)
-    logger.info(
-        f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}, "
-        f"process {jax.process_index()}/{jax.process_count()}, "
-        f"devices: {json.dumps(device_summary())}"
-    )
-    if seq_shards > 1:
-        logger.info(
-            f"Sequence parallelism: ring attention over {seq_shards} shards "
-            f"(--seq-shards); dropout semantics match dense training"
+class _Telemetry:
+    """A run's telemetry plane (docs/OBSERVABILITY.md): the flight recorder,
+    the events log, the opt-in Prometheus endpoint and the SIGUSR2 profiler
+    trigger, opened at set-up and closed once on whichever path the run
+    ends."""
+
+    def __init__(self, args: Any):
+        # Flight recorder: always on (a deque append per step — priced in
+        # BENCH step_breakdown.telemetry); every death path dumps it.
+        # Any --flight-steps <= 0 falls back to the documented default
+        # rather than crashing the run at startup.
+        fsteps = int(getattr(args, "flight_steps", 0) or 0)
+        self.recorder = obs.FlightRecorder(capacity=fsteps if fsteps > 0 else 256)
+        obs.flight.install(self.recorder)
+        obs.register_default_collectors()
+        self.events = (
+            obs.EventLog(os.path.join(logger.logdir(), "events.jsonl"))
+            if is_main_process()
+            else None
         )
-    data_axis = mesh.shape[mesh_lib.AXIS_DATA]
-    if (args.batch_size * jax.process_count()) % data_axis:
-        raise ValueError(
-            f"global batch size {args.batch_size * jax.process_count()} must "
-            f"be divisible by the mesh 'data' axis ({data_axis} devices)"
-        )
-
-    # Set-up phases as spans (explicit begin/end: the phases are this
-    # function's own sections). With jit_first_call and the first
-    # train_epoch they cover the program's part of a run's set-up.
-    setup = obs.BUS.begin("setup_loaders")
-    train_loader = _build_loader(args, spec, "train")
-    val_loader = _build_loader(args, spec, "val")
-    # Validation builds the same batches in every pass: keep the first
-    # whole pass resident and replay it (independent of the model state,
-    # so a rollback keeps it and a restart refills it).
-    resident_val = pipeline.ResidentEvalPass()
-    fs = train_loader.dataset.sampling_rate()
-    setup.end()
-
-    steps_per_epoch = len(train_loader)
-    if steps_per_epoch == 0:
-        raise ValueError("Train split is empty — check data_dir / split sizes")
-    # `--steps > 0` overrides epochs (ref train.py:250-253).
-    epochs = args.epochs
-    if args.steps > 0:
-        epochs = max(1, int(np.ceil(args.steps / steps_per_epoch)))
-    total_steps = steps_per_epoch * epochs
-
-    # Gradient accumulation: k loader batches -> ONE optimizer update
-    # (step.py make_accum_train_step). state.step counts UPDATES and the
-    # LR schedule follows it, so the schedule length shrinks by k.
-    gas = max(1, int(getattr(args, "grad_accum_steps", 1) or 1))
-    if gas > 1:
-        if steps_per_epoch // gas == 0:
-            raise ValueError(
-                f"--grad-accum-steps {gas} exceeds steps_per_epoch "
-                f"{steps_per_epoch}: every epoch would apply ZERO updates"
+        # Opt-in Prometheus endpoint (--metrics-port; obs/http.py): >0 binds
+        # that loopback port, -1 an ephemeral one (logged), 0 disables.
+        self.profile_trigger = profile_trigger = obs.ProfileTrigger()
+        self.metrics_server = None
+        mport = int(getattr(args, "metrics_port", 0) or 0)
+        if mport and is_main_process():
+            self.metrics_server = obs.start_metrics_server(
+                max(mport, 0), profile_trigger=profile_trigger
             )
-        total_steps = (steps_per_epoch // gas) * epochs
+        # SIGUSR2 -> on-demand profiler capture at the next step boundary
+        # (same window machinery as --profile-steps and POST /profile).
+        self._prev_usr2 = None
+        if (
+            threading.current_thread() is threading.main_thread()
+            and hasattr(signal, "SIGUSR2")
+        ):
+            def _on_usr2(signum, frame):
+                # threadlint: disable=signal-handler-unsafe -- request() is a
+                # single lock-free GIL-atomic deque append (ProfileTrigger is
+                # deliberately lockless for exactly this call site: the
+                # interrupted main thread may be inside consume()).
+                profile_trigger.request()
+                # threadlint: disable=signal-handler-unsafe -- best-effort
+                # notice; logging's RLock is reentrant from the interrupted
+                # main thread, worst case interleaved output.
+                logger.info(
+                    "[obs] SIGUSR2: profiler capture requested "
+                    f"({obs.http.DEFAULT_PROFILE_STEPS} steps)"
+                )
+            self._prev_usr2 = signal.signal(signal.SIGUSR2, _on_usr2)
+        self._closed = False
+        _OBS_CLEANUP.append(self.close)
 
-    # Model + optimizer + state (+ restore).
-    setup = obs.BUS.begin("setup_init")
+    def close(self) -> None:
+        """Tear down the telemetry plane. Idempotent; runs on the normal
+        return, the preempt exit, AND — via _OBS_CLEANUP drained in the
+        _dump_flight_on_exception finally — every exception/SystemExit
+        path, so a crashed run cannot leave the metrics port bound or
+        the events fd open for the process's next run. Uninstalling the
+        recorder also unhooks its bus span sink, so back-to-back runs in
+        one process never stack sinks."""
+        if self._closed:
+            return
+        self._closed = True
+        obs.flight.install(None)
+        if self.events is not None:
+            self.events.close()
+        if self.metrics_server is not None:
+            self.metrics_server.shutdown()
+            self.metrics_server.server_close()  # release the listening port
+        if self._prev_usr2 is not None:
+            try:
+                signal.signal(signal.SIGUSR2, self._prev_usr2)
+            except ValueError:  # not the main thread anymore
+                pass
+
+    def emit(self, kind: str, **fields) -> None:
+        self.recorder.record_event(kind, **fields)
+        if self.events is not None:
+            self.events.emit(kind, **fields)
+
+
+class _ProfileWindow:
+    """--profile-steps N: capture a jax.profiler trace of N steady-state
+    OPTIMIZER steps (skipping compile/warmup) in the first trained epoch.
+    Counted in optimizer steps whatever the feed (each call advances
+    ``updates_per_call`` of them). Later captures are re-armed on demand:
+    SIGUSR2 or POST /profile on --metrics-port."""
+
+    def __init__(self, args: Any, updates_per_call: int, telemetry: _Telemetry):
+        self.steps = int(getattr(args, "profile_steps", 0) or 0)
+        self.updates_per_call = updates_per_call
+        self.start = 2 * updates_per_call  # skip the first two calls
+        self.tracing = False
+        self.dir = ""
+        self.telemetry = telemetry
+
+    def step(self, opt_step: int, loss) -> None:
+        """``opt_step``: optimizer steps completed before this call."""
+        if not is_main_process():
+            return
+        if not self.tracing:
+            # On-demand capture (SIGUSR2 / POST /profile): open the
+            # window at the next step boundary. Consume ONLY when idle —
+            # a request arriving mid-capture stays in the trigger box and
+            # opens its own window once this one closes.
+            req = self.telemetry.profile_trigger.consume()
+            if req:
+                self.steps = req
+                self.start = opt_step + self.updates_per_call
+                self.telemetry.emit("profile_requested", steps=req)
+        if not self.steps:
+            return
+        if not self.tracing and opt_step >= self.start:
+            # Unique per supervise attempt AND per capture window
+            # (timestamp + pid + no-clobber suffix): a relaunched run must
+            # never overwrite the previous attempt's trace.
+            self.dir = get_safe_path(
+                os.path.join(
+                    logger.logdir(), "profile",
+                    f"{get_time_str()}_p{os.getpid()}",
+                )
+            )
+            profiling.trace_start(self.dir)
+            self.tracing = True
+        elif self.tracing and opt_step >= self.start + self.steps:
+            self._stop(loss)
+            logger.info(f"Profiler trace saved: {self.dir}")
+
+    def end_epoch(self, losses: List[Any]) -> None:
+        if self.tracing:  # epoch shorter than the capture window
+            self._stop(losses)
+            logger.info(f"Profiler trace saved (short epoch): {self.dir}")
+
+    def _stop(self, pending) -> None:
+        # Sync first: steps may still be executing asynchronously, and
+        # stopping early would truncate their device activity.
+        jax.block_until_ready(pending)
+        profiling.trace_stop()
+        self.tracing = False
+        self.steps = 0  # one-shot; the trigger re-arms it
+
+
+@dataclasses.dataclass
+class _Run:
+    """One training run: what set-up built, where the loop stands (``state``,
+    ``epoch``, ``batches_done``: what a death path checkpoints) and what the
+    epochs have yielded so far."""
+
+    args: Any
+    spec: taskspec.TaskSpec
+    mesh: Any
+    train_loader: pipeline.Loader
+    val_loader: pipeline.Loader
+    # Validation builds the same batches in every pass: the first whole
+    # pass is kept resident and replayed (independent of the model state,
+    # so a rollback keeps it and a restart refills it).
+    resident_val: pipeline.ResidentEvalPass
+    steps_per_epoch: int
+    epochs: int
+    state: Any
+    start_epoch: int
+    start_batch: int  # mid-epoch resume offset (batches already consumed)
+    feed: feed_lib.Feed
+    train_step: Any
+    eval_step: Any
+    writer: Optional[ScalarWriter]
+    ckpt_mgr: TrainCheckpointManager
+    save_every: int
+    faults: faults_lib.FaultInjector
+    watchdog: Optional[io_guard.StallWatchdog]
+    telemetry: _Telemetry
+    monitor: _BadUpdateMonitor
+    preempt: _PreemptionHandler
+    profile: _ProfileWindow
+    epoch: int = 0
+    batches_done: int = 0
+    best_loss: float = float("inf")
+    best_ckpt_path: str = ""
+    patience_counter: int = 0
+    train_losses: List[float] = dataclasses.field(default_factory=list)
+    val_losses: List[float] = dataclasses.field(default_factory=list)
+    epoch_times: List[float] = dataclasses.field(default_factory=list)
+
+
+def _step_out(ret):
+    """Normalize (state, loss, outputs[, diag]) across guard on/off."""
+    if len(ret) == 4:
+        return ret
+    s, l, o = ret
+    return s, l, o, None
+
+
+def _save(run: _Run, epoch: int, batches_done: int, **kw) -> str:
+    """Async save of the state with its data position: at a
+    --save-interval-steps boundary, at the end of the best epoch so far
+    (``val_loss=``, which feeds the manager's keep-best retention) and on
+    the preempt exit (``wait=True``). The recorded position is the NEXT
+    batch to consume; returns the checkpoint's path."""
+    gstep = epoch * run.steps_per_epoch + batches_done
+    with obs.BUS.span("checkpoint_save"):
+        return run.ckpt_mgr.save(
+            gstep,
+            run.state,
+            epoch=epoch,
+            data_epoch=gstep // run.steps_per_epoch,
+            data_batch_offset=gstep % run.steps_per_epoch,
+            seed=run.args.seed,
+            steps_per_epoch=run.steps_per_epoch,
+            batch_size=int(run.args.batch_size),
+            # resume/rollback may re-reach a step, and an interval save may
+            # own an epoch's end
+            on_exists="skip",
+            **kw,
+        )
+
+
+def _rollback(run: _Run):
+    """Bad-update-guard rollback: restore the last checkpoint (params
+    + optimizer) and continue from the CURRENT data position."""
+    monitor = run.monitor
+    run.ckpt_mgr.wait()
+    step_r = run.ckpt_mgr.latest_step()
+    if step_r is None:
+        raise RuntimeError(
+            f"{monitor.bad_run} consecutive non-finite updates and no "
+            "checkpoint to roll back to — aborting (enable "
+            "--save-interval-steps for rollback coverage)"
+        )
+    logger.warning(
+        f"Bad-update guard: {monitor.bad_run} consecutive non-finite "
+        f"updates; rolling back to checkpoint step {step_r}"
+    )
+    # The run survives a rollback, but the steps leading into it are
+    # exactly what a post-mortem wants — snapshot them now, before
+    # the ring rolls past (docs/OBSERVABILITY.md).
+    run.telemetry.emit(
+        "bad_update_rollback",
+        rollback_to_step=int(step_r),
+        consecutive_bad=int(monitor.bad_run),
+    )
+    # arm_dedup=False: this dump is non-fatal (the run continues) and
+    # must never suppress the record of a crash seconds later.
+    obs.flight.dump_on_death(
+        "bad_update_rollback", arm_dedup=False,
+        rollback_to_step=int(step_r),
+    )
+    restored = run.ckpt_mgr.restore(run.state, step=step_r)
+    monitor.reset()
+    return mesh_lib.replicate(run.mesh, restore_into_state(run.state, restored))
+
+
+def _preempt_exit(run: _Run, epoch: int, batches_done: int, hard: bool = False):
+    """Step-boundary preemption: make the final checkpoint durable
+    (wait=True barriers the async write), then exit with the
+    documented preempt code for tools/supervise.py.
+
+    ``hard=True`` (the loader-death path) ends in ``os._exit``: the
+    data plane is known-wedged and its pool threads are non-daemon,
+    so ``sys.exit`` would hang forever in ``threading._shutdown``
+    joining a thread stuck inside a dead read — the exact hang this
+    machinery exists to eliminate. The watchdog is left armed as the
+    escalation if even the final save wedges."""
+    if run.watchdog is not None and not hard:
+        run.watchdog.stop()
+    gstep = epoch * run.steps_per_epoch + batches_done
+    d_epoch, d_off = divmod(gstep, run.steps_per_epoch)  # the next batch
+    _save(run, epoch, batches_done, wait=True)
+    logger.warning(
+        f"Preempted: checkpoint step {gstep} durable "
+        f"(data position {d_epoch}:{d_off}); exiting {PREEMPT_EXIT_CODE}"
+    )
+    run.telemetry.emit(
+        "preempt", gstep=int(gstep), data_epoch=int(d_epoch),
+        data_batch_offset=int(d_off), hard=bool(hard),
+    )
+    obs.flight.dump_on_death("preempt", gstep=int(gstep))
+    if run.writer is not None:
+        run.writer.close()
+    run.train_loader.close()
+    run.val_loader.close()
+    run.ckpt_mgr.close()
+    run.telemetry.close()
+    if hard:
+        io_guard.hard_exit(PREEMPT_EXIT_CODE)
+    sys.exit(PREEMPT_EXIT_CODE)
+
+
+def _loader_death_exit(run: _Run, e, epoch: int, batches_done: int):
+    """Loader-thread death (data/io_guard.py LoaderDeathError): the
+    device and params are healthy — checkpoint the current position
+    and preempt-exit so the supervisor relaunches with a fresh data
+    plane rather than the run dying opaquely (or, pre-watchdog,
+    hanging forever)."""
+    logger.error(
+        f"Loader worker death: {e}; dumping thread stacks and "
+        "preempt-exiting for supervised relaunch"
+    )
+    io_guard.dump_thread_stacks()
+    if run.watchdog is not None:
+        # Escalation: the data plane is wedged; if the final save
+        # below hangs too, the watchdog's os._exit still gets us out.
+        run.watchdog.arm()
+    _preempt_exit(run, epoch, batches_done, hard=True)
+
+
+def _init_state(args: Any, steps_per_epoch: int, total_steps: int):
+    """Model + optimizer + state (+ restore): ``(state, start_epoch,
+    start_batch)``, the state placed as the jitted step leaves it."""
     in_channels = taskspec.get_num_inchannels(args.model_name)
     model = api.create_model(
         args.model_name, in_channels=in_channels, in_samples=args.in_samples
@@ -614,7 +856,7 @@ def train_worker(args: Any) -> str:
     state = create_train_state(model, variables, tx)
 
     start_epoch = args.start_epoch
-    start_batch = 0  # mid-epoch resume offset (batches already consumed)
+    start_batch = 0
     if args.checkpoint:
         restored = load_checkpoint(args.checkpoint, state)
         state = restore_into_state(state, restored)
@@ -661,6 +903,356 @@ def train_worker(args: Any) -> str:
             f"batch offset {start_batch}, loss {float(meta['loss']):.4f}, "
             f"update step {int(state.step)})"
         )
+    return state, start_epoch, start_batch
+
+
+def _build_steps(args: Any, spec: taskspec.TaskSpec, feed: feed_lib.Feed, mesh):
+    """``(train_step, eval_step)``, jitted: the train step of the feed's
+    path, one row a kind. The ``make_*`` / ``jit_*`` names are THIS module's
+    globals, looked up when called: the benchmark's harness wraps and
+    replaces them here (benchmarks/drivers/train.py ``install_taps``,
+    benchmarks/tests/broken_run.py), so a step built through another module
+    would run untapped."""
+    loss_fn, k = spec.loss(), feed.batches_per_call
+    dtype = getattr(args, "dtype", "fp32")
+    # Bad-update guard: detect non-finite loss/grad-norm inside the jitted
+    # step, skip the poisoned update, and after max_bad_steps consecutive
+    # skips roll back to the last checkpoint (train/step.py
+    # _guarded_update; docs/FAULT_TOLERANCE.md).
+    kw = dict(compute_dtype=dtype, guard=bool(getattr(args, "bad_step_guard", True)))
+    if feed.kind == "cached":
+        train_step = jit_cached_call(
+            make_cached_train_call(
+                spec, loss_fn, feed.processor, steps_per_call=k, **kw
+            ),
+            mesh,
+            feed.cache.arrays,
+        )
+    elif feed.kind == "step":
+        train_step = jit_device_aug_step(
+            make_device_aug_train_step(spec, loss_fn, feed.processor, **kw), mesh
+        )
+    elif feed.kind == "packed" and feed.accumulate:
+        # One update from k micro-batch gradients, scanned in one jitted
+        # program; the stacked-batch layout shares jit_multi_step's sharding.
+        train_step = jit_multi_step(
+            make_accum_train_step(spec, loss_fn, accum_steps=k, **kw), mesh
+        )
+    elif feed.kind == "packed":
+        # k updates scanned inside one jitted program (dispatch amortization).
+        train_step = jit_multi_step(
+            make_multi_train_step(spec, loss_fn, steps_per_call=k, **kw), mesh
+        )
+    else:
+        train_step = jit_step(make_train_step(spec, loss_fn, **kw), mesh)
+    eval_step = jit_eval_step(
+        make_eval_step(spec, loss_fn, compute_dtype=dtype), mesh
+    )
+    return train_step, eval_step
+
+
+def _train_call(run: _Run, epoch: int, call: int, step_args, g_gstep):
+    """One call of the step, ``batches_per_call`` batches, every duty once:
+    the flight recorder's tag, the ``global_step`` gauge, the fault hook,
+    the dispatch, the bad-update monitor and its rollback, the profile
+    window, the interval save and the preempt exit. Returns the call's
+    ``(loss, outputs)``, both still on the device."""
+    feed, monitor, save_every = run.feed, run.monitor, run.save_every
+    k = feed.batches_per_call
+    first = epoch * run.steps_per_epoch + call * k
+    # Record BEFORE the spans of this call end, so the recorder tags them
+    # with the step that is actually running — the dying step's spans must
+    # carry its number.
+    run.telemetry.recorder.record_step(first)
+    g_gstep.set(first)
+    run.faults.on_step(first, n_steps=k)
+    with obs.BUS.span("step_dispatch"):
+        run.state, loss, outputs, diag = _step_out(
+            run.train_step(run.state, *step_args)
+        )
+    if diag is not None and monitor.push(diag["applied"]):
+        run.state = _rollback(run)
+    run.profile.step(call * feed.updates_per_call, loss)
+    run.batches_done = done = (call + 1) * k
+    if save_every and done // save_every > (done - k) // save_every:
+        _save(run, epoch, done)
+    if run.preempt.triggered:
+        _preempt_exit(run, epoch, done)
+    return loss, outputs
+
+
+def _train_epoch(run: _Run, epoch: int):
+    """One epoch's training (ref train.py:20-179), whatever the feed:
+    ``(losses, aux, train metrics, wave/s)``, the first two still on the
+    device, one entry a call."""
+    args, feed, monitor = run.args, run.feed, run.monitor
+    k, steps_per_epoch = feed.batches_per_call, run.steps_per_epoch
+    obs.BUS.gauge("epoch").set(epoch)
+    run.train_loader.set_epoch(epoch)
+    skip = run.start_batch if epoch == run.start_epoch else 0
+    if skip % k:
+        # A call consumes k batches; a checkpoint from a run with another
+        # k may sit off a call boundary.
+        logger.warning(
+            f"Resume offset {skip} is not a multiple of the packed "
+            f"group {k}; rounding down (re-trains {skip % k} "
+            "batch(es))"
+        )
+        skip = (skip // k) * k
+    if skip:
+        run.train_loader.set_start_batch(skip)
+        logger.info(f"Mid-epoch resume: epoch {epoch} from batch {skip}")
+    epoch_rng = jax.random.fold_in(jax.random.PRNGKey(args.seed), epoch)
+
+    tasks = list(run.spec.eval)
+    fs = run.train_loader.dataset.sampling_rate()
+    loss_meter = AverageMeter("loss", ":.4e")
+    wps_meter = AverageMeter("wave/s", ":.1f")
+    metrics_merged = _make_metrics(args, tasks, fs)
+    progress = ProgressMeter(
+        steps_per_epoch, [loss_meter, wps_meter], prefix=f"Epoch[{epoch}] "
+    )
+    # Log-interval clock for wave/s (seconds since the last log line).
+    lap = _LapClock()
+    global_bs = args.batch_size * jax.process_count()
+    # Bus handles resolved once an epoch (a per-step gauge set is then one
+    # lock, no registry lookup). All interval clocks are obs spans on the
+    # shared monotonic source: an NTP step or suspend must not corrupt ETA
+    # or throughput math on a days-long run.
+    g_loss = obs.BUS.gauge("train_loss")
+    g_wps = obs.BUS.gauge("waveforms_per_sec")
+    g_gstep = obs.BUS.gauge("global_step")
+    # Device->host transfers are confined to every --log-step calls:
+    # pulling loss/outputs every step serializes JAX's async dispatch
+    # and stalls the chip on host postprocess (the per-step numbers are
+    # only diagnostics — TB scalars and the progress line). Per-call
+    # losses are kept as device scalars and fetched once per epoch.
+    deferred_losses: List[Any] = []
+    deferred_aux: List[Any] = []  # a token task's per-step counts
+    # The progress line reads a loss back. Reading the newest call's would
+    # empty the device's queue every --log-step calls, and whatever then
+    # holds the host up is the device's idle time: beside a checkpoint's
+    # background write the next dispatches took 0.2-0.5 s each where they
+    # take 3 ms (PERF.md, PR 29). So the line is made `monitor.lag` calls
+    # late, where the guard reads too: that call has ended by then and two
+    # more are queued behind it.
+    late_logs: "collections.deque" = collections.deque()
+
+    def _log_call(call, loss, outputs, batch) -> None:
+        first = epoch * steps_per_epoch + call * k
+        loss_f = float(loss)
+        loss_meter.update(loss_f, 1)
+        calls_done = min(args.log_step, call) or 1
+        wps_meter.update(global_bs * k * calls_done / max(lap(), 1e-9))
+        g_loss.set(loss_f)
+        g_wps.set(wps_meter.val)
+        batch_metrics = {}
+        # Train metrics where the feed hands on a batch that can score the
+        # step's outputs (a token task has nothing to pick).
+        if tasks and batch is not None and batch.metrics_targets:
+            results = _postprocess_batch(args, run.spec, outputs, fs)
+            batch_metrics = _make_metrics(args, tasks, fs)
+            _update_task_metrics(
+                metrics_merged, batch_metrics, results,
+                batch.metrics_targets, args.batch_size,
+            )
+        if run.writer is not None:
+            run.writer.add_scalar("train-loss/step", loss_f, first)
+            for task, m in batch_metrics.items():
+                run.writer.add_scalars(
+                    f"train.{task}.metrics/step", m.get_all_metrics(), first
+                )
+        if is_main_process():
+            logger.info(f"{args.model_name}_train {progress.get_str(call * k)}")
+
+    # `run.epoch` / `run.batches_done` are what a loader death checkpoints
+    # (the feed's on_death reads them, and the latest state, at fire time).
+    run.epoch, run.batches_done = epoch, skip
+    for call, (step_args, batch) in enumerate(
+        feed.epoch(epoch, skip, epoch_rng), start=skip // k
+    ):
+        loss, outputs = _train_call(run, epoch, call, step_args, g_gstep)
+        deferred_losses.append(loss)
+        if run.spec.tokens and outputs is not None:
+            deferred_aux.append(outputs)
+        if call % args.log_step == 0:
+            late_logs.append((call, loss, outputs, batch))
+        while late_logs and call - late_logs[0][0] >= monitor.lag:
+            _log_call(*late_logs.popleft())
+    while late_logs:  # the epoch's tail
+        _log_call(*late_logs.popleft())
+
+    run.profile.end_epoch(deferred_losses)
+    if monitor.flush():  # lagging guard flags from the epoch tail
+        run.state = _rollback(run)
+    return deferred_losses, deferred_aux, metrics_merged, wps_meter.val
+
+
+def _end_epoch(run: _Run, epoch: int, epoch_span, trained) -> bool:
+    """An epoch's end: drain the device, report the data plane, validate,
+    checkpoint the best, close ``epoch_span``. True = stop early."""
+    args, steps_per_epoch = run.args, run.steps_per_epoch
+    deferred_losses, deferred_aux, metrics_merged, wps = trained
+    # The device finishing the calls the host ran ahead of.
+    with obs.BUS.span("epoch_drain"):
+        epoch_losses, epoch_aux = jax.device_get((deferred_losses, deferred_aux))
+        epoch_losses = [float(l) for l in epoch_losses]
+    _feed_token_counters(epoch_aux)
+    run.train_losses.extend(epoch_losses)
+    # Exact epoch mean from every call's loss (the meter only samples
+    # every log_step calls, for the progress line). Guard-skipped steps
+    # leave non-finite entries in the raw curve; the epoch mean is
+    # taken over the finite ones only.
+    finite_losses = [l for l in epoch_losses if np.isfinite(l)]
+    epoch_train_loss = float(np.mean(finite_losses)) if finite_losses else 0.0
+    for m in metrics_merged.values():
+        m.synchronize_between_processes()
+
+    # -- data-plane epoch report (docs/FAULT_TOLERANCE.md) ----------------
+    # Quarantined samples and guard counters, logged every epoch so a
+    # slowly-rotting dataset is visible long before the
+    # --max-quarantine-frac abort trips.
+    q_report = run.train_loader.dataset.quarantine_report()
+    if q_report["quarantined"]:
+        logger.warning(
+            f"[data-plane] epoch {epoch} quarantine report: "
+            f"{json.dumps(q_report)}"
+        )
+        run.telemetry.emit(
+            "quarantine_report", epoch=epoch,
+            quarantined=len(q_report["quarantined"]),
+            frac=q_report["frac"],
+        )
+    if io_guard.COUNTERS.any_faults():
+        logger.info(f"[data-plane] counters: {io_guard.COUNTERS.snapshot()}")
+
+    # -- validate + checkpoint (ref train.py:402-415) ---------------------
+    try:
+        with obs.BUS.span("validate"):
+            val_loss, val_metrics = validate(
+                args, run.state, run.eval_step, run.spec, run.val_loader,
+                run.mesh, watchdog=run.watchdog, resident=run.resident_val,
+            )
+    except io_guard.LoaderDeathError as e:
+        _loader_death_exit(run, e, epoch, steps_per_epoch)
+    obs.BUS.gauge("val_loss").set(val_loss)
+    run.val_losses.append(val_loss)
+    if run.writer is not None:
+        run.writer.add_scalar("train-loss/epoch", epoch_train_loss, epoch)
+        run.writer.add_scalar("val-loss/epoch", val_loss, epoch)
+        # Train metrics accumulated at --log-step cadence + psum'd
+        # across hosts above (ref train.py:420-442 logs both phases).
+        for phase, merged in (("train", metrics_merged), ("val", val_metrics)):
+            for task, m in merged.items():
+                run.writer.add_scalars(
+                    f"{phase}.{task}.metrics/epoch", m.get_all_metrics(), epoch
+                )
+
+    if val_loss < run.best_loss:
+        run.best_loss = val_loss
+        run.patience_counter = 0
+        # Checkpoint path is deterministic across hosts: step-numbered
+        # under the log_dir that cli.main_worker broadcast from process 0
+        # (replacing the reference's rank0 ckpt-path broadcast,
+        # train.py:481-482). The val metric feeds the manager's
+        # keep-best retention, so GC never deletes this step.
+        run.best_ckpt_path = _save(run, epoch, steps_per_epoch, val_loss=val_loss)
+    else:
+        run.patience_counter += 1
+        if run.patience_counter > args.patience:
+            logger.info(
+                f"Early stopping at epoch {epoch} "
+                f"(no val improvement in {args.patience} epochs)"
+            )
+            return True
+    if run.preempt.triggered:  # SIGTERM during validation
+        _preempt_exit(run, epoch, steps_per_epoch)
+
+    dt = epoch_span.end()
+    run.epoch_times.append(dt)
+    eta = float(np.mean(run.epoch_times)) * (run.epochs - epoch - 1)
+    logger.info(
+        f"Epoch {epoch}: train-loss {epoch_train_loss:.4e} "
+        f"val-loss {val_loss:.4e} best {run.best_loss:.4e} "
+        f"time {strftimedelta(dt)} ETA {strftimedelta(eta)}"
+    )
+    run.telemetry.emit(
+        "epoch_summary",
+        epoch=epoch,
+        train_loss=round(epoch_train_loss, 6),
+        val_loss=round(float(val_loss), 6),
+        best_loss=round(float(run.best_loss), 6),
+        epoch_time_s=round(dt, 3),
+        wps=round(wps, 1),
+        data_plane=io_guard.COUNTERS.snapshot(),
+    )
+    return False
+
+
+def _train_epochs(run: _Run) -> None:
+    """The epochs from the resume position on, until the last or the
+    early stop; each a ``train_epoch`` span."""
+    for epoch in range(run.start_epoch, run.epochs):
+        epoch_span = obs.BUS.begin("train_epoch")
+        if _end_epoch(run, epoch, epoch_span, _train_epoch(run, epoch)):
+            break
+
+
+@_dump_flight_on_exception
+def train_worker(args: Any) -> str:
+    """Full training run; returns the best checkpoint path
+    (ref train.py:182-484)."""
+    spec = taskspec.get_task_spec(args.model_name)
+    seq_shards = int(getattr(args, "seq_shards", 1) or 1)
+    mesh = mesh_lib.make_mesh(seq=seq_shards)
+    logger.info(
+        f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}, "
+        f"process {jax.process_index()}/{jax.process_count()}, "
+        f"devices: {json.dumps(device_summary())}"
+    )
+    if seq_shards > 1:
+        logger.info(
+            f"Sequence parallelism: ring attention over {seq_shards} shards "
+            f"(--seq-shards); dropout semantics match dense training"
+        )
+    data_axis = mesh.shape[mesh_lib.AXIS_DATA]
+    if (args.batch_size * jax.process_count()) % data_axis:
+        raise ValueError(
+            f"global batch size {args.batch_size * jax.process_count()} must "
+            f"be divisible by the mesh 'data' axis ({data_axis} devices)"
+        )
+
+    # Set-up phases as spans (explicit begin/end: the phases are this
+    # function's own sections). With jit_first_call and the first
+    # train_epoch they cover the program's part of a run's set-up.
+    setup = obs.BUS.begin("setup_loaders")
+    train_loader = _build_loader(args, spec, "train")
+    val_loader = _build_loader(args, spec, "val")
+    setup.end()
+
+    steps_per_epoch = len(train_loader)
+    if steps_per_epoch == 0:
+        raise ValueError("Train split is empty — check data_dir / split sizes")
+    # `--steps > 0` overrides epochs (ref train.py:250-253).
+    epochs = args.epochs
+    if args.steps > 0:
+        epochs = max(1, int(np.ceil(args.steps / steps_per_epoch)))
+    total_steps = steps_per_epoch * epochs
+
+    # Gradient accumulation: k loader batches -> ONE optimizer update
+    # (step.py make_accum_train_step). state.step counts UPDATES and the
+    # LR schedule follows it, so the schedule length shrinks by k.
+    gas = max(1, int(getattr(args, "grad_accum_steps", 1) or 1))
+    if gas > 1:
+        if steps_per_epoch // gas == 0:
+            raise ValueError(
+                f"--grad-accum-steps {gas} exceeds steps_per_epoch "
+                f"{steps_per_epoch}: every epoch would apply ZERO updates"
+            )
+        total_steps = (steps_per_epoch // gas) * epochs
+
+    setup = obs.BUS.begin("setup_init")
+    state, start_epoch, start_batch = _init_state(args, steps_per_epoch, total_steps)
     # Place the state where the jitted step leaves it (replicated over the
     # mesh) BEFORE the first step: a freshly built state carries no mesh in
     # its avals, the step's output does, and the difference would retrace
@@ -668,287 +1260,25 @@ def train_worker(args: Any) -> str:
     state = mesh_lib.replicate(mesh, state)
     setup.end()
 
-    dtype = getattr(args, "dtype", "fp32")
-    # Bad-update guard: detect non-finite loss/grad-norm inside the jitted
-    # step, skip the poisoned update, and after max_bad_steps consecutive
-    # skips roll back to the last checkpoint (train/step.py
-    # _guarded_update; docs/FAULT_TOLERANCE.md).
-    guard_on = bool(getattr(args, "bad_step_guard", True))
-    max_bad = int(getattr(args, "max_bad_steps", 3) or 0)
-    # steps_per_call <= 0 means "auto" (CLI default): 1 on the host path,
-    # raised high under --device-aug cached. An EXPLICIT 1 is honored there
-    # (per-step save/preempt granularity costs throughput but is a choice).
-    spc_raw = int(getattr(args, "steps_per_call", 0) or 0)
-    spc_auto = spc_raw <= 0
-    spc = max(1, spc_raw)
-    if spc > 1 and gas > 1:
-        raise ValueError(
-            "--steps-per-call and --grad-accum-steps are mutually "
-            "exclusive (both scan stacked micro-batches, with different "
-            "update semantics)"
-        )
-
-    # -- device-side augmentation (--device-aug; docs/DATA_PIPELINE.md) ----
-    # 'step': raw rows cross the host per step, augmentation + label
-    # synthesis run inside the jitted step. 'cached': whole raw epochs
-    # live in HBM and a scan executor consumes (k, B) index arrays — zero
-    # per-step host stacking. Unsupported configs fall back to the host
-    # path; an over-budget 'cached' falls back to 'step' (both logged).
     setup = obs.BUS.begin("setup_store")  # device store build and upload
-    device_req = str(getattr(args, "device_aug", "off") or "off")
-    device_mode = "off"
-    dev_store = dev_cache = None
-    sds_train = train_loader.dataset
-    # --ingest: how raw rows reach the device on the device-aug step path.
-    # 'auto' takes the direct shard->staging->device fast path whenever
-    # the dataset is packed (data/ingest.py), 'host' forces the resident
-    # RawStore upload, 'direct' demands the fast path and errors when the
-    # prerequisites are missing instead of degrading silently.
-    ingest_req = str(getattr(args, "ingest", "auto") or "auto")
-    if ingest_req not in ("auto", "direct", "host"):
-        raise ValueError(
-            f"--ingest must be auto|direct|host, got '{ingest_req}'"
-        )
-    if ingest_req == "direct" and device_req == "off":
-        raise ValueError(
-            "--ingest direct feeds the device-aug step path; run with "
-            "--device-aug step (docs/DATA.md)"
-        )
-    mixture_t = _mixture_temperature(args, "train")
-    src_ids_logical = sds_train.source_ids() if mixture_t > 0 else None
-    if device_req != "off":
-        from seist_tpu.data import device_aug as da
-
-        if gas > 1:
-            raise ValueError(
-                "--device-aug is incompatible with --grad-accum-steps "
-                "(accumulation scans stacked host batches)"
-            )
-        reasons = da.unsupported_reasons(
-            sds_train.preprocessor, sds_train.input_names,
-            sds_train.label_names,
-        )
-        budget = da.hbm_budget_bytes(
-            float(getattr(args, "device_aug_hbm_gb", 0.0) or 0.0)
-        )
-        # The cache shards its sample axis over the mesh 'data' axis, so
-        # the budget comparison is PER-DEVICE bytes vs per-device HBM —
-        # comparing the raw total would downgrade a 40 GiB dataset on an
-        # 8-chip mesh (5 GiB/chip) that actually fits.
-        est = 0
-        if not reasons:
-            try:
-                est = pipeline.RawStore.estimate_bytes(
-                    sds_train
-                ) // max(data_axis, 1)
-            except ValueError as e:
-                # The size probe reads raw sample 0 through the guarded
-                # path; a permanently-corrupt sample refuses the device
-                # store — same fallback as a build-time refusal: host
-                # path, whose quarantine machinery handles it.
-                reasons = [str(e)]
-        device_mode, why = da.select_device_aug_mode(
-            device_req, est, budget, reasons
-        )
-        if device_mode != device_req:
-            logger.warning(f"--device-aug {device_req} -> {device_mode}: {why}")
-        if ingest_req == "direct" and device_mode != "step":
-            # The ONE resolved-mode guard for --ingest direct (the
-            # pre-flight check above already rejected --device-aug off;
-            # a non-packed dataset is rejected by the build below).
-            raise ValueError(
-                "--ingest direct requires the device-aug step path; the "
-                f"run resolved --device-aug to '{device_mode}' ({why})"
-            )
-        if device_mode != "off":
-            from seist_tpu.data import ingest as ingest_lib
-
-            # Direct shard->device ingest: on a packed dataset the step
-            # path streams staging batches straight off the shard memmaps
-            # — no Event decode, no resident waveform upload. The cached
-            # mode keeps the RawStore (its whole point is HBM residency).
-            direct = device_mode == "step" and ingest_req != "host" and (
-                ingest_req == "direct"
-                or ingest_lib.packed_dataset_of(sds_train) is not None
-            )
-            if direct:
-                try:
-                    dev_store = ingest_lib.PackedRawStore.build(
-                        sds_train, batch_size=args.batch_size
-                    )
-                    logger.info(ingest_lib.describe(dev_store))
-                except ValueError as e:
-                    if ingest_req == "direct":
-                        raise
-                    logger.warning(
-                        f"packed direct ingest unavailable ({e}); "
-                        "uploading a resident RawStore instead"
-                    )
-                    direct = False
-            if not direct:
-                try:
-                    dev_store = pipeline.RawStore.build(sds_train)
-                except ValueError as e:
-                    logger.warning(f"--device-aug {device_mode} -> off: {e}")
-                    device_mode = "off"
-        if device_mode == "step" and spc > 1:
-            # Explicit 'step' + packing is a config error; but a 'cached'
-            # request that FELL BACK to 'step' must not crash on its
-            # now-meaningless packing flag.
-            if device_req == "step":
-                raise ValueError(
-                    "--steps-per-call > 1 requires --device-aug cached "
-                    "(the step mode feeds one raw batch per dispatch)"
-                )
-            logger.warning(
-                f"--steps-per-call {spc} ignored on the device-aug step "
-                "fallback path"
-            )
-            spc = 1
-        if (
-            device_mode != "off"
-            and faults_lib.FaultInjector.from_env().plan.nan_step >= 0
-        ):
-            raise ValueError(
-                "SEIST_FAULT_NAN_STEP corrupts host-fed input batches, "
-                "which the device-aug paths never materialize; use "
-                "--device-aug off for NaN-injection runs (process-level "
-                "faults — SIGTERM/kill/slow — work on every path)"
-            )
-
-    if device_mode != "off":
-        from seist_tpu.data import device_aug as da
-
-        dev_cfg = da.AugConfig.from_preprocessor(
-            sds_train.preprocessor,
-            seed=args.seed,
-            raw_len=dev_store.raw_len,
-            phase_slots=dev_store.phase_slots,
-        )
-        dev_proc_args = (
-            dev_cfg, sds_train.input_names, sds_train.label_names
-        )
-    if device_mode == "cached":
-        # steps_per_call defaults HIGH here: with epochs resident there is
-        # no host work to overlap, so the only per-step cost left is the
-        # dispatch — amortize it.
-        if spc_auto:
-            spc = max(1, min(32, steps_per_epoch))
-        if steps_per_epoch // spc == 0:
-            raise ValueError(
-                f"--steps-per-call {spc} exceeds steps_per_epoch "
-                f"{steps_per_epoch}: every epoch would train ZERO steps "
-                f"(trailing part-groups are dropped)"
-            )
-        dev_cache = pipeline.DeviceEpochCache(dev_store, mesh)
-        logger.info(
-            f"device-aug cached: {len(dev_store)} epoch samples resident "
-            f"({dev_cache.nbytes / 2**20:.1f} MiB HBM), "
-            f"steps_per_call={spc}"
-        )
+    feed = feed_lib.resolve_feed(args, train_loader, mesh, steps_per_epoch, gas)
     setup.end()
 
     setup = obs.BUS.begin("setup_steps")  # the step closures
-    if device_mode == "cached":
-        train_step = jit_cached_call(
-            make_cached_train_call(
-                spec, loss_fn,
-                da.make_cache_processor(
-                    *dev_proc_args,
-                    n_raw=dev_store.n_raw,
-                    augmentation=dev_store.augmentation,
-                ),
-                steps_per_call=spc, compute_dtype=dtype, guard=guard_on,
-            ),
-            mesh,
-            dev_cache.arrays,
-        )
-        if steps_per_epoch % spc:
-            logger.warning(
-                f"steps_per_call={spc} drops {steps_per_epoch % spc} "
-                f"trailing batch(es) per epoch ({steps_per_epoch} steps)"
-            )
-    elif device_mode == "step":
-        logger.info(
-            "device-aug step: augmentation + labels inside the jitted "
-            "step; host feeds raw rows only"
-        )
-        train_step = jit_device_aug_step(
-            make_device_aug_train_step(
-                spec, loss_fn,
-                da.make_row_processor(*dev_proc_args),
-                compute_dtype=dtype, guard=guard_on,
-            ),
-            mesh,
-        )
-    elif gas > 1:
-        # One update from gas micro-batch gradients, scanned in one jitted
-        # program; stacked-batch layout shares jit_multi_step's sharding.
-        if steps_per_epoch % gas:
-            logger.warning(
-                f"grad_accum_steps={gas} drops {steps_per_epoch % gas} "
-                f"trailing batch(es) per epoch ({steps_per_epoch} steps)"
-            )
-        train_step = jit_multi_step(
-            make_accum_train_step(
-                spec, loss_fn, compute_dtype=dtype, accum_steps=gas,
-                guard=guard_on,
-            ),
-            mesh,
-        )
-        logger.info(
-            f"grad_accum_steps={gas}: effective batch "
-            f"{args.batch_size * gas * jax.process_count()}, "
-            f"{steps_per_epoch // gas} updates/epoch"
-        )
-    elif spc > 1:
-        # k updates scanned inside one jitted program (dispatch
-        # amortization; step.py make_multi_train_step). Per-step output
-        # metrics are skipped on this path — the scan returns no
-        # per-micro-step outputs.
-        if steps_per_epoch // spc == 0:
-            raise ValueError(
-                f"--steps-per-call {spc} exceeds steps_per_epoch "
-                f"{steps_per_epoch}: every epoch would train ZERO steps "
-                f"(trailing part-groups are dropped)"
-            )
-        if steps_per_epoch % spc:
-            logger.warning(
-                f"steps_per_call={spc} drops {steps_per_epoch % spc} "
-                f"trailing batch(es) per epoch ({steps_per_epoch} steps)"
-            )
-        train_step = jit_multi_step(
-            make_multi_train_step(
-                spec, loss_fn, compute_dtype=dtype, steps_per_call=spc,
-                guard=guard_on,
-            ),
-            mesh,
-        )
-        logger.info(f"steps_per_call={spc}: scanned multi-step training")
-    else:
-        train_step = jit_step(
-            make_train_step(spec, loss_fn, compute_dtype=dtype, guard=guard_on),
-            mesh,
-        )
-    eval_step = jit_eval_step(
-        make_eval_step(spec, loss_fn, compute_dtype=dtype), mesh
-    )
+    train_step, eval_step = _build_steps(args, spec, feed, mesh)
     setup.end()
-    base_rng = jax.random.PRNGKey(args.seed)
 
     # Scalar writer (its TensorBoard backend imports torch), checkpoint
     # manager, watchdog, telemetry plane, signal handlers.
     setup = obs.BUS.begin("setup_writers")
-
     writer = (
         ScalarWriter(os.path.join(logger.logdir(), "tensorboard"))
         if (args.use_tensorboard and is_main_process())
         else None
     )
-    ckpt_dir = os.path.join(logger.logdir(), "checkpoints")
-    save_every = int(getattr(args, "save_interval_steps", 0) or 0)
     ckpt_mgr = TrainCheckpointManager(
-        ckpt_dir, keep_last=int(getattr(args, "keep_checkpoints", 3) or 3)
+        os.path.join(logger.logdir(), "checkpoints"),
+        keep_last=int(getattr(args, "keep_checkpoints", 3) or 3),
     )
     if args.checkpoint:
         # Manual rollback (resume from an older step while newer step
@@ -979,775 +1309,59 @@ def train_worker(args: Any) -> str:
     watchdog = (
         io_guard.StallWatchdog(wd_timeout).start() if wd_timeout > 0 else None
     )
-
-    # -- telemetry plane (docs/OBSERVABILITY.md) --------------------------
-    # Flight recorder: always on (a deque append per step — priced in
-    # BENCH step_breakdown.telemetry); every death path below dumps it.
-    # Any --flight-steps <= 0 falls back to the documented default
-    # rather than crashing the run at startup.
-    fsteps = int(getattr(args, "flight_steps", 0) or 0)
-    recorder = obs.FlightRecorder(capacity=fsteps if fsteps > 0 else 256)
-    obs.flight.install(recorder)
-    obs.register_default_collectors()
-    events = (
-        obs.EventLog(os.path.join(logger.logdir(), "events.jsonl"))
-        if is_main_process()
-        else None
+    telemetry = _Telemetry(args)
+    run = _Run(
+        args=args, spec=spec, mesh=mesh,
+        train_loader=train_loader, val_loader=val_loader,
+        resident_val=pipeline.ResidentEvalPass(),
+        steps_per_epoch=steps_per_epoch, epochs=epochs,
+        state=state, start_epoch=start_epoch, start_batch=start_batch,
+        feed=feed, train_step=train_step, eval_step=eval_step,
+        writer=writer, ckpt_mgr=ckpt_mgr,
+        save_every=int(getattr(args, "save_interval_steps", 0) or 0),
+        faults=faults, watchdog=watchdog, telemetry=telemetry,
+        monitor=_BadUpdateMonitor(int(getattr(args, "max_bad_steps", 3) or 0)),
+        preempt=_PreemptionHandler(),
+        profile=_ProfileWindow(args, feed.updates_per_call, telemetry),
     )
-    # Opt-in Prometheus endpoint (--metrics-port; obs/http.py): >0 binds
-    # that loopback port, -1 an ephemeral one (logged), 0 disables.
-    profile_trigger = obs.ProfileTrigger()
-    metrics_server = None
-    mport = int(getattr(args, "metrics_port", 0) or 0)
-    if mport and is_main_process():
-        metrics_server = obs.start_metrics_server(
-            max(mport, 0), profile_trigger=profile_trigger
-        )
-    # SIGUSR2 -> on-demand profiler capture at the next step boundary
-    # (same window machinery as --profile-steps and POST /profile).
-    prev_usr2 = None
-    if (
-        threading.current_thread() is threading.main_thread()
-        and hasattr(signal, "SIGUSR2")
-    ):
-        def _on_usr2(signum, frame):
-            # threadlint: disable=signal-handler-unsafe -- request() is a
-            # single lock-free GIL-atomic deque append (ProfileTrigger is
-            # deliberately lockless for exactly this call site: the
-            # interrupted main thread may be inside consume()).
-            profile_trigger.request()
-            # threadlint: disable=signal-handler-unsafe -- best-effort
-            # notice; logging's RLock is reentrant from the interrupted
-            # main thread, worst case interleaved output.
-            logger.info(
-                "[obs] SIGUSR2: profiler capture requested "
-                f"({obs.http.DEFAULT_PROFILE_STEPS} steps)"
-            )
-        prev_usr2 = signal.signal(signal.SIGUSR2, _on_usr2)
+    # The death callback reaches the record through a weak reference: record
+    # -> feed -> callback -> record would be a cycle, and the record's state
+    # (8 GB on the device in the token cell) would outlive this function
+    # until the collector runs; whoever uses the device next (the benchmark's
+    # check does) then finds no room (PERF.md, PR 31).
+    def _on_loader_death(e, run_ref=weakref.ref(run)) -> None:
+        r = run_ref()
+        _loader_death_exit(r, e, r.epoch, r.batches_done)
 
-    obs_closed = [False]
-
-    def _obs_close() -> None:
-        """Tear down the telemetry plane. Idempotent; runs on the normal
-        return, the preempt exit, AND — via _OBS_CLEANUP drained in the
-        _dump_flight_on_exception finally — every exception/SystemExit
-        path, so a crashed run cannot leave the metrics port bound or
-        the events fd open for the process's next run. Uninstalling the
-        recorder also unhooks its bus span sink, so back-to-back runs in
-        one process never stack sinks."""
-        if obs_closed[0]:
-            return
-        obs_closed[0] = True
-        obs.flight.install(None)
-        if events is not None:
-            events.close()
-        if metrics_server is not None:
-            metrics_server.shutdown()
-            metrics_server.server_close()  # release the listening port
-        if prev_usr2 is not None:
-            try:
-                signal.signal(signal.SIGUSR2, prev_usr2)
-            except ValueError:  # not the main thread anymore
-                pass
-
-    _OBS_CLEANUP.append(_obs_close)
-
-    def _emit_event(kind: str, **fields) -> None:
-        recorder.record_event(kind, **fields)
-        if events is not None:
-            events.emit(kind, **fields)
-
-    def _step_out(ret):
-        """Normalize (state, loss, outputs[, diag]) across guard on/off."""
-        if len(ret) == 4:
-            return ret
-        s, l, o = ret
-        return s, l, o, None
-
-    def _interval_save(state, epoch, batches_done, gstep, wait=False):
-        """Step-granular async save at a --save-interval-steps boundary
-        (also the preempt-exit save, with ``wait=True``). The recorded
-        data position is the NEXT batch to consume."""
-        if batches_done >= steps_per_epoch:
-            d_epoch, d_off = epoch + 1, 0
-        else:
-            d_epoch, d_off = epoch, batches_done
-        with obs.BUS.span("checkpoint_save"):
-            ckpt_mgr.save(
-                gstep,
-                state,
-                epoch=epoch,
-                data_epoch=d_epoch,
-                data_batch_offset=d_off,
-                seed=args.seed,
-                steps_per_epoch=steps_per_epoch,
-                batch_size=int(args.batch_size),
-                on_exists="skip",  # resume/rollback may re-reach a step
-                wait=wait,
-            )
-        return d_epoch, d_off
-
-    def _rollback(state):
-        """Bad-update-guard rollback: restore the last checkpoint (params
-        + optimizer) and continue from the CURRENT data position."""
-        ckpt_mgr.wait()
-        step_r = ckpt_mgr.latest_step()
-        if step_r is None:
-            raise RuntimeError(
-                f"{monitor.bad_run} consecutive non-finite updates and no "
-                "checkpoint to roll back to — aborting (enable "
-                "--save-interval-steps for rollback coverage)"
-            )
-        logger.warning(
-            f"Bad-update guard: {monitor.bad_run} consecutive non-finite "
-            f"updates; rolling back to checkpoint step {step_r}"
-        )
-        # The run survives a rollback, but the steps leading into it are
-        # exactly what a post-mortem wants — snapshot them now, before
-        # the ring rolls past (docs/OBSERVABILITY.md).
-        _emit_event(
-            "bad_update_rollback",
-            rollback_to_step=int(step_r),
-            consecutive_bad=int(monitor.bad_run),
-        )
-        # arm_dedup=False: this dump is non-fatal (the run continues) and
-        # must never suppress the record of a crash seconds later.
-        obs.flight.dump_on_death(
-            "bad_update_rollback", arm_dedup=False,
-            rollback_to_step=int(step_r),
-        )
-        restored = ckpt_mgr.restore(state, step=step_r)
-        monitor.reset()
-        return mesh_lib.replicate(mesh, restore_into_state(state, restored))
-
-    def _preempt_exit(state, epoch, batches_done, gstep, hard=False):
-        """Step-boundary preemption: make the final checkpoint durable
-        (wait=True barriers the async write), then exit with the
-        documented preempt code for tools/supervise.py.
-
-        ``hard=True`` (the loader-death path) ends in ``os._exit``: the
-        data plane is known-wedged and its pool threads are non-daemon,
-        so ``sys.exit`` would hang forever in ``threading._shutdown``
-        joining a thread stuck inside a dead read — the exact hang this
-        machinery exists to eliminate. The watchdog is left armed as the
-        escalation if even the final save wedges."""
-        if watchdog is not None and not hard:
-            watchdog.stop()
-        d_epoch, d_off = _interval_save(
-            state, epoch, batches_done, gstep, wait=True
-        )
-        logger.warning(
-            f"Preempted: checkpoint step {gstep} durable "
-            f"(data position {d_epoch}:{d_off}); exiting {PREEMPT_EXIT_CODE}"
-        )
-        _emit_event(
-            "preempt", gstep=int(gstep), data_epoch=int(d_epoch),
-            data_batch_offset=int(d_off), hard=bool(hard),
-        )
-        obs.flight.dump_on_death("preempt", gstep=int(gstep))
-        if writer is not None:
-            writer.close()
-        train_loader.close()
-        val_loader.close()
-        ckpt_mgr.close()
-        _obs_close()
-        if hard:
-            io_guard.hard_exit(PREEMPT_EXIT_CODE)
-        sys.exit(PREEMPT_EXIT_CODE)
-
-    def _loader_death_exit(e, state, epoch, batches_done):
-        """Loader-thread death (data/io_guard.py LoaderDeathError): the
-        device and params are healthy — checkpoint the current position
-        and preempt-exit so the supervisor relaunches with a fresh data
-        plane rather than the run dying opaquely (or, pre-watchdog,
-        hanging forever)."""
-        logger.error(
-            f"Loader worker death: {e}; dumping thread stacks and "
-            "preempt-exiting for supervised relaunch"
-        )
-        io_guard.dump_thread_stacks()
-        if watchdog is not None:
-            # Escalation: the data plane is wedged; if the final save
-            # below hangs too, the watchdog's os._exit still gets us out.
-            watchdog.arm()
-        _preempt_exit(
-            state, epoch, batches_done,
-            epoch * steps_per_epoch + batches_done,
-            hard=True,
-        )
-
-    best_loss = float("inf")
-    best_ckpt_path = ""
-    patience_counter = 0
-    tasks = list(spec.eval)
-    train_losses: List[float] = []
-    val_losses: List[float] = []
-    epoch_times: List[float] = []
-
-    # --profile-steps N: capture a jax.profiler trace of N steady-state
-    # OPTIMIZER steps (skipping compile/warmup) in the first trained epoch.
-    # Counted in optimizer steps regardless of the packed path (each loop
-    # iteration advances `updates_per_call` of them). Later captures are
-    # re-armed on demand: SIGUSR2 or POST /profile on --metrics-port.
-    profile_steps = int(getattr(args, "profile_steps", 0) or 0)
-    # Batches consumed per loop iteration on the packed path (steps-per-call
-    # runs kpack updates/call; grad accumulation runs ONE update over kpack
-    # micro-batches) — vs optimizer UPDATES per iteration, which is what
-    # _maybe_trace counts.
-    kpack = gas if gas > 1 else spc
-    updates_per_call = 1 if gas > 1 else spc
-    profile_from = 2 * updates_per_call  # skip the first two loop iterations
-    tracing = False
-    trace_dir = ""
-
-    def _trace_dir() -> str:
-        # Unique per supervise attempt AND per capture window
-        # (timestamp + pid + no-clobber suffix): a relaunched run must
-        # never overwrite the previous attempt's trace.
-        return get_safe_path(
-            os.path.join(
-                logger.logdir(), "profile",
-                f"{get_time_str()}_p{os.getpid()}",
-            )
-        )
-
-    monitor = _BadUpdateMonitor(max_bad)
-    preempt = _PreemptionHandler()
-    preempt.__enter__()  # uninstalled after the epoch loop (normal path)
-
-    def _maybe_trace(opt_step: int, loss) -> None:
-        """``opt_step``: optimizer steps completed before this iteration."""
-        nonlocal tracing, profile_steps, profile_from, trace_dir
-        if not is_main_process():
-            return
-        if not tracing:
-            # On-demand capture (SIGUSR2 / POST /profile): open the
-            # window at the next step boundary. Consume ONLY when idle —
-            # a request arriving mid-capture stays in the trigger box and
-            # opens its own window once this one closes.
-            req = profile_trigger.consume()
-            if req:
-                profile_steps = req
-                profile_from = opt_step + updates_per_call
-                _emit_event("profile_requested", steps=req)
-        if not profile_steps:
-            return
-        if not tracing and opt_step >= profile_from:
-            trace_dir = _trace_dir()
-            profiling.trace_start(trace_dir)
-            tracing = True
-        elif tracing and opt_step >= profile_from + profile_steps:
-            jax.block_until_ready(loss)
-            profiling.trace_stop()
-            tracing = False
-            profile_steps = 0  # one-shot; the trigger re-arms it
-            logger.info(f"Profiler trace saved: {trace_dir}")
-
-    # Bus handles resolved once (a per-step gauge set is then one lock,
-    # no registry lookup). All interval clocks below are obs spans on the
-    # shared monotonic source: an NTP step or suspend must not corrupt
-    # ETA/throughput math on a days-long run; time.time() remains only
-    # where a real timestamp is reported.
-    g_loss = obs.BUS.gauge("train_loss")
-    g_wps = obs.BUS.gauge("waveforms_per_sec")
-    g_epoch = obs.BUS.gauge("epoch")
-    g_gstep = obs.BUS.gauge("global_step")
+    feed.attach(watchdog=watchdog, faults=faults, on_death=_on_loader_death)
+    run.preempt.__enter__()  # uninstalled after the epoch loop (normal path)
     setup.end()
 
-    for epoch in range(start_epoch, epochs):
-        epoch_span = obs.BUS.begin("train_epoch")
-        g_epoch.set(epoch)
-        train_loader.set_epoch(epoch)
-        skip = start_batch if epoch == start_epoch else 0
-        if skip and kpack > 1 and skip % kpack:
-            # Packed paths consume kpack batches per call; a checkpoint
-            # from the single-step path may sit off a call boundary.
-            logger.warning(
-                f"Resume offset {skip} is not a multiple of the packed "
-                f"group {kpack}; rounding down (re-trains {skip % kpack} "
-                "batch(es))"
-            )
-            skip = (skip // kpack) * kpack
-        if skip:
-            train_loader.set_start_batch(skip)
-            logger.info(f"Mid-epoch resume: epoch {epoch} from batch {skip}")
-        epoch_rng = jax.random.fold_in(base_rng, epoch)
+    _train_epochs(run)
 
-        # -- train epoch (ref train.py:20-179) --------------------------------
-        loss_meter = AverageMeter("loss", ":.4e")
-        wps_meter = AverageMeter("wave/s", ":.1f")
-        metrics_merged = _make_metrics(args, tasks, fs)
-        progress = ProgressMeter(
-            steps_per_epoch, [loss_meter, wps_meter], prefix=f"Epoch[{epoch}] "
-        )
-        # Log-interval clock for wave/s (seconds since the last log line).
-        lap = _LapClock()
-        # Device->host transfers are confined to every --log-step steps:
-        # pulling loss/outputs every step serializes JAX's async dispatch
-        # and stalls the chip on host postprocess (the per-step numbers are
-        # only diagnostics — TB scalars and the progress line). Per-step
-        # losses are kept as device scalars and fetched once per epoch.
-        deferred_losses: List[Any] = []
-        deferred_aux: List[Any] = []  # a token task's per-step counts
-        global_bs = args.batch_size * jax.process_count()
-        # Loader-death handling (io_guard.watch on_death): checkpoint at
-        # the last completed batch and preempt-exit. `batches_done` is
-        # kept current by every loop body; the closure reads the latest
-        # `state` at fire time.
-        batches_done = skip
-
-        def _on_loader_death(e: io_guard.LoaderDeathError) -> None:
-            _loader_death_exit(e, state, epoch, batches_done)
-
-        if device_mode == "cached":
-            # HBM-resident path: one jitted call = kpack scanned updates;
-            # the ONLY per-call host->device traffic is the (k, B) int32
-            # index array. Loss/save/preempt bookkeeping mirrors the
-            # packed host path.
-            import jax.numpy as jnp
-
-            for call, idx_k in enumerate(
-                dev_cache.epoch_index_chunks(
-                    epoch,
-                    seed=args.seed,
-                    shuffle=args.shuffle,
-                    batch_size=args.batch_size,
-                    steps_per_call=kpack,
-                    start_batch=skip,
-                    num_shards=jax.process_count(),
-                    shard_index=jax.process_index(),
-                    source_ids=src_ids_logical,
-                    mixture_temperature=mixture_t,
-                ),
-                start=skip // kpack,
-            ):
-                gstep = epoch * steps_per_epoch + call * kpack
-                # Record BEFORE the spans of this step end, so the
-                # recorder tags them with the step that is actually
-                # running — the dying step's spans must carry its number.
-                recorder.record_step(gstep)
-                g_gstep.set(gstep)
-                faults.on_step(gstep, n_steps=kpack)
-                idx_dev = mesh_lib.shard_stacked_batch(mesh, idx_k)
-                with obs.BUS.span("step_dispatch"):
-                    state, loss, _, diag = _step_out(
-                        train_step(
-                            state, dev_cache.arrays, idx_dev,
-                            jnp.int32(epoch), epoch_rng,
-                        )
-                    )
-                deferred_losses.append(loss)
-                if diag is not None and monitor.push(diag["applied"]):
-                    state = _rollback(state)
-                _maybe_trace(call * updates_per_call, loss)
-                batches_done = (call + 1) * kpack
-                if save_every and (
-                    batches_done // save_every
-                    > (batches_done - kpack) // save_every
-                ):
-                    _interval_save(
-                        state, epoch, batches_done,
-                        epoch * steps_per_epoch + batches_done,
-                    )
-                if preempt.triggered:
-                    _preempt_exit(
-                        state, epoch, batches_done,
-                        epoch * steps_per_epoch + batches_done,
-                    )
-                if call % args.log_step == 0:
-                    loss_f = float(loss)
-                    loss_meter.update(loss_f, 1)
-                    interval = lap()
-                    calls_done = min(args.log_step, call) or 1
-                    wps_meter.update(
-                        global_bs * kpack * calls_done
-                        / max(interval, 1e-9)
-                    )
-                    g_loss.set(loss_f)
-                    g_wps.set(wps_meter.val)
-                    if writer is not None:
-                        writer.add_scalar(
-                            "train-loss/step",
-                            loss_f,
-                            epoch * steps_per_epoch + call * kpack,
-                        )
-                    if is_main_process():
-                        logger.info(
-                            f"{args.model_name}_train "
-                            f"{progress.get_str(call * kpack)}"
-                        )
-
-        elif device_mode == "step":
-            # Raw rows cross the host per step (fancy-index gather, no
-            # per-sample augmentation / label synthesis / stacking);
-            # the jitted step does the rest. Per-step train metrics are
-            # skipped like the packed path — metrics targets only exist
-            # on the host pipeline.
-            import jax.numpy as jnp
-
-            for step, (rows, idx, aug) in enumerate(
-                obs.timed_iter(
-                    io_guard.watch(
-                        pipeline.prefetch_raw_to_device(
-                            pipeline.iter_raw_batches(
-                                dev_store,
-                                epoch,
-                                seed=args.seed,
-                                shuffle=args.shuffle,
-                                batch_size=args.batch_size,
-                                num_shards=jax.process_count(),
-                                shard_index=jax.process_index(),
-                                start_batch=skip,
-                                source_ids=src_ids_logical,
-                                mixture_temperature=mixture_t,
-                            ),
-                            mesh,
-                        ),
-                        watchdog,
-                    ),
-                    "host_wait",
-                ),
-                start=skip,
-            ):
-                batches_done = step + 1
-                gstep = epoch * steps_per_epoch + step
-                recorder.record_step(gstep)  # before this step's spans end
-                g_gstep.set(gstep)
-                faults.on_step(gstep)
-                with obs.BUS.span("step_dispatch"):
-                    state, loss, _, diag = _step_out(
-                        train_step(
-                            state, rows, idx, aug, jnp.int32(epoch), epoch_rng
-                        )
-                    )
-                deferred_losses.append(loss)
-                if diag is not None and monitor.push(diag["applied"]):
-                    state = _rollback(state)
-                _maybe_trace(step, loss)
-                if save_every and (step + 1) % save_every == 0:
-                    _interval_save(state, epoch, step + 1, gstep + 1)
-                if preempt.triggered:
-                    _preempt_exit(state, epoch, step + 1, gstep + 1)
-                if step % args.log_step == 0:
-                    loss_f = float(loss)
-                    loss_meter.update(loss_f, 1)
-                    interval = lap()
-                    steps_done = min(args.log_step, step) or 1
-                    wps_meter.update(
-                        global_bs * steps_done / max(interval, 1e-9)
-                    )
-                    g_loss.set(loss_f)
-                    g_wps.set(wps_meter.val)
-                    if writer is not None:
-                        writer.add_scalar("train-loss/step", loss_f, gstep)
-                    if is_main_process():
-                        logger.info(
-                            f"{args.model_name}_train {progress.get_str(step)}"
-                        )
-
-        elif kpack > 1:
-            # Packed path: one jitted call consumes kpack batches — either
-            # kpack sequential updates (--steps-per-call) or one
-            # accumulated update (--grad-accum-steps). The per-call loss is
-            # already the mean over its micro-batches.
-            for call, (xk, yk) in enumerate(
-                obs.timed_iter(
-                    io_guard.watch(
-                        pipeline.prefetch_packed_to_device(
-                            iter(train_loader), mesh, kpack
-                        ),
-                        watchdog,
-                        on_death=_on_loader_death,
-                    ),
-                    "host_wait",
-                ),
-                start=skip // kpack,
-            ):
-                first_b = epoch * steps_per_epoch + call * kpack
-                recorder.record_step(first_b)  # before this call's spans end
-                g_gstep.set(first_b)
-                faults.on_step(first_b, n_steps=kpack)
-                xk = faults.corrupt_inputs(first_b, xk, n_steps=kpack)
-                with obs.BUS.span("step_dispatch"):
-                    state, loss, _, diag = _step_out(
-                        train_step(state, xk, yk, epoch_rng)
-                    )
-                deferred_losses.append(loss)
-                if diag is not None and monitor.push(diag["applied"]):
-                    state = _rollback(state)
-                _maybe_trace(call * updates_per_call, loss)
-                batches_done = (call + 1) * kpack
-                if save_every and (
-                    batches_done // save_every
-                    > (batches_done - kpack) // save_every
-                ):
-                    _interval_save(
-                        state, epoch, batches_done,
-                        epoch * steps_per_epoch + batches_done,
-                    )
-                if preempt.triggered:
-                    _preempt_exit(
-                        state, epoch, batches_done,
-                        epoch * steps_per_epoch + batches_done,
-                    )
-                if call % args.log_step == 0:
-                    loss_f = float(loss)
-                    loss_meter.update(loss_f, 1)
-                    interval = lap()
-                    calls_done = min(args.log_step, call) or 1
-                    wps_meter.update(
-                        global_bs * kpack * calls_done
-                        / max(interval, 1e-9)
-                    )
-                    g_loss.set(loss_f)
-                    g_wps.set(wps_meter.val)
-                    if writer is not None:
-                        writer.add_scalar(
-                            "train-loss/step",
-                            loss_f,
-                            epoch * steps_per_epoch + call * kpack,
-                        )
-                    if is_main_process():
-                        logger.info(
-                            f"{args.model_name}_train "
-                            f"{progress.get_str(call * kpack)}"
-                        )
-
-        else:
-            # The progress line reads a loss back. Reading the newest
-            # step's would empty the device's queue every --log-step
-            # steps, and whatever then holds the host up is the device's
-            # idle time: beside a checkpoint's background write the next
-            # dispatches took 0.2-0.5 s each where they take 3 ms (PERF.md,
-            # PR 29). So the line is made `monitor.lag` steps late, where
-            # the guard reads too: that step has ended by then and two
-            # more are queued behind it.
-            late_logs: "collections.deque" = collections.deque()
-
-            def _log_step(step, gstep, loss, outputs, batch) -> None:
-                loss_f = float(loss)
-                loss_meter.update(loss_f, 1)
-                interval = lap()
-                steps_done = min(args.log_step, step) or 1
-                wps_meter.update(global_bs * steps_done / max(interval, 1e-9))
-                g_loss.set(loss_f)
-                g_wps.set(wps_meter.val)
-
-                batch_metrics = {}
-                if tasks:  # a token task has nothing to pick
-                    results = _postprocess_batch(args, spec, outputs, fs)
-                    batch_metrics = _make_metrics(args, tasks, fs)
-                    _update_task_metrics(
-                        metrics_merged,
-                        batch_metrics,
-                        results,
-                        batch.metrics_targets,
-                        args.batch_size,
-                    )
-                if writer is not None:
-                    writer.add_scalar("train-loss/step", loss_f, gstep)
-                    for task, m in batch_metrics.items():
-                        writer.add_scalars(
-                            f"train.{task}.metrics/step",
-                            m.get_all_metrics(),
-                            gstep,
-                        )
-                if is_main_process():
-                    logger.info(
-                        f"{args.model_name}_train {progress.get_str(step)}"
-                    )
-
-            for step, batch in enumerate(
-                obs.timed_iter(
-                    io_guard.watch(
-                        pipeline.prefetch_to_device(iter(train_loader), mesh),
-                        watchdog,
-                        on_death=_on_loader_death,
-                    ),
-                    "host_wait",
-                ),
-                start=skip,
-            ):
-                batches_done = step + 1
-                gstep = epoch * steps_per_epoch + step
-                recorder.record_step(gstep)  # before this step's spans end
-                g_gstep.set(gstep)
-                faults.on_step(gstep)
-                inputs = faults.corrupt_inputs(gstep, batch.inputs)
-                with obs.BUS.span("step_dispatch"):
-                    state, loss, outputs, diag = _step_out(
-                        train_step(
-                            state, inputs, batch.loss_targets, epoch_rng
-                        )
-                    )
-                deferred_losses.append(loss)
-                if spec.tokens:
-                    deferred_aux.append(outputs)
-                if diag is not None and monitor.push(diag["applied"]):
-                    state = _rollback(state)
-                _maybe_trace(step, loss)
-                if save_every and (step + 1) % save_every == 0:
-                    _interval_save(state, epoch, step + 1, gstep + 1)
-                if preempt.triggered:
-                    _preempt_exit(state, epoch, step + 1, gstep + 1)
-
-                if step % args.log_step == 0:
-                    late_logs.append((step, gstep, loss, outputs, batch))
-                while late_logs and step - late_logs[0][0] >= monitor.lag:
-                    _log_step(*late_logs.popleft())
-            while late_logs:  # the epoch's tail
-                _log_step(*late_logs.popleft())
-
-        if tracing:  # epoch shorter than the capture window
-            # Sync first: steps may still be executing asynchronously, and
-            # stopping early would truncate their device activity.
-            jax.block_until_ready(deferred_losses)
-            profiling.trace_stop()
-            tracing = False
-            profile_steps = 0
-            logger.info(f"Profiler trace saved (short epoch): {trace_dir}")
-
-        if monitor.flush():  # lagging guard flags from the epoch tail
-            state = _rollback(state)
-        # The device finishing the calls the host ran ahead of.
-        with obs.BUS.span("epoch_drain"):
-            epoch_losses, epoch_aux = jax.device_get(
-                (deferred_losses, deferred_aux))
-            epoch_losses = [float(l) for l in epoch_losses]
-        _feed_token_counters(epoch_aux)
-        train_losses.extend(epoch_losses)
-        # Exact epoch mean from every step's loss (the meter only samples
-        # every log_step steps, for the progress line). Guard-skipped steps
-        # leave non-finite entries in the raw curve; the epoch mean is
-        # taken over the finite ones only.
-        finite_losses = [l for l in epoch_losses if np.isfinite(l)]
-        epoch_train_loss = (
-            float(np.mean(finite_losses)) if finite_losses else 0.0
-        )
-        for m in metrics_merged.values():
-            m.synchronize_between_processes()
-
-        # -- data-plane epoch report (docs/FAULT_TOLERANCE.md) ----------------
-        # Quarantined samples and guard counters, logged every epoch so a
-        # slowly-rotting dataset is visible long before the
-        # --max-quarantine-frac abort trips.
-        q_report = train_loader.dataset.quarantine_report()
-        if q_report["quarantined"]:
-            logger.warning(
-                f"[data-plane] epoch {epoch} quarantine report: "
-                f"{json.dumps(q_report)}"
-            )
-            _emit_event(
-                "quarantine_report", epoch=epoch,
-                quarantined=len(q_report["quarantined"]),
-                frac=q_report["frac"],
-            )
-        if io_guard.COUNTERS.any_faults():
-            logger.info(
-                f"[data-plane] counters: {io_guard.COUNTERS.snapshot()}"
-            )
-
-        # -- validate + checkpoint (ref train.py:402-415) ---------------------
-        try:
-            with obs.BUS.span("validate"):
-                val_loss, val_metrics = validate(
-                    args, state, eval_step, spec, val_loader, mesh,
-                    watchdog=watchdog, resident=resident_val,
-                )
-        except io_guard.LoaderDeathError as e:
-            _loader_death_exit(e, state, epoch, steps_per_epoch)
-        obs.BUS.gauge("val_loss").set(val_loss)
-        val_losses.append(val_loss)
-        if writer is not None:
-            writer.add_scalar("train-loss/epoch", epoch_train_loss, epoch)
-            writer.add_scalar("val-loss/epoch", val_loss, epoch)
-            # Train metrics accumulated at --log-step cadence + psum'd
-            # across hosts above (ref train.py:420-442 logs both phases).
-            for task, m in metrics_merged.items():
-                writer.add_scalars(
-                    f"train.{task}.metrics/epoch", m.get_all_metrics(), epoch
-                )
-            for task, m in val_metrics.items():
-                writer.add_scalars(
-                    f"val.{task}.metrics/epoch", m.get_all_metrics(), epoch
-                )
-
-        epoch_end_step = (epoch + 1) * steps_per_epoch
-        if val_loss < best_loss:
-            best_loss = val_loss
-            patience_counter = 0
-            # Checkpoint path is deterministic across hosts: step-numbered
-            # under the log_dir that cli.main_worker broadcast from process 0
-            # (replacing the reference's rank0 ckpt-path broadcast,
-            # train.py:481-482). The val metric feeds the manager's
-            # keep-best retention, so GC never deletes this step.
-            with obs.BUS.span("checkpoint_save"):
-                best_ckpt_path = ckpt_mgr.save(
-                    epoch_end_step,
-                    state,
-                    epoch=epoch,
-                    data_epoch=epoch + 1,
-                    data_batch_offset=0,
-                    val_loss=val_loss,
-                    seed=args.seed,
-                    steps_per_epoch=steps_per_epoch,
-                    batch_size=int(args.batch_size),
-                    # an interval save may own this boundary
-                    on_exists="skip",
-                )
-        else:
-            patience_counter += 1
-            if patience_counter > args.patience:
-                logger.info(
-                    f"Early stopping at epoch {epoch} "
-                    f"(no val improvement in {args.patience} epochs)"
-                )
-                break
-        if preempt.triggered:  # SIGTERM during validation
-            _preempt_exit(state, epoch, steps_per_epoch, epoch_end_step)
-
-        dt = epoch_span.end()
-        epoch_times.append(dt)
-        eta = float(np.mean(epoch_times)) * (epochs - epoch - 1)
-        logger.info(
-            f"Epoch {epoch}: train-loss {epoch_train_loss:.4e} "
-            f"val-loss {val_loss:.4e} best {best_loss:.4e} "
-            f"time {strftimedelta(dt)} ETA {strftimedelta(eta)}"
-        )
-        _emit_event(
-            "epoch_summary",
-            epoch=epoch,
-            train_loss=round(epoch_train_loss, 6),
-            val_loss=round(float(val_loss), 6),
-            best_loss=round(float(best_loss), 6),
-            epoch_time_s=round(dt, 3),
-            wps=round(wps_meter.val, 1),
-            data_plane=io_guard.COUNTERS.snapshot(),
-        )
-
-    preempt.__exit__()
+    run.preempt.__exit__()
     if watchdog is not None:
         watchdog.stop()
     if io_guard.COUNTERS.any_faults():
         logger.info(
             f"[data-plane] run counters: {io_guard.COUNTERS.snapshot()}"
         )
-    if monitor.total_skipped:
+    if run.monitor.total_skipped:
         logger.warning(
-            f"Bad-update guard skipped {monitor.total_skipped} non-finite "
+            f"Bad-update guard skipped {run.monitor.total_skipped} non-finite "
             "update(s) this run"
         )
     ckpt_mgr.close()  # barrier on any in-flight async save
     if is_main_process():
-        np.save(os.path.join(logger.logdir(), "train_losses.npy"), train_losses)
-        np.save(os.path.join(logger.logdir(), "val_losses.npy"), val_losses)
+        np.save(os.path.join(logger.logdir(), "train_losses.npy"), run.train_losses)
+        np.save(os.path.join(logger.logdir(), "val_losses.npy"), run.val_losses)
     if writer is not None:
         writer.close()
-    _emit_event("train_done", best_loss=round(float(best_loss), 6))
-    _obs_close()
+    telemetry.emit("train_done", best_loss=round(float(run.best_loss), 6))
+    telemetry.close()
     train_loader.close()
     val_loader.close()
-    return best_ckpt_path
+    return run.best_ckpt_path
 
 
 def test_worker(args: Any) -> float:

@@ -1,9 +1,11 @@
 """The span tree (obs/bus.py), the spans in the profiler's trace, compile
 accounting (obs/jit_events.py), and the spans the train worker emits."""
 
+import gc
 import glob
 import os
 import threading
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -204,22 +206,86 @@ def test_compile_counters_move_on_a_compile_and_on_a_cache_hit(tmp_path):
         cc.reset_cache()
 
 
+class Tap:
+    """What benchmarks/drivers/train.py ``StepTap`` keeps of a call."""
+
+    def __init__(self, fn):
+        self.fn, self.gsteps = fn, []
+
+    def __call__(self, *args, **kwargs):
+        self.gsteps.append(BUS.gauge("global_step").value)
+        return self.fn(*args, **kwargs)
+
+
 @pytest.fixture(scope="module")
 def worker_spans(tmp_path_factory):
-    """One small cached-path training run (two epochs) under a span sink."""
-    from seist_tpu.train.worker import train_worker
+    """One small cached-path training run (two epochs) under a span sink,
+    with the worker module's ``jit_*`` factories wrapped the way the
+    benchmark's harness wraps them (``install_taps``): ``sink.taps``,
+    and the run's log text as ``sink.log``."""
+    from seist_tpu.train import worker
 
-    logger.set_logdir(str(tmp_path_factory.mktemp("tree_logs")))
+    logdir = str(tmp_path_factory.mktemp("tree_logs"))
+    logger.set_logdir(logdir)
     sink = Sink()
+    sink.taps, sink.runs = {}, []
     BUS.add_span_sink(sink)
-    try:
-        train_worker(make_args(
-            mode="train", epochs=2, in_samples=512, device_aug="cached",
-            dataset_kwargs={"num_events": 80, "trace_samples": 1500},
-        ))
-    finally:
-        BUS.remove_span_sink(sink)
+    BUS.gauge("global_step").set(-1)
+    with pytest.MonkeyPatch.context() as patch:
+        for name in dir(worker):
+            factory = getattr(worker, name)
+            if not name.startswith("jit_") or not callable(factory):
+                continue
+
+            def wrapped(*a, _factory=factory, _name=name, **k):
+                sink.taps[_name] = Tap(_factory(*a, **k))
+                return sink.taps[_name]
+
+            patch.setattr(worker, name, wrapped)
+
+        class TrackedRun(worker._Run):
+            def __init__(self, **fields):
+                super().__init__(**fields)
+                sink.runs.append(weakref.ref(self))
+
+        patch.setattr(worker, "_Run", TrackedRun)
+        gc.disable()  # a record kept alive by a cycle must stay visible
+        try:
+            worker.train_worker(make_args(
+                mode="train", epochs=2, in_samples=512, device_aug="cached",
+                dataset_kwargs={"num_events": 80, "trace_samples": 1500},
+            ))
+            sink.runs_alive_at_return = [r() is not None for r in sink.runs]
+        finally:
+            gc.enable()
+            BUS.remove_span_sink(sink)
+    sink.log = "".join(
+        open(f).read() for f in glob.glob(os.path.join(logdir, "*.log")))
     return sink
+
+
+def test_the_harness_finds_its_taps_and_its_log_line(worker_spans):
+    """The benchmark holds the trainer by three unwritten contracts
+    (ROADMAP D16): every ``jit_*`` factory is called as an attribute of the
+    module ``seist_tpu.train.worker``, the ``global_step`` gauge is set
+    before a call is dispatched, and ``checks/train_invariants.py`` reads
+    the resolved input path out of this log line."""
+    import re
+
+    taps = worker_spans.taps
+    assert set(taps) == {"jit_cached_call", "jit_eval_step"}
+    train, evalt = taps["jit_cached_call"], taps["jit_eval_step"]
+    # 64 train events at batch 8: one call of 8 steps an epoch
+    assert train.gsteps == [0, 8]
+    assert len(evalt.gsteps) >= 2 and evalt.gsteps[0] == 0
+    assert len(worker_spans.named("step_dispatch")) == len(train.gsteps)
+    found = re.search(
+        r"device-aug cached: (\d+) epoch samples resident", worker_spans.log)
+    assert found and int(found.group(1)) == 64
+    # The check calls the trainer's programs once more after the run: the run
+    # record, and the train state it holds on the device, must be gone when
+    # train_worker returns, without waiting for the cycle collector.
+    assert worker_spans.runs_alive_at_return == [False]
 
 
 @pytest.mark.parametrize("which", ["streamed", "replayed"])
