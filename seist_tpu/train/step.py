@@ -40,6 +40,12 @@ def _first_call_span(jitted: Callable, name: str) -> Callable:
     invisible outside stderr. Steady-state cost: one truthiness check per
     call.
 
+    The first call runs to completion inside its span, which so also owns
+    the wait for the call's inputs (the cached store's upload outlasts
+    ``setup_store`` by a quarter of a minute) and one execution: a returned
+    first call means a device that has run the program, to the loop and to
+    whoever watches the run from outside (PERF.md, PR 35).
+
     The wrapper keeps what ``obs.scopes.scope_map`` needs to read the
     executable's HLO back later, on demand: the jitted function
     (``.jitted``) and the first call's types (``.first_call_types``:
@@ -55,6 +61,7 @@ def _first_call_span(jitted: Callable, name: str) -> Callable:
         types = abstract_call(args, kwargs)
         with BUS.span("jit_first_call", fn=name):
             out = jitted(*args, **kwargs)
+            jax.block_until_ready(out)
         call.first_call_types = types
         return out
 

@@ -1147,6 +1147,8 @@ def _end_epoch(run: _Run, epoch: int, epoch_span, trained) -> bool:
                 run.writer.add_scalars(
                     f"{phase}.{task}.metrics/epoch", m.get_all_metrics(), epoch
                 )
+        # On disk by the epoch's end (the writer has no flushing thread).
+        run.writer.flush()
 
     if val_loss < run.best_loss:
         run.best_loss = val_loss
@@ -1268,7 +1270,7 @@ def train_worker(args: Any) -> str:
     train_step, eval_step = _build_steps(args, spec, feed, mesh)
     setup.end()
 
-    # Scalar writer (its TensorBoard backend imports torch), checkpoint
+    # Scalar writer (utils/tb.py frames its own event files), checkpoint
     # manager, watchdog, telemetry plane, signal handlers.
     setup = obs.BUS.begin("setup_writers")
     writer = (

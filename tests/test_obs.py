@@ -461,6 +461,26 @@ def test_jit_first_call_span_recorded():
     assert h.count == before + 1  # only the first call is recorded
 
 
+def test_jit_first_call_runs_to_completion():
+    """The first call returns a finished result (its span owns the wait for
+    its inputs and one execution; the benchmark's conductor starts its
+    trace a fixed delay after that return, PERF.md PR 35)."""
+    import jax
+    import jax.numpy as jnp
+
+    from seist_tpu.train.step import _first_call_span
+
+    def slow(x):
+        return jax.lax.fori_loop(0, 300, lambda _, a: jnp.tanh(a @ a), x)
+
+    x = jnp.eye(256, dtype=jnp.float32) * 0.5
+    jax.block_until_ready(jax.jit(slow)(x))  # compiled: the wait below is the run's
+    fn = _first_call_span(jax.jit(slow), "unit_probe_ready")
+    # Without the wait this reads False: the run takes a tenth of a second
+    # and dispatch is asynchronous on the CPU backend too.
+    assert fn(x).is_ready()
+
+
 # ----------------------------------------- scrape-under-load consistency
 class TestScrapeUnderLoad:
     """ISSUE 11 satellite: /metrics scrapes racing a flushing batcher

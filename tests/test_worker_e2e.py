@@ -14,6 +14,7 @@ pytestmark = pytest.mark.slow  # full train->ckpt->test runs
 import seist_tpu
 from seist_tpu import taskspec
 from seist_tpu.utils.logger import logger
+from tests.test_tb import event_file, read_scalars
 
 seist_tpu.load_all()
 
@@ -88,7 +89,9 @@ def e2e_run(tmp_path_factory):
 
     logdir = str(tmp_path_factory.mktemp("e2e_logs"))
     logger.set_logdir(logdir)
-    args = make_args()
+    # The one run of this file with the scalar writer on, as a user's run
+    # and the benchmark's cells have it (--use-tensorboard defaults to true).
+    args = make_args(use_tensorboard=True)
     ckpt = train_worker(args)
     assert ckpt and os.path.exists(ckpt)
     args.checkpoint = ckpt
@@ -138,6 +141,64 @@ def test_training_learns_p_picks(tmp_path_factory):
     # while still failing hard on a model that didn't learn.
     f1 = payload["metrics"]["ppk"]["f1"]
     assert f1 >= 0.6, payload["metrics"]
+
+
+def test_event_file_holds_the_trainers_tags(e2e_run):
+    """The writer frames its own event files (utils/tb.py): what the trainer
+    wrote through it comes back out of TensorBoard's protobuf classes."""
+    pytest.importorskip("tensorboard.compat.proto.event_pb2")
+    logdir, _, _ = e2e_run
+    scalars = read_scalars(event_file(os.path.join(logdir, "tensorboard")))
+    tags = {tag for tag, _, _ in scalars}
+    assert {"train-loss/step", "train-loss/epoch", "val-loss/epoch"} <= tags, tags
+    assert any(t.startswith("val.ppk.metrics/epoch/") for t in tags), tags
+    assert all(np.isfinite(v) for t, v, _ in scalars if "loss" in t), scalars
+    # The epoch's curves are the numbers the run saved beside them.
+    by_tag = {tag: value for tag, value, step in scalars if step == 0}
+    assert by_tag["train-loss/epoch"] == np.float32(
+        np.mean(np.load(os.path.join(logdir, "train_losses.npy")))  # one epoch
+    )
+    assert by_tag["val-loss/epoch"] == np.float32(
+        np.load(os.path.join(logdir, "val_losses.npy"))[0]
+    )
+
+
+def test_preempt_exit_leaves_nothing_running(tmp_path, monkeypatch):
+    """``cli.main`` left through the preempt exit, the benchmark's way out
+    (``benchmarks/run.py`` then ends by ``sys.exit``, which joins every
+    non-daemon thread and orphans nothing only if every child was closed):
+    no thread of the run's but the main one outlives it, and no child
+    process (PRs 32 and 34 were refused as ``process_left_running``)."""
+    import multiprocessing
+    import threading
+
+    from seist_tpu import cli
+    from seist_tpu.train.checkpoint import PREEMPT_EXIT_CODE
+
+    before = set(threading.enumerate())
+    monkeypatch.setenv("SEIST_FAULT_SIGTERM_STEP", "3")
+    with pytest.raises(SystemExit) as exit_info:
+        cli.main([
+            "--mode", "train", "--model-name", "phasenet",
+            "--dataset-name", "synthetic", "--synthetic-events", "40",
+            "--in-samples", "512", "--batch-size", "8", "--epochs", "2",
+            "--seed", "1", "--augmentation", "false", "--workers", "2",
+            "--save-interval-steps", "2", "--log-step", "1",
+            "--log-base", str(tmp_path),
+        ])
+    assert exit_info.value.code == PREEMPT_EXIT_CODE
+    left = [
+        t for t in threading.enumerate()
+        if t not in before and t.is_alive() and not t.daemon
+    ]
+    assert left == [], [(t.name, t.daemon) for t in left]
+    assert multiprocessing.active_children() == []
+    # The writer was on (the flag's default) and was closed on the way out:
+    # the file is whole, and holds the steps trained before the signal.
+    (run_dir,) = os.listdir(str(tmp_path))
+    scalars = read_scalars(event_file(os.path.join(str(tmp_path), run_dir, "tensorboard")))
+    steps = [s for tag, _, s in scalars if tag == "train-loss/step"]
+    assert steps and steps == sorted(steps), scalars
 
 
 def test_results_csv_written(e2e_run):
