@@ -42,10 +42,15 @@ therefore dense: each slot evaluates its curve over all the window's
 columns from the one ``make_soft_window`` table, which the compiler fuses
 into a few elementwise passes. Write the next op the same way: masks over
 ``jnp.arange(length)`` (as ``add_gaps``, ``generate_noise`` and
-``add_event_once`` do) instead of a data-dependent slice. The two per-row
-moves that remain, ``shift_event``'s roll and ``cut_window``'s crop, are
-such loops too (0.97 ms a step); ``tests/test_chip_compile.py`` counts
-them, so a third shows up there before it shows up on a chip.
+``add_event_once`` do) instead of a data-dependent slice. The per-row moves
+that cannot be masks — ``shift_event``'s roll, ``cut_window``'s crop and
+``add_event_once``'s roll, each a window of a circular row from a start of
+its own — go through ``ops/row_window.circular_window``, whose batching rule
+issues one Pallas kernel for the whole batch on the TPU (two such loops were
+0.97 ms a step and a padded copy of the rows; PERF.md, PR 36) and the
+``jnp.roll`` / ``dynamic_slice`` they were elsewhere.
+``tests/test_chip_compile.py`` counts the region's ``while`` ops (none), so
+a per-row slice shows up there before it shows up on a chip.
 
 Golden parity
 -------------
@@ -86,6 +91,7 @@ from seist_tpu.data.preprocess import (
     make_soft_window,
     pad_phases,
 )
+from seist_tpu.ops.row_window import circular_window
 
 # Invalid phase-slot sentinel: sorts after every real sample index.
 _BIG = 2**30
@@ -325,7 +331,7 @@ def add_event_once(
     space = jnp.minimum(L - pos, ce - ppk)
     cols = jnp.arange(L)
     seg = (cols >= pos) & (cols < pos + space)
-    rolled = jnp.roll(data, pos - ppk, axis=1)
+    rolled = circular_window(data, ppk - pos, L)  # roll by pos - ppk
     data = jnp.where(fire & seg[None, :], data + rolled * u_scale, data)
     ppks = jnp.where(fire, _sorted_insert(ppks, np_p, pos), ppks)
     spks = jnp.where(fire, _sorted_insert(spks, np_s, spk_add), spks)
@@ -336,7 +342,7 @@ def shift_event(data, ppks, np_p, spks, np_s, shift):
     """Circular time shift (ref preprocess.py:294-305)."""
     L = data.shape[-1]
     P = ppks.shape[0]
-    data = jnp.roll(data, shift, axis=1)
+    data = circular_window(data, -shift, L)  # roll by shift
     ar = jnp.arange(P)
 
     def sh(vals, n):
@@ -452,7 +458,7 @@ def cut_window(cfg: AugConfig, data, ppks, np_p, spks, np_s, u_crop):
         jnp.minimum(min_ppk, L - W) - cfg.min_event_gap, 1
     )
     c_l = _u2i(u_crop, bound)
-    win = jax.lax.dynamic_slice(data, (0, c_l), (C, W))
+    win = circular_window(data, c_l, W)
 
     def cutp(vals, n):
         keep = (ar < n) & (vals >= c_l) & (vals < c_l + W)

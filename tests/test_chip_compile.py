@@ -19,6 +19,7 @@ file. The persistent compile cache is turned off around the compiles — an
 executable compiled for a described chip cannot be read back without one.
 """
 
+import collections
 import re
 
 import jax
@@ -193,19 +194,13 @@ def test_data_parallel_kernel_compiles_for_four_v5e_chips(
 
 # ------------------------------------------------- augmentation's row loops
 AUG_LABELS = [("det", "ppk", "spk"), ("non", "ppk", "spk")]
+# (labels, add_event_rate, the region's kernels): the benchmark cells' label
+# sets at the trainer's default rates, and the event-duplication augment on
+AUG_CASES = [(AUG_LABELS[0], 0.0, 2), (AUG_LABELS[1], 0.0, 2), (AUG_LABELS[0], 0.3, 3)]
+AUG_IDS = ["det-ppk-spk", "non-ppk-spk", "det-ppk-spk-add_event"]
 
 
-@pytest.mark.parametrize("labels", AUG_LABELS, ids=["-".join(l) for l in AUG_LABELS])
-def test_device_aug_walks_the_batch_only_for_the_roll_and_the_crop(
-    one_chip, no_compile_cache, labels
-):
-    """The benchmark cells' label sets at their row shape (3 x 12000 raw,
-    8192 window, one phase slot, the trainer's default rates), batch 32:
-    the program for the chip holds two serial loops over the rows under
-    ``device_aug`` — ``shift_event``'s roll and ``cut_window``'s crop —
-    where it held fourteen while ``soft_label_place`` sliced and updated a
-    padded buffer per row (six placements: a gather loop and a scatter
-    loop each). A new per-row ``dynamic_slice`` shows up here as a third."""
+def _aug_process(labels, add_event_rate, n_raw):
     from seist_tpu.data import device_aug as da
 
     cfg = da.AugConfig(
@@ -214,29 +209,126 @@ def test_device_aug_walks_the_batch_only_for_the_roll_and_the_crop(
         min_event_gap=50, shift_event_rate=0.2, generate_noise_rate=0.05,
         drop_channel_rate=0.4, scale_amplitude_rate=0.4,
         pre_emphasis_rate=0.4, add_noise_rate=0.4, add_gap_rate=0.4,
+        add_event_rate=add_event_rate,
     )
-    n_raw = 256
-    process = da.make_cache_processor(
+    return da.make_cache_processor(
         cfg, (("z", "n", "e"),), (labels,), n_raw=n_raw, augmentation=True
     )
 
-    def struct(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+def _aug_structs(n_raw, batch, rows, repl):
+    def struct(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     cache = {
-        "data": struct((n_raw, 3, 12000), jnp.float32),
-        "ppks": struct((n_raw, 1), jnp.int32),
-        "np_p": struct((n_raw,), jnp.int32),
-        "spks": struct((n_raw, 1), jnp.int32),
-        "np_s": struct((n_raw,), jnp.int32),
+        "data": struct((n_raw, 3, 12000), jnp.float32, rows),
+        "ppks": struct((n_raw, 1), jnp.int32, rows),
+        "np_p": struct((n_raw,), jnp.int32, rows),
+        "spks": struct((n_raw, 1), jnp.int32, rows),
+        "np_s": struct((n_raw,), jnp.int32, rows),
     }
+    return cache, struct((batch,), jnp.int32, rows), struct((), jnp.int32, repl)
+
+
+def _region_ops(text, opcode):
+    """op_name of every ``opcode`` the region owns ('.' stops at the line's
+    end)."""
+    return re.findall(
+        rf' {opcode}\(.*op_name="([^"]*device_aug[^"]*)"', text
+    )
+
+
+@pytest.mark.parametrize("labels,add_event_rate,kernels", AUG_CASES, ids=AUG_IDS)
+def test_device_aug_walks_the_batch_in_no_loop_and_reads_its_windows_in_kernels(
+    one_chip, no_compile_cache, monkeypatch, labels, add_event_rate, kernels
+):
+    """The benchmark cells' row shape (3 x 12000 raw, 8192 window, one phase
+    slot), batch 32: the program for the chip holds NO serial loop over the
+    rows under ``device_aug``. It held fourteen while ``soft_label_place``
+    sliced and updated a padded buffer per row (PERF.md, PR 30) and two —
+    ``shift_event``'s roll and ``cut_window``'s crop — until those became
+    ``ops/row_window.circular_window``, one Mosaic kernel for the whole
+    batch each (``add_event_once``'s roll is the third, compiled only at
+    ``add_event_rate > 0``). The kernels carry the region's scope, so the
+    trace's join charges them to ``device_aug`` (obs/scopes.py). A new
+    per-row ``dynamic_slice`` under ``vmap`` shows up here as a loop."""
+    from seist_tpu.obs import scopes
+    from seist_tpu.ops import row_window
+
+    # the dispatch asks jax.default_backend(), which sees the CPU here
+    monkeypatch.setattr(row_window, "_on_tpu", lambda: True)
+    n_raw = 256
+    process = _aug_process(labels, add_event_rate, n_raw)
     text = (
         jax.jit(process)
-        .lower(cache, struct((BATCH,), jnp.int32), struct((), jnp.int32))
+        .lower(*_aug_structs(n_raw, BATCH, one_chip, one_chip))
         .compile()
         .as_text()
     )
-    # op_name of every `while` the region owns ('.' stops at the line's end)
-    loops = re.findall(r' while\(.*op_name="([^"]*device_aug[^"]*)"', text)
-    assert len(loops) <= 2, loops
-    assert not any("scatter" in op for op in loops), loops
+    loops = _region_ops(text, "while")
+    assert len(loops) == 0, loops
+    calls = [
+        line.split(" = ")[0].replace("ROOT ", "").strip()
+        for line in text.splitlines()
+        if "tpu_custom_call" in line and " custom-call(" in line
+    ]
+    assert len(calls) == kernels, calls
+    smap = scopes.parse_hlo(text)
+    assert [smap[name]["region"] for name in calls] == ["device_aug"] * kernels
+
+
+def test_device_aug_dense_passes_keep_their_layout_around_the_kernels(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """The compiler hands a custom call's operand layout ((B, C, L)
+    row-major, tiled T(4,128)) on to the elementwise passes around it unless
+    the rows are pinned on both sides of the call: at the cells' batch that
+    made the region slower than the loops it replaced and moved the loss's
+    last digits (PERF.md, PR 36). The program WITHOUT the kernels keeps a
+    batch of 256 rows of 12000 samples batch along the lanes."""
+    from seist_tpu.ops import row_window
+
+    monkeypatch.setattr(row_window, "_on_tpu", lambda: True)
+    n_raw = batch = 256
+    process = _aug_process(AUG_LABELS[0], 0.0, n_raw)
+    text = (
+        jax.jit(process)
+        .lower(*_aug_structs(n_raw, batch, one_chip, one_chip))
+        .compile()
+        .as_text()
+    )
+    rows = re.findall(rf"f32\[{batch},3,12000\](\{{[\d,]*:?)", text)
+    assert sum(r == "{0,2,1:" for r in rows) > 0.85 * len(rows), (
+        collections.Counter(rows)
+    )
+
+
+def test_device_aug_kernels_compile_for_four_v5e_chips(
+    topo, no_compile_cache, monkeypatch
+):
+    # Mosaic kernels cannot be partitioned automatically: under a
+    # data-parallel mesh each chip's kernels read ITS rows inside a
+    # shard_map, or XLA refuses the cached step on four chips.
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from seist_tpu.ops import row_window
+    from seist_tpu.parallel import mesh as mesh_lib
+
+    monkeypatch.setattr(row_window, "_on_tpu", lambda: True)
+    mesh = mesh_lib.make_mesh(data=4, devices=topo.devices)
+    rows = NamedSharding(mesh, P("data"))
+    repl = NamedSharding(mesh, P())
+    n_raw = 256
+    process = _aug_process(AUG_LABELS[0], 0.0, n_raw)
+    with mesh_lib.use_mesh(mesh):
+        text = (
+            jax.jit(process)
+            .lower(*_aug_structs(n_raw, BATCH, rows, repl))
+            .compile()
+            .as_text()
+        )
+    assert len(_region_ops(text, "while")) == 0
+    assert text.count("tpu_custom_call") >= 2
+    # each chip's kernel sees its quarter of the batch
+    assert f"f32[{BATCH // 4},3,12000]" in text

@@ -453,7 +453,8 @@ class TestBatchWideLabels:
         """Holds the mechanism in place: a per-row ``dynamic_slice`` /
         ``dynamic_update_slice`` under ``vmap`` lowers to a gather / a
         scatter that the TPU walks one row at a time. Of those the row
-        processor may keep the roll and the crop of the waveform, integer
+        processor may keep the roll and the crop of the waveform (off the
+        TPU; there they are one kernel, ops/row_window.py), integer
         phase look-ups and look-ups in the (width + 1)-entry window table;
         nothing is scattered, and no gather reads a label-sized buffer."""
         pre = make_pre(max_event_num=1, add_event_rate=0.0)
@@ -483,9 +484,10 @@ class TestBatchWideLabels:
             *dims, dtype = operand.split("x")
             if dtype == "f32":
                 float_minors.add(int(dims[-1]))
-        # the window table, the raw row (crop) and its doubled copy (roll)
-        assert float_minors <= {table, L, 2 * L}, float_minors
-        assert L in float_minors  # the crop is there: the parse sees gathers
+        # the window table, and the raw row twice over: off the TPU the roll
+        # and the crop are ``circular_window``'s plain form, a slice of that
+        assert float_minors <= {table, 2 * L}, float_minors
+        assert 2 * L in float_minors  # they are there: the parse sees gathers
 
 
 # --------------------------------------------------------- composed parity
